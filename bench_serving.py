@@ -29,7 +29,7 @@ mismatch is an engine bug, not noise).
 
 Writes the standard instrument status JSON (mxnet_tpu.instrument.v2:
 ``status`` ok|degraded|unavailable, rc 0 on outage — the
-BENCH_r05-proof contract every instrument in this repo honors) with
+contract every instrument in this repo honors) with
 the telemetry summary block.
 
 Usage: python bench_serving.py [--quick] [--decode]

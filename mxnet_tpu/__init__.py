@@ -67,8 +67,9 @@ from . import observability
 from . import serving
 from . import amp
 
-# persistent XLA compilation cache (MXNET_TPU_COMPILE_CACHE): applied
-# before any program compiles so restarts warm-start from disk
+# persistent XLA compilation cache (JAX_COMPILATION_CACHE_DIR, else
+# <repo>/.jax_cache): applied before any program compiles so restarts
+# warm-start from disk
 config.configure_compile_cache()
 
 # the join happened before observability existed; stamp it into the

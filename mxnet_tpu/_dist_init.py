@@ -143,21 +143,15 @@ def _enable_cpu_collectives():
     collective dies with "Multiprocess computations aren't implemented
     on the CPU backend" — the Gloo layer must be picked before the
     backend client is created. Harmless on TPU (the TPU client ignores
-    the CPU knob) and on jax versions predating the option."""
+    the CPU knob)."""
     import jax
-    try:
-        jax.config.update('jax_cpu_collectives_implementation', 'gloo')
-    except Exception:
-        pass                      # pragma: no cover - old jax
+    jax.config.update('jax_cpu_collectives_implementation', 'gloo')
 
 
 def _initialize(timeout_s, **kwargs):
     import jax
-    try:
-        jax.distributed.initialize(
-            initialization_timeout=int(max(1.0, timeout_s)), **kwargs)
-    except TypeError:             # pragma: no cover - old jax signature
-        jax.distributed.initialize(**kwargs)
+    jax.distributed.initialize(
+        initialization_timeout=int(max(1.0, timeout_s)), **kwargs)
 
 
 def _record_info():

@@ -40,7 +40,8 @@ import os
 import sys
 import tempfile
 
-os.environ.setdefault('JAX_PLATFORMS', 'cpu')
+from .. import config as _config  # noqa: E402
+_config.cpu_rig('amp')
 
 SCHEMA = 'mxnet_tpu.amp_selftest.v1'
 

@@ -48,7 +48,8 @@ if '--xla_force_host_platform_device_count' not in _flags:
     os.environ['XLA_FLAGS'] = (
         _flags + ' --xla_force_host_platform_device_count=%s'
         % _n).strip()
-os.environ.setdefault('JAX_PLATFORMS', 'cpu')
+from .. import config as _config  # noqa: E402
+_config.cpu_rig('analysis')
 
 
 # -- selftest fixtures ------------------------------------------------------

@@ -16,7 +16,8 @@ import os
 import threading
 
 __all__ = ['Knob', 'KNOBS', 'get', 'set', 'unset', 'describe',
-           'naive_engine', 'NaiveEngineScope', 'configure_compile_cache']
+           'naive_engine', 'NaiveEngineScope', 'configure_compile_cache',
+           'cpu_rig']
 
 _lock = threading.Lock()
 _values = {}
@@ -129,7 +130,7 @@ KNOBS = {k.name: k for k in [
     # resilience layer (docs/RESILIENCE.md)
     _knob('MXNET_TPU_FAULT', str, None,
           'Scripted fault injection: comma list of kind[@site][:count]'
-          ' (device_unavailable, tunnel_stall, worker_crash, preempt,'
+          ' (device_unavailable, device_stall, worker_crash, preempt,'
           ' hang, device_loss, and the value kinds nan/inf, e.g.'
           ' nan@grads:2 for the guardrail or preempt@train.step.12:1'
           ' to preempt exactly at step 12).'
@@ -218,15 +219,6 @@ KNOBS = {k.name: k for k in [
     _knob('MXNET_TPU_TRACE_BUFFER', int, 4096,
           'Span-buffer capacity per process (records); the oldest'
           ' spans drop when full.'),
-    # persistent compilation cache (docs/SERVING.md; training too)
-    _knob('MXNET_TPU_COMPILE_CACHE', str, None,
-          "Directory for jax's persistent compilation cache. When set"
-          ' (applied at import via configure_compile_cache), every'
-          ' XLA compile — training steps and serving buckets alike —'
-          ' is keyed into this directory and a later process reuses'
-          ' the compiled binary instead of recompiling: restarts and'
-          ' fleet rollouts warm-start. Unset (default) keeps'
-          " compilation in-memory only."),
     # inference serving engine (docs/SERVING.md)
     _knob('MXNET_TPU_SERVE_MAX_BATCH', int, 64,
           'Micro-batcher aggregation cap and the default top of the'
@@ -786,32 +778,51 @@ def describe():
 
 # -- persistent compilation cache -------------------------------------------
 
-_compile_cache_dir = None
+_REPO_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+    '.jax_cache')
 
 
 def configure_compile_cache():
-    """Point jax's persistent compilation cache at the
-    ``MXNET_TPU_COMPILE_CACHE`` directory (no-op when unset).
+    """Make sure jax's persistent compilation cache is on, and return
+    the directory in effect.
 
-    Called once at package import — before any program compiles — so
-    both training steps and serving buckets key their XLA binaries
-    into the directory and a second process warm-starts: it still
-    traces python (cheap) but the expensive backend compile is a disk
-    read. The thresholds are dropped to "cache everything" because a
-    serving ladder is many small programs. Returns the directory in
-    effect, or None.
+    One way to place it: where ``JAX_COMPILATION_CACHE_DIR`` is set jax
+    has already taken the directory from it and nothing is set in code;
+    otherwise the cache lives at one fixed path inside the checkout
+    (``<repo>/.jax_cache`` — the path is part of the cache key, so it
+    must never move between runs). Called once at package import,
+    before any program compiles, so training steps and serving buckets
+    alike warm-start from disk in a later process. jax's own thresholds
+    stay: only programs that took over a second to compile are written,
+    which keeps the thousands of tiny programs a CPU test run builds
+    out of the directory.
     """
-    global _compile_cache_dir
-    cache_dir = get('MXNET_TPU_COMPILE_CACHE')
-    if not cache_dir or cache_dir == _compile_cache_dir:
-        return _compile_cache_dir
     import jax
-    cache_dir = os.path.abspath(cache_dir)
-    jax.config.update('jax_compilation_cache_dir', cache_dir)
-    jax.config.update('jax_persistent_cache_min_entry_size_bytes', -1)
-    jax.config.update('jax_persistent_cache_min_compile_time_secs', 0.0)
-    _compile_cache_dir = cache_dir
-    return cache_dir
+    if not os.environ.get('JAX_COMPILATION_CACHE_DIR'):
+        jax.config.update('jax_compilation_cache_dir', _REPO_CACHE_DIR)
+    return jax.config.jax_compilation_cache_dir
+
+
+# -- selftest platform --------------------------------------------------------
+
+def cpu_rig(name):
+    """First statement of every ``python -m mxnet_tpu.<subsystem>``
+    selftest: they are CPU rigs. Unless ``JAX_PLATFORMS`` is exported,
+    pin jax — and, through the environment, every process the selftest
+    spawns — to the CPU, and say which platform it is in the first
+    output line instead of defaulting silently. (The package import
+    has already imported jax by the time ``__main__`` runs, so the
+    environment variable alone would come too late for this process.)
+    """
+    import jax
+    note = ''
+    if not os.environ.get('JAX_PLATFORMS'):
+        os.environ['JAX_PLATFORMS'] = 'cpu'
+        jax.config.update('jax_platforms', 'cpu')
+        note = ' (CPU rig: JAX_PLATFORMS was not set)'
+    print('%s selftest: JAX_PLATFORMS=%s%s'
+          % (name, os.environ['JAX_PLATFORMS'], note), flush=True)
 
 
 # -- debug mode (NaiveEngine analog) ----------------------------------------

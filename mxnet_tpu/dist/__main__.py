@@ -62,7 +62,8 @@ _flags = os.environ.get('XLA_FLAGS', '')
 if '--xla_force_host_platform_device_count' not in _flags:
     os.environ['XLA_FLAGS'] = (
         _flags + ' --xla_force_host_platform_device_count=2').strip()
-os.environ.setdefault('JAX_PLATFORMS', 'cpu')
+from .. import config as _config  # noqa: E402
+_config.cpu_rig('dist')
 
 _WORKER = [sys.executable, '-m', 'mxnet_tpu.dist._selftest_worker']
 

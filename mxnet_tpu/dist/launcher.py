@@ -31,8 +31,8 @@ import subprocess
 import sys
 import time
 
-__all__ = ['WorkerResult', 'LaunchResult', 'launch_local', 'free_port',
-           'worker_env']
+__all__ = ['WorkerResult', 'LaunchResult', 'LaunchError', 'launch_local',
+           'free_port', 'worker_env']
 
 _RESUMABLE_RC = 75          # mirrors MXNET_TPU_PREEMPT_EXIT_CODE default
 
@@ -51,6 +51,10 @@ def _resumable_rc():
                                   _RESUMABLE_RC))
     except ValueError:
         return _RESUMABLE_RC
+
+
+class LaunchError(RuntimeError):
+    """The requested pod cannot be started on this host as asked."""
 
 
 class WorkerResult:
@@ -152,6 +156,11 @@ def launch_local(num_workers, command, env=None, coordinator_port=None,
     captures each rank's stdout+stderr into ``worker-<rank>.log``.
     ``local_devices`` forces that many virtual CPU devices per worker;
     ``platform`` pins ``JAX_PLATFORMS`` (pass 'cpu' for the Gloo rig).
+    This launcher IS the Gloo/CPU rig: it assigns no accelerator to a
+    worker, and an accelerator belongs to one process, so several
+    workers whose ``JAX_PLATFORMS`` is not ``cpu`` would each claim
+    every chip of the host and fail or hang — that combination raises
+    :class:`LaunchError` before anything is spawned.
     If any worker fails hard (or ``timeout`` seconds elapse), the
     remaining workers are terminated. A worker exiting with the
     resumable rc (75) also ends the pod — a preempted host means the
@@ -159,6 +168,17 @@ def launch_local(num_workers, command, env=None, coordinator_port=None,
     reports 75, not a hard failure.
     """
     port = coordinator_port or free_port()
+    effective = worker_env(0, num_workers, port, env=env,
+                           platform=platform).get('JAX_PLATFORMS')
+    if num_workers > 1 and effective != 'cpu':
+        raise LaunchError(
+            'launch_local(%d workers) with JAX_PLATFORMS=%r: the local '
+            'launcher is the Gloo/CPU rig and gives no worker a chip of '
+            'its own, so every worker would claim every accelerator of '
+            'this host. Pass platform=\'cpu\' (or export '
+            'JAX_PLATFORMS=cpu); multi-process launch on TPU is not '
+            'brought up (docs/DISTRIBUTED.md).'
+            % (num_workers, effective))
     rc75 = _resumable_rc()
     if local_devices is None:
         # knob default (docs/DISTRIBUTED.md): 0 leaves XLA_FLAGS alone

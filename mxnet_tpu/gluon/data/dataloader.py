@@ -21,6 +21,9 @@ cpu_shared_storage_manager.h:52):
 from __future__ import annotations
 
 import multiprocessing
+import multiprocessing.context
+import os
+import threading
 
 import numpy as np
 
@@ -121,6 +124,36 @@ def _as_nd(data):
 
 
 _worker_dataset = None
+
+_spawn_env_lock = threading.Lock()
+
+
+class _CpuSpawnProcess(multiprocessing.context.SpawnProcess):
+    """A spawn worker that starts with ``JAX_PLATFORMS=cpu``.
+
+    An accelerator belongs to one process. A worker re-imports the
+    user's ``__main__`` and this package before it runs anything of
+    ours, so an NDArray touched at module level, in a dataset or in a
+    transform would open the parent's chip from the child and fail or
+    hang. spawn gives the child the parent's environment as it stands
+    at exec time, so the pin is set around ``start()`` — which also
+    covers workers the pool respawns later."""
+
+    def start(self):
+        with _spawn_env_lock:
+            saved = os.environ.get('JAX_PLATFORMS')
+            os.environ['JAX_PLATFORMS'] = 'cpu'
+            try:
+                super().start()
+            finally:
+                if saved is None:
+                    del os.environ['JAX_PLATFORMS']
+                else:
+                    os.environ['JAX_PLATFORMS'] = saved
+
+
+class _CpuSpawnContext(multiprocessing.context.SpawnContext):
+    Process = _CpuSpawnProcess
 
 
 def _worker_initializer(dataset):
@@ -364,7 +397,7 @@ class DataLoader:
                     picklable = True
                 except Exception:
                     picklable = False
-                ctx = multiprocessing.get_context('spawn')
+                ctx = _CpuSpawnContext()
                 if picklable:
                     self._worker_pool = ctx.Pool(
                         self._num_workers,

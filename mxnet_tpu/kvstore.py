@@ -29,7 +29,7 @@ from .resilience.policy import (Retry, RetryExhausted, WorkerCrashError,
 
 __all__ = ['KVStore', 'KVStoreInitError', 'create']
 
-_KV_FAULTS = ('device_unavailable', 'tunnel_stall')
+_KV_FAULTS = ('device_unavailable', 'device_stall')
 # the init handshake additionally honors worker_crash: a worker dying
 # mid-handshake is recoverable by re-running the join from scratch
 # (the restarted-worker rejoin path), unlike a mid-collective death
@@ -75,11 +75,11 @@ def _on_comm_retry(attempt, exc, pause):
 
 def _comm_retry():
     """Backoff policy for dist collectives (init/push/pull): transient
-    tunnel errors get bounded retries; deterministic errors propagate.
+    transport errors get bounded retries; deterministic errors propagate.
 
     Caveat (docs/RESILIENCE.md): a collective retry is only safe when
     every participant fails and retries in lockstep — the common case
-    for a slice-wide tunnel outage, where the error surfaces on all
+    for a slice-wide network outage, where the error surfaces on all
     workers. A partial failure (one worker errors while peers complete)
     cannot be healed by per-process retry; jax collectives give no
     abort-and-rejoin, so that case still ends in the runtime's own
@@ -228,7 +228,7 @@ class KVStore:
 
         def _reduce():
             # scripted-fault hook: lets tests drive the retry path
-            # without a real tunnel outage (docs/RESILIENCE.md)
+            # without a real network outage (docs/RESILIENCE.md)
             inject('kvstore.push', _KV_FAULTS)
             from jax.experimental import multihost_utils
             return multihost_utils.process_allgather(value._data)

@@ -16,10 +16,7 @@ from __future__ import annotations
 
 import argparse
 import json
-import os
 import sys
-
-os.environ.setdefault('JAX_PLATFORMS', 'cpu')
 
 
 def _write(path, doc):

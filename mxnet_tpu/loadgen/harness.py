@@ -17,7 +17,7 @@ the experiment. Three modes:
     admitted tail: admitted p99 within budget, the excess resolving
     as FAST 429s (with Retry-After) rather than slow timeouts.
   * **chaos** — sustained mixed traffic while the FaultInjector
-    scripts device_unavailable bursts, tunnel stalls, a worker crash
+    scripts device_unavailable bursts, device stalls, a worker crash
     and a preemption mid-stream; gate an availability floor, a
     recovery-time ceiling per fault, and the zero-hang invariant
     (every fired request resolves; no slot leaked at drain).
@@ -57,8 +57,8 @@ OVERLOAD_MIX = {'predict': 0.3, 'generate': 0.7}
 CHAOS_SCRIPT = (
     (0.10, 'device_unavailable',
      'device_unavailable@serving:3,device_unavailable@serving.decode:1'),
-    (0.32, 'tunnel_stall',
-     'tunnel_stall@serving:2,tunnel_stall@serving.decode:1'),
+    (0.32, 'device_stall',
+     'device_stall@serving:2,device_stall@serving.decode:1'),
     (0.50, 'worker_crash', 'worker_crash@serving.decode:1'),
     (0.64, 'preempt', 'preempt@serving.decode:1'),
 )

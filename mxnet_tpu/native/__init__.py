@@ -3,18 +3,21 @@
 The reference's IO runtime is C++ (dmlc recordio + src/io/ threaded
 iterators); this package compiles the TPU-native equivalent
 (native/src/recio.cc) with the in-image g++ on first use and binds it
-via ctypes — no pybind11 needed. Everything degrades gracefully to the
-pure-Python paths when the toolchain or build is unavailable
-(``native.available()`` reports which path is live).
+via ctypes — no pybind11 needed. The library is always built from that
+source (``_build_util`` never loads a binary it cannot tie to it);
+when the toolchain or build is unavailable everything degrades to the
+pure-Python paths, with a warning, and ``native.available()`` reports
+which path is live.
 """
 from __future__ import annotations
 
 import ctypes
 import os
-import subprocess
 import threading
 
 import numpy as np
+
+from ._build_util import load_library
 
 __all__ = ['available', 'lib', 'scan_offsets', 'read_batch', 'RecReader']
 
@@ -30,15 +33,6 @@ _BUILD_DIR = os.path.join(os.path.dirname(os.path.abspath(__file__)),
 _SO = os.path.join(_BUILD_DIR, 'librecio.so')
 
 _ABI = 2
-
-
-def _compile():
-    os.makedirs(_BUILD_DIR, exist_ok=True)
-    tmp = '%s.tmp.%d' % (_SO, os.getpid())  # per-process: no build races
-    cmd = ['g++', '-O3', '-std=c++17', '-shared', '-fPIC', '-pthread',
-           _SRC, '-o', tmp]
-    subprocess.run(cmd, check=True, capture_output=True, timeout=120)
-    os.replace(tmp, _SO)
 
 
 def _bind(path):
@@ -77,13 +71,7 @@ def lib():
         if _lib is not None or _tried:
             return _lib
         _tried = True
-        try:
-            if not os.path.exists(_SO) or \
-                    os.path.getmtime(_SO) < os.path.getmtime(_SRC):
-                _compile()
-            _lib = _bind(_SO)
-        except Exception:
-            _lib = None
+        _lib = load_library(_SRC, _SO, _bind, name='librecio')
     return _lib
 
 

@@ -1,10 +1,17 @@
 """Shared compile-on-first-use machinery for the native libraries
 (recio / predict ABI / core C API). One place owns the g++ command,
-the tmp-file + atomic-replace dance, source-mtime staleness, and the
+the tmp-file + atomic-replace dance, provenance, and the
 compile-failure diagnostics, so the per-library loaders can't drift.
+
+Provenance: a built ``.so`` is loaded only when the ``.src`` stamp
+written next to it holds the SHA-256 of the source it is asked to
+stand for. A binary without a matching stamp — copied in from another
+tree, left over from an older source, of unknown origin — is rebuilt,
+never loaded (file times say nothing once a tree has been copied).
 """
 from __future__ import annotations
 
+import hashlib
 import os
 import subprocess
 import sysconfig
@@ -30,17 +37,26 @@ def build_so(src, so_path, link_python=False):
                    % __import__('sys').version_info[:2])
     subprocess.run(cmd, check=True, capture_output=True, timeout=180)
     os.replace(tmp, so_path)
+    with open(so_path + '.src', 'w') as f:
+        f.write(_source_digest(src))
+
+
+def _source_digest(src):
+    with open(src, 'rb') as f:
+        return hashlib.sha256(f.read()).hexdigest()
 
 
 def _stale(src, so_path):
     try:
-        return os.path.getmtime(so_path) < os.path.getmtime(src)
+        with open(so_path + '.src') as f:
+            return not os.path.exists(so_path) \
+                or f.read().strip() != _source_digest(src)
     except OSError:
         return True
 
 
 def load_library(src, so_path, bind, link_python=False, name=None):
-    """Compile (when missing or older than ``src``), then ``bind`` the
+    """Compile (when missing or not built from ``src``), then ``bind`` the
     library. ``bind`` must raise OSError/AttributeError on an
     ABI-stale .so — the loader rebuilds once. Returns the bound
     library or None (with a warning carrying the g++ stderr)."""

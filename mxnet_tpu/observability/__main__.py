@@ -50,7 +50,8 @@ if '--devices' in sys.argv[:-1]:
         os.environ['XLA_FLAGS'] = (
             _flags + ' --xla_force_host_platform_device_count=%s'
             % _n).strip()
-os.environ.setdefault('JAX_PLATFORMS', 'cpu')
+from .. import config as _config  # noqa: E402
+_config.cpu_rig('observability')
 
 
 def check_registry():

@@ -119,12 +119,17 @@ def enabled(kind):
 
 
 def interpret_mode():
-    """Mosaic compilation is TPU-only; everywhere else (cpu tests,
-    gpu jax) the same kernels run through the Pallas interpreter —
-    the NMS precedent, so the kernel logic is exercised on every CI
-    rig."""
+    """False exactly when the computation being traced is placed on a
+    TPU: there a kernel is Mosaic-compiled, never interpreted and never
+    replaced by its reference. Everywhere else (the CPU test rig, and
+    the serving CPU replay that runs under ``jax.default_device(cpu)``
+    while the default backend is still ``tpu``) the same kernel logic
+    runs through the Pallas interpreter."""
     import jax
-    return jax.default_backend() != 'tpu'
+    dev = jax.config.jax_default_device
+    if dev is None:
+        return jax.default_backend() != 'tpu'
+    return getattr(dev, 'platform', dev) != 'tpu'
 
 
 # re-exports: the kernel families — LAZY (module __getattr__), so the

@@ -78,29 +78,55 @@ def _pad_to(x, axis, mult):
     return jnp.pad(x, cfg)
 
 
-def online_softmax_block(scores, v_blk, m, l, o):
-    """One online-softmax accumulation step over a key block.
+def _online_block_cols(scores, v_blk, m, l, o):
+    """One online-softmax accumulation step over a key block, with the
+    per-row carries held as columns.
 
     ``scores``: (..., q, k) float32 with masked entries at exactly
-    -inf; ``v_blk``: (..., k, d) float32; carries ``m`` (..., q) /
-    ``l`` (..., q) / ``o`` (..., q, d). Returns the updated carries.
-    Fully-masked rows stay (m=-inf, l=0, o=0) — the caller divides by
-    max(l, eps). This is the ring-attention body's math verbatim
-    (parallel/ring_attention.py); the ring rotates ``v_blk`` over ICI
-    where this module's kernels walk VMEM blocks.
+    -inf; ``v_blk``: (..., k, d) float32; carries ``m`` / ``l``
+    (..., q, 1) and ``o`` (..., q, d). Fully-masked rows stay
+    (m=-inf, l=0, o=0) — the caller divides by max(l, eps). The kernel
+    bodies call this form directly: Mosaic lays vectors out as
+    (sublane, lane) tiles and refuses most rank-1 shapes, so nothing
+    inside a kernel is ever rank 1 (keepdims reductions throughout).
     """
-    m_new = jnp.maximum(m, scores.max(axis=-1))
+    m_new = jnp.maximum(m, scores.max(axis=-1, keepdims=True))
     safe_m = jnp.where(jnp.isneginf(m_new), 0.0, m_new)
     p = jnp.exp(jnp.where(jnp.isneginf(scores), _NEG_INF,
-                          scores - safe_m[..., None]))
+                          scores - safe_m))
     corr = jnp.exp(jnp.where(jnp.isneginf(m), _NEG_INF, m - safe_m))
     corr = jnp.where(jnp.isneginf(m), 0.0, corr)
-    l_new = l * corr + p.sum(axis=-1)
+    l_new = l * corr + p.sum(axis=-1, keepdims=True)
     batch = tuple(range(p.ndim - 2))
-    o_new = o * corr[..., None] + jax.lax.dot_general(
+    o_new = o * corr + jax.lax.dot_general(
         p, v_blk, (((p.ndim - 1,), (v_blk.ndim - 2,)), (batch, batch)),
         preferred_element_type=jnp.float32)
     return m_new, l_new, o_new
+
+
+def online_softmax_block(scores, v_blk, m, l, o):
+    """:func:`_online_block_cols` with (..., q) carries — the form the
+    ring-attention body uses (parallel/ring_attention.py); the ring
+    rotates ``v_blk`` over ICI where this module's kernels walk VMEM
+    blocks. One expression set for both."""
+    m, l, o = _online_block_cols(scores, v_blk, m[..., None],
+                                 l[..., None], o)
+    return m[..., 0], l[..., 0], o
+
+
+def _k_rows(j):
+    """Key-axis slice of block ``j`` (aligned: lets Mosaic use
+    unmasked sublane loads)."""
+    return pl.ds(pl.multiple_of(j * K_BLOCK, K_BLOCK), K_BLOCK)
+
+
+def _bias_blocks(bias):
+    """(B, Sk_pad) additive key bias -> (B, nk, K_BLOCK): one row per
+    key block, so a kernel picks block ``j`` with a sublane slice and
+    every BlockSpec's last two dims equal the array's (the TPU
+    lowering refuses a (1, Sk) block over a (B, Sk) array)."""
+    b, sk = bias.shape
+    return bias.reshape(b, sk // K_BLOCK, K_BLOCK)
 
 
 # ---------------------------------------------------------------------------
@@ -122,35 +148,32 @@ def mxnet_tpu_flash_attention_fwd(q_ref, k_ref, v_ref, bias_ref,
 
     def body(j, carry):
         m, l, acc = carry
-        kb = k_ref[0, pl.ds(j * K_BLOCK, K_BLOCK), :].astype(
-            jnp.float32)
-        vb = v_ref[0, pl.ds(j * K_BLOCK, K_BLOCK), :].astype(
-            jnp.float32)
+        kb = k_ref[0, _k_rows(j), :].astype(jnp.float32)
+        vb = v_ref[0, _k_rows(j), :].astype(jnp.float32)
         s = jax.lax.dot_general(qb, kb, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)
-        s = s + bias_ref[0, pl.ds(j * K_BLOCK, K_BLOCK)][None, :]
+        s = s + bias_ref[0, pl.ds(j, 1), :]
         if causal:
             k_pos = j * K_BLOCK + jax.lax.broadcasted_iota(
                 jnp.int32, (bq, K_BLOCK), 1)
             s = jnp.where(q_pos >= k_pos, s, _NEG_INF)
-        return online_softmax_block(s, vb, m, l, acc)
+        return _online_block_cols(s, vb, m, l, acc)
 
-    m0 = jnp.full((bq,), _NEG_INF, jnp.float32)
-    l0 = jnp.zeros((bq,), jnp.float32)
+    m0 = jnp.full((bq, 1), _NEG_INF, jnp.float32)
+    l0 = jnp.zeros((bq, 1), jnp.float32)
     a0 = jnp.zeros((bq, d), jnp.float32)
     m, l, acc = jax.lax.fori_loop(0, nk, body, (m0, l0, a0))
-    o_ref[0] = (acc / jnp.maximum(l, 1e-20)[:, None]).astype(
-        o_ref.dtype)
+    o_ref[0] = (acc / jnp.maximum(l, 1e-20)).astype(o_ref.dtype)
     safe_m = jnp.where(jnp.isneginf(m), 0.0, m)
-    lse_ref[0, :] = jnp.where(l > 0,
-                              safe_m + jnp.log(jnp.maximum(l, 1e-20)),
-                              _NEG_INF)
+    lse_ref[0] = jnp.where(l > 0,
+                           safe_m + jnp.log(jnp.maximum(l, 1e-20)),
+                           _NEG_INF)
 
 
 def _fwd_call(q3, k3, v3, bias, *, heads, causal, scale, interpret):
-    """q3/k3/v3: (B*H, S*, D) padded; bias: (B, Sk_pad) f32 additive
-    (-inf = blocked key). Returns (out (B*H, Sq_pad, D), lse
-    (B*H, Sq_pad) f32)."""
+    """q3/k3/v3: (B*H, S*, D) padded; bias: (B, nk, K_BLOCK) f32
+    additive (-inf = blocked key). Returns (out (B*H, Sq_pad, D), lse
+    (B*H, Sq_pad, 1) f32 — a column per row, like the carries)."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
     bh, sq, d = q3.shape
@@ -170,18 +193,18 @@ def _fwd_call(q3, k3, v3, bias, *, heads, causal, scale, interpret):
                          memory_space=pltpu.VMEM),
             pl.BlockSpec((1, sk, d), lambda b, i: (b, 0, 0),
                          memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, sk), lambda b, i: (b // h, 0),
+            pl.BlockSpec((1, nk, K_BLOCK), lambda b, i: (b // h, 0, 0),
                          memory_space=pltpu.VMEM),
         ],
         out_specs=[
             pl.BlockSpec((1, bq, d), lambda b, i: (b, i, 0),
                          memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, bq), lambda b, i: (b, i),
+            pl.BlockSpec((1, bq, 1), lambda b, i: (b, i, 0),
                          memory_space=pltpu.VMEM),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((bh, sq, d), q3.dtype),
-            jax.ShapeDtypeStruct((bh, sq), jnp.float32),
+            jax.ShapeDtypeStruct((bh, sq, 1), jnp.float32),
         ],
         interpret=interpret,
     )(q3, k3, v3, bias)
@@ -194,15 +217,16 @@ def _fwd_call(q3, k3, v3, bias, *, heads, causal, scale, interpret):
 
 def _p_block(qb, kb, bias_blk, lse, q_pos, k_pos, causal, scale):
     """Recompute one probability block p = exp(s - lse) with masked
-    and fully-masked entries at exactly 0."""
+    and fully-masked entries at exactly 0. ``bias_blk`` is a (1, K)
+    row, ``lse`` a (Q, 1) column."""
     s = jax.lax.dot_general(qb, kb, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32) * scale
-    s = s + bias_blk[None, :]
+    s = s + bias_blk
     if causal:
         s = jnp.where(q_pos >= k_pos, s, _NEG_INF)
-    dead = jnp.isneginf(s) | jnp.isneginf(lse)[:, None]
+    dead = jnp.isneginf(s) | jnp.isneginf(lse)
     return jnp.where(dead, 0.0, jnp.exp(s - jnp.where(
-        jnp.isneginf(lse), 0.0, lse)[:, None])), s
+        jnp.isneginf(lse), 0.0, lse))), s
 
 
 def mxnet_tpu_flash_attention_dq(q_ref, k_ref, v_ref, bias_ref,
@@ -212,26 +236,24 @@ def mxnet_tpu_flash_attention_dq(q_ref, k_ref, v_ref, bias_ref,
     qb = q_ref[0].astype(jnp.float32)
     dob = do_ref[0].astype(jnp.float32)
     ob = o_ref[0].astype(jnp.float32)
-    lse = lse_ref[0]
+    lse = lse_ref[0]                                    # (BQ, 1)
     bq, d = qb.shape
     qi = pl.program_id(1)
     q_pos = qi * bq + jax.lax.broadcasted_iota(
         jnp.int32, (bq, K_BLOCK), 0)
-    delta = jnp.sum(dob * ob, axis=-1)                  # (BQ,)
+    delta = jnp.sum(dob * ob, axis=-1, keepdims=True)   # (BQ, 1)
 
     def body(j, dq):
-        kb = k_ref[0, pl.ds(j * K_BLOCK, K_BLOCK), :].astype(
-            jnp.float32)
-        vb = v_ref[0, pl.ds(j * K_BLOCK, K_BLOCK), :].astype(
-            jnp.float32)
+        kb = k_ref[0, _k_rows(j), :].astype(jnp.float32)
+        vb = v_ref[0, _k_rows(j), :].astype(jnp.float32)
         k_pos = j * K_BLOCK + jax.lax.broadcasted_iota(
             jnp.int32, (bq, K_BLOCK), 1)
-        bias_blk = bias_ref[0, pl.ds(j * K_BLOCK, K_BLOCK)]
+        bias_blk = bias_ref[0, pl.ds(j, 1), :]
         p, _ = _p_block(qb, kb, bias_blk, lse, q_pos, k_pos, causal,
                         scale)
         dp = jax.lax.dot_general(dob, vb, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)
-        ds = p * (dp - delta[:, None]) * scale
+        ds = p * (dp - delta) * scale
         return dq + jax.lax.dot_general(
             ds, kb, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
@@ -249,16 +271,17 @@ def mxnet_tpu_flash_attention_dkv(q_ref, k_ref, v_ref, bias_ref,
     vb = v_ref[0].astype(jnp.float32)
     bk, d = kb.shape
     kj = pl.program_id(1)
-    bias_blk = bias_ref[0]                              # (BK,)
+    bias_blk = bias_ref[0, pl.ds(kj, 1), :]             # (1, BK)
     k_pos = kj * bk + jax.lax.broadcasted_iota(
         jnp.int32, (bq, bk), 1)
 
     def body(i, carry):
         dk, dv = carry
-        qb = q_ref[0, pl.ds(i * bq, bq), :].astype(jnp.float32)
-        dob = do_ref[0, pl.ds(i * bq, bq), :].astype(jnp.float32)
-        ob = o_ref[0, pl.ds(i * bq, bq), :].astype(jnp.float32)
-        lse = lse_ref[0, pl.ds(i * bq, bq)]
+        rows = pl.ds(pl.multiple_of(i * bq, bq), bq)
+        qb = q_ref[0, rows, :].astype(jnp.float32)
+        dob = do_ref[0, rows, :].astype(jnp.float32)
+        ob = o_ref[0, rows, :].astype(jnp.float32)
+        lse = lse_ref[0, rows, :]                       # (BQ, 1)
         q_pos = i * bq + jax.lax.broadcasted_iota(
             jnp.int32, (bq, bk), 0)
         p, _ = _p_block(qb, kb, bias_blk, lse, q_pos, k_pos, causal,
@@ -268,8 +291,8 @@ def mxnet_tpu_flash_attention_dkv(q_ref, k_ref, v_ref, bias_ref,
             preferred_element_type=jnp.float32)
         dp = jax.lax.dot_general(dob, vb, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)
-        delta = jnp.sum(dob * ob, axis=-1)
-        ds = p * (dp - delta[:, None]) * scale
+        delta = jnp.sum(dob * ob, axis=-1, keepdims=True)
+        ds = p * (dp - delta) * scale
         dk = dk + jax.lax.dot_general(
             ds, qb, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
@@ -298,16 +321,19 @@ def _bwd_call(q3, k3, v3, bias, o3, lse, do3, *, heads, causal, scale,
                           memory_space=pltpu.VMEM)
     k_spec = pl.BlockSpec((1, K_BLOCK, d), lambda b, j: (b, j, 0),
                           memory_space=pltpu.VMEM)
+    # the whole (nk, K_BLOCK) bias of the program's batch row; the dkv
+    # kernel picks its key block's row by program id
+    bias_spec = pl.BlockSpec((1, nk, K_BLOCK),
+                             lambda b, i: (b // h, 0, 0),
+                             memory_space=pltpu.VMEM)
     dq = pl.pallas_call(
         functools.partial(mxnet_tpu_flash_attention_dq, nk=nk,
                           scale=scale, causal=causal, heads=heads),
         grid=(bh, nq),
         in_specs=[
-            q_spec, k_full, k_full,
-            pl.BlockSpec((1, sk), lambda b, i: (b // h, 0),
-                         memory_space=pltpu.VMEM),
+            q_spec, k_full, k_full, bias_spec,
             q_spec,
-            pl.BlockSpec((1, bq), lambda b, i: (b, i),
+            pl.BlockSpec((1, bq, 1), lambda b, i: (b, i, 0),
                          memory_space=pltpu.VMEM),
             q_spec,
         ],
@@ -320,11 +346,9 @@ def _bwd_call(q3, k3, v3, bias, o3, lse, do3, *, heads, causal, scale,
                           scale=scale, causal=causal, heads=heads),
         grid=(bh, nk),
         in_specs=[
-            q_full, k_spec, k_spec,
-            pl.BlockSpec((1, K_BLOCK), lambda b, j: (b // h, j),
-                         memory_space=pltpu.VMEM),
+            q_full, k_spec, k_spec, bias_spec,
             q_full,
-            pl.BlockSpec((1, sq), lambda b, j: (b, 0),
+            pl.BlockSpec((1, sq, 1), lambda b, j: (b, 0, 0),
                          memory_space=pltpu.VMEM),
             q_full,
         ],
@@ -343,8 +367,8 @@ def _bwd_call(q3, k3, v3, bias, o3, lse, do3, *, heads, causal, scale,
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
 def _flash_core(q, k, v, bias, causal, scale, interpret):
-    """q/k/v: (B, H, Sq_pad, D) / (B, H, Sk_pad, D); bias (B, Sk_pad)
-    f32 additive with -inf on blocked keys."""
+    """q/k/v: (B, H, Sq_pad, D) / (B, H, Sk_pad, D); bias
+    (B, nk, K_BLOCK) f32 additive with -inf on blocked keys."""
     out, _ = _flash_fwd_impl(q, k, v, bias, causal, scale, interpret)
     return out
 
@@ -408,8 +432,8 @@ def flash_attention(q, k, v, lengths=None, causal=False, scale=None):
             jnp.asarray(lengths), (-1, 1))) & (k_pos[None, :] < sk)
     valid = jnp.broadcast_to(valid, (b, sk_pad))
     bias = jnp.where(valid, 0.0, _NEG_INF).astype(jnp.float32)
-    out = _flash_core(qp, kp, vp, bias, bool(causal), float(scale),
-                      interpret_mode())
+    out = _flash_core(qp, kp, vp, _bias_blocks(bias), bool(causal),
+                      float(scale), interpret_mode())
     return out[:, :, :sq, :]
 
 
@@ -429,30 +453,27 @@ def mxnet_tpu_flash_decode_fwd(q_ref, k_ref, v_ref, bias_ref, o_ref,
     real keys is identical (the decode bit-identity contract)."""
     u = q_ref.shape[-1]
     d = u // heads
-    q = q_ref[0].astype(jnp.float32) * scale            # (8, U)
 
-    outs = []
     for h in range(heads):
-        qh = q[:, h * d:(h + 1) * d]                    # (8, D)
+        lanes = slice(h * d, (h + 1) * d)
+        qh = q_ref[0, :, lanes].astype(jnp.float32) * scale   # (8, D)
 
-        def body(j, carry, qh=qh, h=h):
+        def body(j, carry, qh=qh, lanes=lanes):
             m, l, acc = carry
-            kb = k_ref[0, pl.ds(j * K_BLOCK, K_BLOCK),
-                       h * d:(h + 1) * d].astype(jnp.float32)
-            vb = v_ref[0, pl.ds(j * K_BLOCK, K_BLOCK),
-                       h * d:(h + 1) * d].astype(jnp.float32)
+            kb = k_ref[0, _k_rows(j), lanes].astype(jnp.float32)
+            vb = v_ref[0, _k_rows(j), lanes].astype(jnp.float32)
             s = jax.lax.dot_general(
                 qh, kb, (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32)
-            s = s + bias_ref[0, pl.ds(j * K_BLOCK, K_BLOCK)][None, :]
-            return online_softmax_block(s, vb, m, l, acc)
+            s = s + bias_ref[0, pl.ds(j, 1), :]
+            return _online_block_cols(s, vb, m, l, acc)
 
-        m0 = jnp.full((8,), _NEG_INF, jnp.float32)
-        l0 = jnp.zeros((8,), jnp.float32)
+        m0 = jnp.full((8, 1), _NEG_INF, jnp.float32)
+        l0 = jnp.zeros((8, 1), jnp.float32)
         a0 = jnp.zeros((8, d), jnp.float32)
         m, l, acc = jax.lax.fori_loop(0, nk, body, (m0, l0, a0))
-        outs.append(acc / jnp.maximum(l, 1e-20)[:, None])
-    o_ref[0] = jnp.concatenate(outs, axis=-1).astype(o_ref.dtype)
+        o_ref[0, :, lanes] = (acc / jnp.maximum(l, 1e-20)).astype(
+            o_ref.dtype)
 
 
 def flash_decode_attention(q, keys, values, positions, heads,
@@ -477,7 +498,8 @@ def flash_decode_attention(q, keys, values, positions, heads,
     k_pos = jnp.arange(lp)
     valid = (k_pos[None, :] <= positions[:, None]) & \
         (k_pos[None, :] < max_len)
-    bias = jnp.where(valid, 0.0, _NEG_INF).astype(jnp.float32)
+    bias = _bias_blocks(
+        jnp.where(valid, 0.0, _NEG_INF).astype(jnp.float32))
     # pad the single query row to the f32 sublane tile (8)
     q8 = jnp.pad(q[:, None, :], ((0, 0), (0, 7), (0, 0)))
     from jax.experimental import pallas as pl_mod
@@ -494,7 +516,7 @@ def flash_decode_attention(q, keys, values, positions, heads,
                              memory_space=pltpu.VMEM),
             pl_mod.BlockSpec((1, lp, u), lambda s: (s, 0, 0),
                              memory_space=pltpu.VMEM),
-            pl_mod.BlockSpec((1, lp), lambda s: (s, 0),
+            pl_mod.BlockSpec((1, nk, K_BLOCK), lambda s: (s, 0, 0),
                              memory_space=pltpu.VMEM),
         ],
         out_specs=pl_mod.BlockSpec((1, 8, u), lambda s: (s, 0, 0),

@@ -36,10 +36,38 @@ import jax.numpy as jnp
 __all__ = ['fused_bn_apply', 'fused_act', 'fused_add_act']
 
 _ROW_BLOCK = 256
+# elements of one (row-block, col-block) tile: 2 MiB in float32. Mosaic
+# double-buffers every operand and the kernels compute in float32, so a
+# tile this size keeps the whole pipeline well inside the 16 MiB scoped
+# VMEM limit (a (256, 12544) bf16 tile — ResNet-50's first BatchNorm at
+# batch 128 — needs 24.5 MiB and is refused on a v5e).
+_TILE_ELEMS = 512 * 1024
 
 
 def _cdiv(a, b):
     return -(-a // b)
+
+
+def _row_block(rows, cols):
+    """Rows per tile: ``_ROW_BLOCK``, shrunk (to a multiple of 16, the
+    bf16 sublane tile) when a row is so wide and unsplittable that 256
+    of them overflow the tile budget."""
+    br = min(_ROW_BLOCK, rows)
+    if cols % 128 and br * cols > _TILE_ELEMS:
+        br = max(16, (_TILE_ELEMS // cols) // 16 * 16)
+    return br
+
+
+def _col_block(cols, br):
+    """Widest column block that keeps a ``br``-row tile within
+    ``_TILE_ELEMS``: the whole row when it fits or cannot be split on
+    the 128-lane tile, else the largest divisor of ``cols`` that is a
+    multiple of 128."""
+    if br * cols <= _TILE_ELEMS or cols % 128:
+        return cols
+    lanes = cols // 128
+    return 128 * max(k for k in range(1, lanes + 1)
+                     if lanes % k == 0 and br * k * 128 <= _TILE_ELEMS)
 
 
 def _act_apply(x, act_type, slope):
@@ -86,28 +114,33 @@ def _act_grad_from_out(out, act_type, slope):
 
 
 def _rows_call(kernel, outs, interpret, *arrays):
-    """Grid a row-blocked elementwise kernel over 2-D operands. Every
-    operand is (R, C) or (R, 1); outputs follow ``outs`` (list of
-    (cols, dtype))."""
+    """Grid a tiled elementwise kernel over 2-D operands. Every
+    operand is (R, C) or an (R, 1) per-row coefficient column; outputs
+    follow ``outs`` (list of (cols, dtype)). R must already be padded
+    to a multiple of ``_row_block(R, C)``."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
-    r = arrays[0].shape[0]
-    br = min(_ROW_BLOCK, r)
-    specs = [pl.BlockSpec((br, a.shape[1]), lambda i: (i, 0),
-                          memory_space=pltpu.VMEM) for a in arrays]
-    out_specs = [pl.BlockSpec((br, c), lambda i: (i, 0),
-                              memory_space=pltpu.VMEM)
-                 for c, _ in outs]
-    out_shape = [jax.ShapeDtypeStruct((r, c), dt) for c, dt in outs]
+    r, c = arrays[0].shape
+    br = _row_block(r, c)
+    bc = _col_block(c, br)
+
+    def spec(cols):
+        if cols == 1:   # per-row coefficient: the same for every column tile
+            return pl.BlockSpec((br, 1), lambda i, j: (i, 0),
+                                memory_space=pltpu.VMEM)
+        return pl.BlockSpec((br, bc), lambda i, j: (i, j),
+                            memory_space=pltpu.VMEM)
+
+    out_shape = [jax.ShapeDtypeStruct((r, cols), dt) for cols, dt in outs]
+    out_specs = [spec(cols) for cols, _ in outs]
     single = len(outs) == 1
-    res = pl.pallas_call(
-        kernel, grid=(r // br,),
-        in_specs=specs,
+    return pl.pallas_call(
+        kernel, grid=(r // br, c // bc),
+        in_specs=[spec(a.shape[1]) for a in arrays],
         out_specs=out_specs[0] if single else out_specs,
         out_shape=out_shape[0] if single else out_shape,
         interpret=interpret,
     )(*arrays)
-    return res
 
 
 def _pad_rows(x, br):
@@ -212,7 +245,7 @@ def fused_bn_apply(x, scale, mean, beta, axis=1, act_type=None,
         return jnp.broadcast_to(v32.reshape(1, c, 1),
                                 (xt.shape[0], c, 1)).reshape(-1, 1)
 
-    br = min(_ROW_BLOCK, rows)
+    br = _row_block(rows, x2.shape[1])
     x2p, r = _pad_rows(x2, br)
     cols = [_pad_rows(col(v), br)[0] for v in (scale, mean, beta)]
     out = _bn_apply_core(x2p, cols[0], cols[1], cols[2], act_type,
@@ -281,9 +314,8 @@ def fused_act(x, act_type, slope=0.0):
     the kernelized twin of ``ops/nn.py`` ``_act_core`` (same forward
     expressions, same output-only residual)."""
     from . import interpret_mode
-    br = _ROW_BLOCK
     x2, n = _flat2d(x)
-    x2p, r = _pad_rows(x2, min(br, x2.shape[0]))
+    x2p, r = _pad_rows(x2, _row_block(*x2.shape))
     out = _act_kernel_core(x2p, act_type, float(slope),
                            interpret_mode())[:r]
     return out.reshape(-1)[:n].reshape(x.shape)
@@ -329,7 +361,7 @@ def fused_add_act(x, y, act_type='relu', slope=0.0):
     from . import interpret_mode
     x2, n = _flat2d(x)
     y2, _ = _flat2d(y)
-    br = min(_ROW_BLOCK, x2.shape[0])
+    br = _row_block(*x2.shape)
     x2p, r = _pad_rows(x2, br)
     y2p, _ = _pad_rows(y2, br)
     out = _add_act_core(x2p, y2p, act_type, float(slope),

@@ -71,16 +71,7 @@ def local_mesh(n_devices=None, axes=None):
 
 
 def shard_map_compat(fn, mesh, in_specs, out_specs):
-    """shard_map with per-output replication checking off, across jax
-    versions (new: check_vma; old: check_rep; older: jax.experimental).
-    One spelling for every parallel module."""
-    try:
-        from jax import shard_map
-    except ImportError:                    # pragma: no cover - old jax
-        from jax.experimental.shard_map import shard_map
-    try:
-        return shard_map(fn, mesh=mesh, in_specs=in_specs,
+    """shard_map with per-output replication checking off. One spelling
+    for every parallel module."""
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
                          out_specs=out_specs, check_vma=False)
-    except TypeError:                      # pragma: no cover - old jax
-        return shard_map(fn, mesh=mesh, in_specs=in_specs,
-                         out_specs=out_specs, check_rep=False)

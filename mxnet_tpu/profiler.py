@@ -155,10 +155,8 @@ class op_span:
     """Tiny timing guard used by the dispatch hot paths: no-op when the
     profiler is idle; otherwise times the block, calling ``sync`` (a
     device fence) before the stop stamp so the span covers execution,
-    not just async dispatch. On locally attached backends
-    block_until_ready is a true fence; on tunneled PJRT backends spans
-    still under-report device time (see wait_to_read docs) — the
-    XPlane trace is the ground truth there."""
+    not just async dispatch (block_until_ready is a true fence). The
+    XPlane trace remains the ground truth for device time."""
 
     __slots__ = ('name', 'sync', '_t0')
 

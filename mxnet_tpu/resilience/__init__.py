@@ -24,7 +24,7 @@ explicit and composable (docs/RESILIENCE.md):
                       exit code (75 = EX_TEMPFAIL).
   * ``watchdog``    — per-phase stall budgets for compiled steps /
                       collectives; structured ``mxnet_tpu.stall.v1``
-                      artifact + TunnelStallError escalation.
+                      artifact + DeviceStallError escalation.
   * ``elastic``     — mesh-shrink resume: re-place checkpointed
                       logical state on fewer devices, preserving the
                       global batch via gradient accumulation.
@@ -39,7 +39,7 @@ from .policy import (Retry, Timeout, Deadline, CircuitBreaker,
                      FaultInjector, get_injector, inject,
                      ResilienceError, RetryExhausted, TimeoutExpired,
                      CircuitOpenError, InjectedFault,
-                     DeviceUnavailableError, TunnelStallError,
+                     DeviceUnavailableError, DeviceStallError,
                      WorkerCrashError, PreemptionSignal, HangError,
                      DeviceLossError, is_transient)
 from .device import BackendStatus, acquire_backend
@@ -57,7 +57,7 @@ __all__ = [
     'Retry', 'Timeout', 'Deadline', 'CircuitBreaker', 'FaultInjector',
     'get_injector', 'inject', 'ResilienceError', 'RetryExhausted',
     'TimeoutExpired', 'CircuitOpenError', 'InjectedFault',
-    'DeviceUnavailableError', 'TunnelStallError', 'WorkerCrashError',
+    'DeviceUnavailableError', 'DeviceStallError', 'WorkerCrashError',
     'PreemptionSignal', 'HangError', 'DeviceLossError',
     'is_transient', 'BackendStatus', 'acquire_backend',
     'atomic_write_bytes', 'atomic_replace', 'save_state', 'load_state',

@@ -54,7 +54,8 @@ if '--devices' in sys.argv[:-1]:
         os.environ['XLA_FLAGS'] = (
             _flags + ' --xla_force_host_platform_device_count=%s'
             % _n).strip()
-os.environ.setdefault('JAX_PLATFORMS', 'cpu')
+from .. import config as _config  # noqa: E402
+_config.cpu_rig('resilience')
 
 FEATURES = 16
 CLASSES = 4
@@ -191,7 +192,7 @@ def run_train(args):
 
 def run_watchdog_smoke(args):
     from mxnet_tpu import nd, parallel
-    from . import TunnelStallError, Watchdog
+    from . import DeviceStallError, Watchdog
 
     _configure_flight(args)
     mesh = parallel.create_mesh()      # whatever devices exist
@@ -206,7 +207,7 @@ def run_watchdog_smoke(args):
         for step in range(args.steps):
             x, y = _batch(step, args.batch)
             pt.step(nd.array(x), nd.array(y))
-    except TunnelStallError as exc:
+    except DeviceStallError as exc:
         detected = {'step': pt.num_update - 1, 'error': str(exc)}
     record = watchdog.last_record or {}
     artifact_ok = os.path.exists(args.stall_artifact)

@@ -2,9 +2,8 @@
 
 Every instrument run — healthy, degraded, or facing a dead backend —
 produces the SAME JSON shape and exits 0, so snapshot automation
-records a data point instead of a traceback (the BENCH_r05 failure
-mode). Only a non-transient error (a real bug) propagates with a
-non-zero exit.
+records a data point instead of a traceback. Only a non-transient
+error (a real bug, a refused compile) propagates with a non-zero exit.
 
 Artifact schema (docs/RESILIENCE.md):
 

@@ -14,7 +14,7 @@ event instead:
     a breach writes the structured stall artifact
     (``mxnet_tpu.stall.v1``: phase, step, waited/budget seconds, and a
     stack dump of every live thread) and raises
-    :class:`~.policy.TunnelStallError` — which ``is_transient`` and
+    :class:`~.policy.DeviceStallError` — which ``is_transient`` and
     therefore flows into the existing degraded-mode path
     (bench/instrument artifacts record ``status: degraded`` and exit 0
     instead of hanging until an opaque external kill);
@@ -46,7 +46,7 @@ import threading
 import time
 import traceback
 
-from .policy import HangError, TunnelStallError, inject
+from .policy import HangError, DeviceStallError, inject
 
 __all__ = ['STALL_SCHEMA', 'Watchdog', 'stall_record']
 
@@ -205,7 +205,7 @@ class Watchdog:
         return waited, budget, phase, step
 
     def check(self):
-        """Raise :class:`TunnelStallError` (after writing the stall
+        """Raise :class:`DeviceStallError` (after writing the stall
         artifact) when the current phase overran its budget; no-op
         otherwise. Drivers call this right after the blocking call a
         :meth:`beat` preceded."""
@@ -214,8 +214,8 @@ class Watchdog:
             return
         waited, budget, phase, step = hit
         self._emit(waited, budget, phase, step)
-        raise TunnelStallError(
-            'tunnel_stall', 'watchdog',
+        raise DeviceStallError(
+            'device_stall', 'watchdog',
             'watchdog: %s phase stalled %.1fs (budget %.1fs) at step '
             '%s — stall artifact at %s'
             % (phase, waited, budget, step, self.artifact_path))

@@ -78,7 +78,8 @@ import tempfile
 import threading
 import time
 
-os.environ.setdefault('JAX_PLATFORMS', 'cpu')
+from .. import config as _config  # noqa: E402
+_config.cpu_rig('serving')
 
 import numpy as onp  # noqa: E402
 

@@ -50,7 +50,8 @@ import os
 import sys
 import tempfile
 
-os.environ.setdefault('JAX_PLATFORMS', 'cpu')
+from ... import config as _config  # noqa: E402
+_config.cpu_rig('serving.adapters')
 
 import numpy as onp  # noqa: E402
 

@@ -817,7 +817,7 @@ class DecodeEngine:
     def _execute(self, fn, step, *args, **kwargs):
         from ...resilience.policy import inject
         inject('serving.decode',
-               ('device_loss', 'device_unavailable', 'tunnel_stall',
+               ('device_loss', 'device_unavailable', 'device_stall',
                 'worker_crash', 'preempt'), step=step)
         if self._watchdog is not None:
             self._watchdog.check()

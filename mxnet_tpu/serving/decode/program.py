@@ -651,7 +651,7 @@ class DecodeProgram:
         import jax
         with open(os.path.join(path, 'MANIFEST.json')) as f:
             manifest = json.load(f)
-        from ..freeze import FROZEN_SCHEMA
+        from ..freeze import FROZEN_SCHEMA, load_executable
         if manifest.get('schema') != FROZEN_SCHEMA or \
                 manifest.get('kind') != _DECODE_KIND:
             raise ValueError(
@@ -692,12 +692,8 @@ class DecodeProgram:
                 prog.retraced_buckets.append(key)
                 continue
             try:
-                from jax.experimental import serialize_executable
-                with open(os.path.join(path, fname), 'rb') as f:
-                    ser, in_tree, out_tree = pickle.load(f)
-                prog._loaded[key] = \
-                    serialize_executable.deserialize_and_load(
-                        ser, in_tree, out_tree)
+                prog._loaded[key] = load_executable(
+                    os.path.join(path, fname), prog._params)
             except Exception:
                 prog.retraced_buckets.append(key)
         return prog

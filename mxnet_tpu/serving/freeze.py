@@ -25,9 +25,9 @@ traces the python function, so the selftest can PROVE a reloaded
 artifact served without tracing (``python -m mxnet_tpu.serving``).
 When executable deserialization is impossible (different jax version
 or platform), load falls back to re-jit per bucket — correct, just
-cold — and records which buckets retraced; the
-``MXNET_TPU_COMPILE_CACHE`` persistent jit cache (config.py) still
-skips the XLA compile in that case.
+cold — and records which buckets retraced; jax's persistent
+compilation cache (``config.configure_compile_cache``) still skips the
+XLA compile in that case.
 """
 from __future__ import annotations
 
@@ -49,6 +49,20 @@ def _as_numpy(arr):
     if hasattr(arr, 'asnumpy'):
         return arr.asnumpy()
     return onp.asarray(arr)
+
+
+def load_executable(path, params):
+    """Deserialize one AOT executable onto the device ``params`` live
+    on — the device it was compiled for. (Left to its default, jax
+    loads it across every device of the backend, and a one-device
+    program then refuses its arguments on a multi-device host.)"""
+    from jax.experimental import serialize_executable
+    with open(path, 'rb') as f:
+        ser, in_tree, out_tree = pickle.load(f)
+    devices = sorted(next(iter(params.values())).devices(),
+                     key=lambda d: d.id)
+    return serialize_executable.deserialize_and_load(
+        ser, in_tree, out_tree, execution_devices=devices)
 
 
 class FrozenProgram:
@@ -366,12 +380,8 @@ class FrozenProgram:
                 prog.retraced_buckets.append(bucket)
                 continue
             try:
-                from jax.experimental import serialize_executable
-                with open(os.path.join(path, fname), 'rb') as f:
-                    ser, in_tree, out_tree = pickle.load(f)
-                prog._loaded[bucket] = \
-                    serialize_executable.deserialize_and_load(
-                        ser, in_tree, out_tree)
+                prog._loaded[bucket] = load_executable(
+                    os.path.join(path, fname), prog._params)
             except Exception:
                 prog.retraced_buckets.append(bucket)
         return prog

@@ -15,7 +15,7 @@ or deadline) -> pad to bucket -> AOT executable -> unpad -> future.
 Failure path (docs/RESILIENCE.md, threaded through rather than bolted
 on): every device-side batch runs under the circuit breaker; a
 transient failure — injected ``hang@serving.infer`` (stall watchdog
-artifact + ``TunnelStallError``), injected ``device_loss@serving``, or
+artifact + ``DeviceStallError``), injected ``device_loss@serving``, or
 a real backend error — counts a breaker failure and the batch is
 re-served on the CPU fallback path, so requests complete degraded
 instead of erroring. When the breaker opens, batches skip the dead
@@ -310,12 +310,12 @@ class InferenceSession:
     def _execute_accel(self, stacked, n, seq):
         from ..resilience.policy import inject
         inject('serving',
-               ('device_loss', 'device_unavailable', 'tunnel_stall',
+               ('device_loss', 'device_unavailable', 'device_stall',
                 'worker_crash', 'preempt'), step=seq)
         if self._watchdog is not None:
             # an injected hang@serving.infer aged the heartbeat at
             # beat(); check() now writes the stall artifact + flight
-            # dump and raises TunnelStallError into the breaker
+            # dump and raises DeviceStallError into the breaker
             self._watchdog.check()
         return self.frozen.run(stacked, n)
 

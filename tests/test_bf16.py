@@ -2,8 +2,7 @@
 fp16 training in tests/python/train/test_dtype.py).
 
 Round-1 regression: cotangents crossing TapeNode boundaries in the loss's
-promoted dtype (f32) broke conv/dense backward under net.cast('bfloat16')
-— BENCH_r01.json rc=1 was exactly this.
+promoted dtype (f32) broke conv/dense backward under net.cast('bfloat16').
 """
 import numpy as np
 import pytest

@@ -16,7 +16,7 @@ from mxnet_tpu.gluon import nn
 from mxnet_tpu.resilience import (
     CheckpointManager, DeviceLossError, ElasticPlan, FaultInjector,
     MeshShrinkError, Preempted, PreemptionHandler, PreemptionSignal,
-    STALL_SCHEMA, TunnelStallError, Watchdog, available_devices,
+    STALL_SCHEMA, DeviceStallError, Watchdog, available_devices,
     mesh_meta, resumable_exit_code, shrink_plan)
 
 
@@ -107,7 +107,7 @@ def test_watchdog_budget_math_and_artifact(tmp_path):
     wd.check()                       # inside budget: no-op
     wd.beat(1)
     clock.sleep(11.0)
-    with pytest.raises(TunnelStallError) as ei:
+    with pytest.raises(DeviceStallError) as ei:
         wd.check()
     assert 'stalled' in str(ei.value)
     import json

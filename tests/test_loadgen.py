@@ -227,13 +227,13 @@ def test_chaos_single_burst_recovers_and_zero_hang(rig):
 
 @pytest.mark.slow
 def test_chaos_full_script_soak(rig):
-    """The full scripted soak (device_unavailable burst, tunnel
+    """The full scripted soak (device_unavailable burst, device
     stall, worker crash, preemption mid-stream) at sustained rate:
     every verdict the slo CI stage gates must hold."""
     from mxnet_tpu.loadgen.harness import run_chaos
     doc = run_chaos(rig, qps=20.0, duration_s=12.0, seed=1)
     kinds = [f['kind'] for f in doc['faults']]
-    assert kinds == ['device_unavailable', 'tunnel_stall',
+    assert kinds == ['device_unavailable', 'device_stall',
                      'worker_crash', 'preempt']
     assert all(f['consumed'] for f in doc['faults'])
     assert doc['verdicts']['all_faults_recovered'], doc['faults']

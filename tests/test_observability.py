@@ -466,7 +466,7 @@ def test_profiler_dumps_sort_options():
     profiler.set_state('run')
     try:
         import time
-        for name, dur, reps in (('slow_op', 0.004, 1),
+        for name, dur, reps in (('slow_op', 0.05, 1),
                                 ('fast_op', 0.001, 3)):
             for _ in range(reps):
                 with profiler.scope(name):

@@ -223,12 +223,38 @@ def test_injector_value_faults_poison_instead_of_raise():
 
 def test_injected_faults_look_transient():
     try:
-        FaultInjector('tunnel_stall:1').fire('device', ('tunnel_stall',))
+        FaultInjector('device_stall:1').fire('device', ('device_stall',))
     except Exception as exc:
         assert is_transient(exc)
     assert is_transient(RuntimeError(
         "Unable to initialize backend 'tpu': UNAVAILABLE"))
     assert not is_transient(ValueError('shape mismatch'))
+
+
+@pytest.mark.parametrize('message', [
+    'INTERNAL: Mosaic failed to compile TPU kernel: failed to legalize '
+    'operation \'vector.extract_strided_slice\'',
+    'RESOURCE_EXHAUSTED: XLA:TPU compile permanent error. Ran out of '
+    'memory in memory space vmem. Used 23.5M of 16.0M',
+    'INTERNAL: Core halted unexpectedly',
+    # a status code that would read as transient, raised by a compile
+    'DEADLINE_EXCEEDED: while compiling module jit_step',
+])
+def test_compile_shaped_errors_are_never_transient(message):
+    """XLA:TPU and Mosaic report a refused program as INTERNAL / a
+    program that does not fit as RESOURCE_EXHAUSTED: deterministic, so
+    never retried and never served around (docs/RESILIENCE.md)."""
+    exc = RuntimeError(message)
+    assert not is_transient(exc)
+    calls = []
+
+    def build():
+        calls.append(1)
+        raise exc
+
+    with pytest.raises(RuntimeError) as ei:
+        Retry(max_attempts=3, sleep=lambda s: None).call(build)
+    assert ei.value is exc and len(calls) == 1
 
 
 # ---------------------------------------------------------------------------
@@ -504,14 +530,14 @@ def test_kvstore_collectives_retry_transient(monkeypatch):
     monkeypatch.setattr(KVStore, 'num_workers',
                         property(lambda self: 2))
     monkeypatch.setenv('MXNET_TPU_FAULT',
-                       'tunnel_stall@kvstore.push:1,'
-                       'tunnel_stall@kvstore.pull:1')
+                       'device_stall@kvstore.push:1,'
+                       'device_stall@kvstore.pull:1')
     kv.init('w', nd.ones((3,)))
     kv.push('w', nd.full((3,), 2.0))   # first allreduce stalls, retried
     kv._barrier()                      # first sync stalls, retried
     # both scripted stalls were consumed by successful retries
-    assert not get_injector().pending('kvstore.push', ('tunnel_stall',))
-    assert not get_injector().pending('kvstore.pull', ('tunnel_stall',))
+    assert not get_injector().pending('kvstore.push', ('device_stall',))
+    assert not get_injector().pending('kvstore.pull', ('device_stall',))
 
 
 def test_kvstore_worker_crash_rejoins_instead_of_failing(monkeypatch):
@@ -539,20 +565,20 @@ def test_kvstore_collective_retry_exhaustion_is_typed(monkeypatch):
     _comm_retry path under injection (vs the recovering case in
     test_kvstore_collectives_retry_transient)."""
     from mxnet_tpu.kvstore import KVStore
-    from mxnet_tpu.resilience.policy import TunnelStallError
+    from mxnet_tpu.resilience.policy import DeviceStallError
     kv = KVStore('dist_sync')
     monkeypatch.setattr(KVStore, 'num_workers',
                         property(lambda self: 2))
-    monkeypatch.setenv('MXNET_TPU_FAULT', 'tunnel_stall@kvstore.push')
+    monkeypatch.setenv('MXNET_TPU_FAULT', 'device_stall@kvstore.push')
     kv.init('w', nd.ones((3,)))
     with pytest.raises(RetryExhausted) as ei:
         kv.push('w', nd.full((3,), 2.0))
     assert ei.value.attempts == 3
-    assert isinstance(ei.value.last_error, TunnelStallError)
+    assert isinstance(ei.value.last_error, DeviceStallError)
     # a mid-collective crash is NOT healable by per-process rejoin
     # (docs/RESILIENCE.md): only the init handshake honors
     # worker_crash, push exhaustion stays typed
-    monkeypatch.setenv('MXNET_TPU_FAULT', 'tunnel_stall@kvstore.pull')
+    monkeypatch.setenv('MXNET_TPU_FAULT', 'device_stall@kvstore.pull')
     with pytest.raises(RetryExhausted):
         kv._barrier()
 
@@ -576,8 +602,8 @@ def test_artifact_schema_is_status_invariant(tmp_path):
 @pytest.mark.slow
 def test_bench_faulted_subprocess_exits_zero(tmp_path):
     """End-to-end acceptance: MXNET_TPU_FAULT=device_unavailable makes
-    bench.py write an 'unavailable' artifact and exit 0 — the BENCH_r05
-    traceback failure mode is structurally impossible now."""
+    bench.py write an 'unavailable' artifact and exit 0 instead of
+    ending in a backend-init traceback."""
     out = str(tmp_path / 'BENCH.json')
     env = dict(os.environ, MXNET_TPU_FAULT='device_unavailable',
                JAX_PLATFORMS='cpu')

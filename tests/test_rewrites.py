@@ -332,7 +332,7 @@ def test_resnet_v1_vs_v2_parameter_counts_differ_only_in_norms():
 
 def test_conv_internal_nhwc_matches_nchw():
     """The channels-last internal conv path (used on accelerators) is
-    numerically identical to the NCHW path (docs/PERF_NOTES.md)."""
+    numerically identical to the NCHW path."""
     from mxnet_tpu.ops import nn as nn_ops
     from mxnet_tpu.ndarray.ndarray import invoke
     rng = np.random.RandomState(0)

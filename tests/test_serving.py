@@ -3,7 +3,7 @@ micro-batcher contract (deadline vs max-batch flush, FIFO ordering
 under concurrent submitters, queue-full rejection type, per-request
 timeout), pad/unpad bit-exactness, frozen save/load, the circuit
 breaker -> CPU-fallback degraded path, the partial-batch predict fix,
-and the MXNET_TPU_COMPILE_CACHE warm-start."""
+and the persistent compile-cache warm-start."""
 import json
 import os
 import subprocess
@@ -428,6 +428,60 @@ def test_session_device_loss_falls_back_and_degrades():
     assert st['batches']['accel'] == 0
 
 
+_COMPILE_ERROR = ('INTERNAL: Mosaic failed to compile TPU kernel: '
+                  'failed to legalize operation')
+
+
+def test_session_compile_failure_raises_instead_of_cpu_completion():
+    """A compile-shaped failure on the serving path is not transient:
+    the breaker re-raises it to the caller — no CPU completion, no
+    fallback batch, no serve_fallback / breaker_open event."""
+    from mxnet_tpu import observability as obs
+    mod, x, _ = _fitted_module()
+    frozen = serving.freeze(mod, max_batch=4)
+
+    def refused(arrays, n=None):
+        raise RuntimeError(_COMPILE_ERROR)
+
+    frozen.run = refused
+    frozen.run_fallback = lambda *a, **k: pytest.fail(
+        'a refused program was completed on the CPU')
+    obs.get_recorder().clear()
+    with serving.InferenceSession(frozen, deadline_ms=1.0, max_batch=1,
+                                  watchdog=False) as sess:
+        for _ in range(4):      # past the breaker threshold of 3
+            with pytest.raises(RuntimeError, match='Mosaic failed'):
+                sess.infer(x[0], timeout=30)
+        st = sess.status()
+    assert st['batches'] == {'accel': 0, 'fallback': 0}
+    kinds = [e.get('kind') for e in obs.get_recorder().events()]
+    assert 'serve_fallback' not in kinds and 'breaker_open' not in kinds
+
+
+def test_decode_compile_failure_fails_stream_instead_of_cpu_tokens():
+    """Same rule through DecodeEngine._device: the stream fails with
+    the compiler's error and no token is produced on the CPU."""
+    from mxnet_tpu.serving.decode import init_transformer_lm
+    model, params = init_transformer_lm(vocab=19, units=16, hidden=24,
+                                        layers=1, heads=4, max_len=32)
+    prog = serving.freeze_decode(model, params, slots=2,
+                                 prefill_buckets=(4, 8), max_len=32)
+
+    def refused(*a, **k):
+        raise RuntimeError(_COMPILE_ERROR)
+
+    prog.run_prefill = refused
+    prog.fallback_generate = lambda *a, **k: pytest.fail(
+        'a refused program was completed on the CPU')
+    with serving.InferenceSession(prog, watchdog=False) as sess:
+        stream = sess.generate([3, 1, 4], max_new_tokens=4)
+        with pytest.raises(RuntimeError, match='Mosaic failed'):
+            stream.result(30)
+        st = sess.status()
+    assert st['decode']['counts']['fallback_tokens'] == 0
+    assert stream.degraded is False
+
+
 def test_session_recovers_after_transient_faults():
     mod, x, _ = _fitted_module()
     frozen = serving.freeze(mod, max_batch=4)
@@ -566,10 +620,11 @@ def test_module_train_batch_still_reshapes():
 
 
 # ---------------------------------------------------------------------------
-# persistent compilation cache (MXNET_TPU_COMPILE_CACHE)
+# persistent compilation cache: JAX_COMPILATION_CACHE_DIR where set,
+# else the fixed <repo>/.jax_cache (config.configure_compile_cache)
 # ---------------------------------------------------------------------------
 
-_CACHE_CHILD = r'''
+_CACHE_CHILD = r"""
 import sys
 import mxnet_tpu as mx
 from mxnet_tpu import nd
@@ -581,18 +636,21 @@ ex = out.simple_bind(ctx=mx.cpu(), data=(4, 8))
 ex.forward(is_train=False, data=nd.array(np.ones((4, 8), 'float32')))
 ex.outputs[0].wait_to_read()
 print('CHILD_OK')
-'''
+"""
 
 
 @pytest.mark.slow
 def test_compile_cache_second_process_warm_starts(tmp_path):
-    """MXNET_TPU_COMPILE_CACHE warm-start: the first process populates
-    the persistent cache; a second identical process compiles nothing
-    new — zero new cache entries, every XLA compile (the expensive
-    part of a jit-cache miss) served from disk."""
+    """Warm-start through JAX_COMPILATION_CACHE_DIR: the first process
+    populates the persistent cache; a second identical process compiles
+    nothing new — zero new cache entries, every XLA compile (the
+    expensive part of a jit-cache miss) served from disk. jax's
+    write thresholds are zeroed so this toy program is cached at all."""
     cache = str(tmp_path / 'jitcache')
     env = dict(os.environ, JAX_PLATFORMS='cpu',
-               MXNET_TPU_COMPILE_CACHE=cache)
+               JAX_COMPILATION_CACHE_DIR=cache,
+               JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS='0',
+               JAX_PERSISTENT_CACHE_MIN_ENTRY_SIZE_BYTES='-1')
 
     def run_child():
         r = subprocess.run([sys.executable, '-c', _CACHE_CHILD],
@@ -613,21 +671,41 @@ def test_compile_cache_second_process_warm_starts(tmp_path):
         'warm-starting'
 
 
-def test_compile_cache_knob_configures_jax(tmp_path):
-    import jax
-    prev = jax.config.jax_compilation_cache_dir
-    cache = str(tmp_path / 'cc')
-    mx.config.set('MXNET_TPU_COMPILE_CACHE', cache)
-    try:
-        assert mx.config.configure_compile_cache() == \
-            os.path.abspath(cache)
-        assert jax.config.jax_compilation_cache_dir == \
-            os.path.abspath(cache)
-    finally:
-        mx.config.unset('MXNET_TPU_COMPILE_CACHE')
-        jax.config.update('jax_compilation_cache_dir', prev)
-        import mxnet_tpu.config as _cfg
-        _cfg._compile_cache_dir = None
+_CACHE_DIR_CHILD = (
+    'import jax, mxnet_tpu as mx\n'
+    'print("DIR", jax.config.jax_compilation_cache_dir)\n'
+    'print("RESOLVED", mx.config.configure_compile_cache())\n')
+
+
+def _cache_dir_child(env):
+    r = subprocess.run([sys.executable, '-c', _CACHE_DIR_CHILD],
+                       cwd=REPO, env=env, capture_output=True,
+                       text=True, timeout=300)
+    assert r.returncode == 0, r.stderr
+    lines = dict(ln.split(' ', 1) for ln in r.stdout.splitlines()
+                 if ln.startswith(('DIR ', 'RESOLVED ')))
+    return lines['DIR'], lines['RESOLVED']
+
+
+def test_compile_cache_env_var_places_the_cache(tmp_path):
+    """JAX_COMPILATION_CACHE_DIR set => the code sets no directory of
+    its own: jax's config holds exactly the variable's value."""
+    cache = str(tmp_path / 'x')
+    env = dict(os.environ, JAX_PLATFORMS='cpu',
+               JAX_COMPILATION_CACHE_DIR=cache)
+    assert _cache_dir_child(env) == (cache, cache)
+
+
+def test_compile_cache_default_is_fixed_path_in_checkout():
+    """JAX_COMPILATION_CACHE_DIR unset => one fixed path inside the
+    checkout (the path is part of the cache key: never a tempdir, a
+    pid or a time), and the old knob no longer exists."""
+    env = dict(os.environ, JAX_PLATFORMS='cpu')
+    env.pop('JAX_COMPILATION_CACHE_DIR', None)
+    env['MXNET_TPU_COMPILE_CACHE'] = '/nonexistent/ignored'
+    want = os.path.join(REPO, '.jax_cache')
+    assert _cache_dir_child(env) == (want, want)
+    assert 'MXNET_TPU_COMPILE_CACHE' not in mx.config.KNOBS
 
 
 # ---------------------------------------------------------------------------

@@ -7,8 +7,8 @@ Eight checks:
   2. bench.py in forced-degraded mode: with
      MXNET_TPU_FAULT=device_unavailable the bench must EXIT 0 and write
      an artifact whose status != "ok" with the full degraded-mode
-     schema (docs/RESILIENCE.md) — the BENCH_r05 traceback failure mode
-     is the regression this tier gates against.
+     schema (docs/RESILIENCE.md) — a backend-init traceback is the
+     regression this tier gates against.
   3. NaN-injection guardrail contract: with MXNET_TPU_FAULT=nan@grads:2
      the guardrail selftest (python -m mxnet_tpu.guardrail) must skip
      both poisoned updates with params bit-identical, halve the loss

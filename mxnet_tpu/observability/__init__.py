@@ -15,9 +15,10 @@ evidence:
                     dumped as a ``mxnet_tpu.flight.v1`` JSONL artifact
                     on crash / stall / preemption, so post-mortems
                     always have the last N events of run history.
-  * ``spans``     — step-phase spans (data-wait / step / sync /
-                    checkpoint / compile) unified with the profiler's
-                    chrome-trace scopes and jax.profiler annotations.
+  * ``spans``     — host spans (training phases, the decode
+                    scheduler's tick) written to the jax profiler's
+                    trace, the phase histogram, the chrome trace and
+                    the request-span buffer.
   * ``export``    — Prometheus text format (file + stdlib HTTP, off by
                     default), JSONL, TensorBoard.
   * ``hlo``       — per-step collective-byte accounting from optimized
@@ -45,8 +46,7 @@ from .recorder import (FLIGHT_SCHEMA, FlightRecorder, get_recorder,
                        install_excepthook, read_flight)
 from .spans import PHASES, span
 from .hlo import collective_bytes, trainer_collective_stats
-from .roofline import (roofline_artifact, diff_artifacts as
-                       diff_fusion_artifacts)
+from .roofline import roofline_artifact
 from .export import (prometheus_text, write_prometheus, write_jsonl,
                      tensorboard_export, PrometheusServer,
                      maybe_start_http_server, parse_prometheus)
@@ -57,7 +57,7 @@ __all__ = [
     'metrics', 'recorder', 'spans', 'export', 'hlo', 'roofline',
     'trace', 'TRACE_SCHEMA', 'TRACE_HEADER', 'TraceContext',
     'SpanBuffer',
-    'roofline_artifact', 'diff_fusion_artifacts',
+    'roofline_artifact',
     'Counter', 'Gauge', 'Histogram', 'MetricsRegistry', 'counter',
     'gauge', 'histogram', 'get_registry', 'enabled', 'set_enabled',
     'snapshot', 'FLIGHT_SCHEMA', 'FlightRecorder', 'get_recorder',

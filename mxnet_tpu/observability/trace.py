@@ -49,7 +49,7 @@ __all__ = [
     'TRACE_SCHEMA', 'TRACE_HEADER', 'NO_PARENT', 'TraceContext',
     'SpanBuffer', 'enabled', 'set_enabled', 'get_buffer',
     'current', 'current_trace_id', 'activate', 'emit_phase',
-    'parse_header', 'stitch', 'normalize_skew', 'tree_verdict',
+    'parse_header', 'inbound', 'stitch', 'normalize_skew', 'tree_verdict',
     'waterfall', 'critical_path', 'read_ndjson',
 ]
 
@@ -156,6 +156,18 @@ def parse_header(value):
     if span_id == NO_PARENT:
         span_id = None
     return TraceContext(trace_id, span_id, None)
+
+
+def inbound(headers):
+    """The context a server opens its request span under: the sender's
+    when ``headers`` (any mapping with ``get``) carries an
+    ``X-Mxnet-Trace`` that parses, else a fresh identity with no
+    parent, so a request that arrives without one still gets a root
+    span and a trace of its own. None when tracing is off (one flag
+    read: no header lookup, no parse, no allocation)."""
+    if not _state.enabled and not enabled():
+        return None
+    return parse_header(headers.get(TRACE_HEADER)) or TraceContext.new()
 
 
 # ---------------------------------------------------------------------------

@@ -1192,10 +1192,11 @@ class ParallelTrainer:
             [self._next_base_key()[0],
              self._base_key[1] ^ onp.uint32(self.num_update + 1)],
             dtype=onp.uint32)
-        xd = tuple(self._put_data(a, sh)
-                   for a, sh in zip(xs, self._data_shardings[0]))
-        yd = tuple(self._put_data(a, sh)
-                   for a, sh in zip(ys, self._data_shardings[1]))
+        with _obs.span('train.put_data'):
+            xd = tuple(self._put_data(a, sh)
+                       for a, sh in zip(xs, self._data_shardings[0]))
+            yd = tuple(self._put_data(a, sh)
+                       for a, sh in zip(ys, self._data_shardings[1]))
         if self._multiproc and first:
             # the program's operand shapes are GLOBAL; _build only saw
             # this host's local shard — re-record for compiled_step()
@@ -1208,7 +1209,8 @@ class ParallelTrainer:
         loss = None
         health = None
         with _profiler.op_span('fused_train_step',
-                               lambda: loss.block_until_ready()):
+                               lambda: loss.block_until_ready()), \
+                _obs.span('train.dispatch'):
             if self._guard is None:
                 self._param_arrays, self._state_leaves, loss = \
                     self._jitted(key, hyper, self._param_arrays,
@@ -1231,7 +1233,8 @@ class ParallelTrainer:
         if self._guard is not None:
             self._guard.record(self.num_update - 1, health, loss=loss,
                                scale=self._gstate[0])
-        self._boundary_post()
+        with _obs.span('train.boundary'):
+            self._boundary_post()
         return NDArray(loss)
 
     def _record_step_telemetry(self, first, t0, examples, nsteps=1):

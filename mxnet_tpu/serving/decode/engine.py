@@ -62,6 +62,7 @@ import time
 
 import numpy as onp
 
+from ...observability.spans import span as _span
 from ..batcher import BackpressureError, BatcherClosed, RequestTimeout
 from .paged import TRASH_PAGE, PageAllocator, PrefixCache, pages_for
 from .sampling import key_for
@@ -368,6 +369,9 @@ class DecodeEngine:
                     'proposals (needs a position-addressed cache: '
                     'use a transformer draft)' % (dm.family,))
             self._draft = draft
+            # its XLA modules read jit_draft_* in a trace (programs it
+            # compiled or loaded before now keep the names they have)
+            draft.module_prefix = 'draft_'
             self.spec_k = spec_k
         # multi-adapter serving: the id -> pool-index registry. The
         # program must have been frozen with an adapter_spec (the pool
@@ -604,16 +608,24 @@ class DecodeEngine:
 
     # -- worker ------------------------------------------------------------
 
+    def _idle(self):
+        """Nothing to schedule (caller holds the lock)."""
+        return not self._pending and not self._active \
+            and not self._migrations
+
     def _run(self):
         while True:
             with self._lock:
-                while not self._pending and not self._active \
-                        and not self._migrations:
-                    if self._closed:
-                        return
-                    self._wake.wait(0.05)
-                if self._closed and not self._pending \
-                        and not self._active and not self._migrations:
+                idle = self._idle()
+            if idle:
+                # the span opens outside the lock: its own emits never
+                # extend the critical section
+                with _span('eng.wait_work'):
+                    with self._lock:
+                        while self._idle() and not self._closed:
+                            self._wake.wait(0.05)
+            with self._lock:
+                if self._closed and self._idle():
                     return
             try:
                 self._tick()
@@ -625,34 +637,54 @@ class DecodeEngine:
     def _tick(self):
         """One scheduler iteration: retire finished/abandoned slots,
         service migration requests, admit prefills, advance the live
-        batch one token."""
-        self._retire_abandoned()
-        self._service_migrations()
-        budget = self.prefill_interleave if self._active \
-            else self.slots
-        while budget > 0:
-            with self._lock:
-                if not self._pending or not self._free:
-                    break
-                seq = self._pending.pop(0)
-                slot = self._free.pop(0)
-            if self.paged:
-                self._admit_paged(seq, slot)
-            else:
-                self._admit(seq, slot)
-            budget -= 1
-        if self._active:
-            self._step()
-        inst = _serving_instruments()
-        if inst is not None:
-            with self._lock:
-                inst.active_slots.set(len(self._active))
-                inst.queue_depth.set(len(self._pending))
-                if self._allocator is not None:
-                    pool = self._allocator.stats()
-                    inst.pages_total.set(pool['pages_total'])
-                    inst.pages_free.set(pool['pages_free'])
-                    inst.page_occupancy.set(pool['occupancy_pct'])
+        batch one token.
+
+        The tick and each of its phases is a host span on the
+        profiler's clock (``eng.tick`` / ``eng.tick.*``, worker thread
+        only), so a trace's idle gaps name the phase the scheduler was
+        in. ``step`` is the id ``eng.queue_wait`` / ``eng.prefill``
+        carry as ``tick``; ``wall`` ties the profiler's clock to the
+        span buffer's."""
+        with self._lock:
+            step = self._counts['steps']
+            active, pending = len(self._active), len(self._pending)
+        with _span('eng.tick', step=step, active=active,
+                   pending=pending, wall=time.time()):
+            with _span('eng.tick.retire'):
+                self._retire_abandoned()
+            with _span('eng.tick.migrate'):
+                self._service_migrations()
+            budget = self.prefill_interleave if self._active \
+                else self.slots
+            while budget > 0:
+                with self._lock:
+                    if not self._pending or not self._free:
+                        break
+                    seq = self._pending.pop(0)
+                    slot = self._free.pop(0)
+                # one span an admission, not one around the loop: the
+                # reduction that reads them (benchmark/xplane.py) looks
+                # back 64 spans for the one open over a gap, and one
+                # prefill's device call leaves some forty behind it
+                with _span('eng.tick.admit'):
+                    if self.paged:
+                        self._admit_paged(seq, slot)
+                    else:
+                        self._admit(seq, slot)
+                budget -= 1
+            if self._active:
+                self._step()
+            with _span('eng.tick.telemetry'):
+                inst = _serving_instruments()
+                if inst is not None:
+                    with self._lock:
+                        inst.active_slots.set(len(self._active))
+                        inst.queue_depth.set(len(self._pending))
+                        if self._allocator is not None:
+                            pool = self._allocator.stats()
+                            inst.pages_total.set(pool['pages_total'])
+                            inst.pages_free.set(pool['pages_free'])
+                            inst.page_occupancy.set(pool['occupancy_pct'])
 
     def _retire_abandoned(self):
         """Free slots whose stream is already done (timeout reaper or
@@ -851,12 +883,15 @@ class DecodeEngine:
                 raise               # bug-shaped: surface loudly
             self._note_failure(exc, step, was_open)
             raise _DegradedPath() from exc
-        with self._lock:
-            self._degraded = False
-            self._last_error = None
-        inst = _serving_instruments()
-        if inst is not None:
-            inst.degraded.set(0.0)
+        # a span of its own: the call has left some hundred runtime
+        # spans behind it, more than the reduction looks back over
+        with _span('eng.tick.after_call'):
+            with self._lock:
+                self._degraded = False
+                self._last_error = None
+            inst = _serving_instruments()
+            if inst is not None:
+                inst.degraded.set(0.0)
         return out
 
     def on_stall(self, record):
@@ -1040,7 +1075,10 @@ class DecodeEngine:
         tr = seq.trace
         if tr is not None:
             w0 = time.time()
-            self._trace_span(seq, 'eng.queue_wait', tr['enq'], w0)
+            # the eng.tick span (profiler's clock) that admitted it
+            tr['tick'] = self._counts['steps']
+            self._trace_span(seq, 'eng.queue_wait', tr['enq'], w0,
+                             tick=tr['tick'])
             tr['last'] = w0
         if not self._admit_adapter(seq, slot):
             return
@@ -1076,44 +1114,8 @@ class DecodeEngine:
             logging.exception('decode %s: prefill failed with a '
                               'non-transient error', self.name)
             return
-        with self._lock:
-            self._counts['prefills'] += 1
-            self._counts['tokens'] += 1
-            if seq.temperature > 0:
-                self._counts['sampled_tokens'] += 1
-        seq.slot = slot
-        seq.pos = len(seq.prompt)
-        seq.last_token = int(tok)
-        now = self._clock()
-        seq.first_token_at = now
-        inst = _serving_instruments()
-        if inst is not None:
-            inst.prefills.inc()
-            inst.tokens.inc()
-            if seq.temperature > 0:
-                inst.sampled_tokens.inc()
-            inst.ttft.observe(max(0.0, now - seq.enqueued_at))
-        if tr is not None:
-            w1 = time.time()
-            self._trace_span(seq, 'eng.prefill', tr['last'], w1,
-                             tokens=len(seq.prompt))
-            self._trace_span(seq, 'eng.first_token', tr['last'], w1,
-                             ttft_s=round(w1 - tr['enq'], 6))
-            tr['last'] = tr['first_w'] = w1
-        _record_event('decode_admit', slot=slot,
-                      prompt_len=len(seq.prompt))
-        # register BEFORE the finish check so a first-token EOS /
-        # max_new=1 retirement flows through _retire and frees the
-        # slot instead of leaking it
-        with self._lock:
-            self._active[slot] = seq
-        seq.stream._emit(tok)
-        reason = self._finished_reason(seq, int(tok))
-        if reason is not None:
-            seq.stream._finish(reason)
-            self._retire(slot, seq, reason)
-        elif seq.prefill_only:
-            self._export_at_boundary(seq, slot)
+        with _span('eng.tick.emit'):
+            self._emit_prefilled(seq, slot, tok)
 
     def _admit_paged(self, seq, slot):
         """Paged join: a prefix-cache hit references the shared pages
@@ -1129,7 +1131,10 @@ class DecodeEngine:
         tr = seq.trace
         if tr is not None:
             w0 = time.time()
-            self._trace_span(seq, 'eng.queue_wait', tr['enq'], w0)
+            # the eng.tick span (profiler's clock) that admitted it
+            tr['tick'] = self._counts['steps']
+            self._trace_span(seq, 'eng.queue_wait', tr['enq'], w0,
+                             tick=tr['tick'])
             tr['last'] = w0
         if not self._admit_adapter(seq, slot):
             return
@@ -1206,7 +1211,7 @@ class DecodeEngine:
                     self._draft.run_prefill, self._draft_cache,
                     onp.asarray(prompt, 'int32'), slot)
             if self._prefix is not None:
-                with self._lock:
+                with _span('eng.tick.prefix_register'), self._lock:
                     self._prefix.register(prompt, ids,
                                           namespace=seq.adapter_id)
         except _DegradedPath:
@@ -1232,6 +1237,14 @@ class DecodeEngine:
             logging.exception('decode %s: paged prefill failed with a '
                               'non-transient error', self.name)
             return
+        with _span('eng.tick.emit'):
+            self._emit_prefilled(seq, slot, tok, prefix_tokens=0)
+
+    def _emit_prefilled(self, seq, slot, tok, **event):
+        """A prefill has landed in ``slot``: book it, make the sequence
+        live and stream its first token (``event`` adds fields to the
+        ``decode_admit`` flight event)."""
+        n = len(seq.prompt)
         with self._lock:
             self._counts['prefills'] += 1
             self._counts['tokens'] += 1
@@ -1249,15 +1262,18 @@ class DecodeEngine:
             if seq.temperature > 0:
                 inst.sampled_tokens.inc()
             inst.ttft.observe(max(0.0, now - seq.enqueued_at))
+        tr = seq.trace
         if tr is not None:
             w1 = time.time()
             self._trace_span(seq, 'eng.prefill', tr['last'], w1,
-                             tokens=n)
+                             tokens=n, tick=tr['tick'])
             self._trace_span(seq, 'eng.first_token', tr['last'], w1,
                              ttft_s=round(w1 - tr['enq'], 6))
             tr['last'] = tr['first_w'] = w1
-        _record_event('decode_admit', slot=slot, prompt_len=n,
-                      prefix_tokens=0)
+        _record_event('decode_admit', slot=slot, prompt_len=n, **event)
+        # register BEFORE the finish check so a first-token EOS /
+        # max_new=1 retirement flows through _retire and frees the
+        # slot instead of leaking it
         with self._lock:
             self._active[slot] = seq
         seq.stream._emit(tok)
@@ -1296,16 +1312,18 @@ class DecodeEngine:
             else:
                 self._paged_step(active)
             return
-        tokens = onp.zeros(self.slots, 'int32')
-        positions = onp.zeros(self.slots, 'int32')
-        for slot, seq in active.items():
-            tokens[slot] = seq.last_token
-            positions[slot] = seq.pos
-        t0 = self._clock()
         try:
+            with _span('eng.tick.build_inputs'):
+                tokens = onp.zeros(self.slots, 'int32')
+                positions = onp.zeros(self.slots, 'int32')
+                for slot, seq in active.items():
+                    tokens[slot] = seq.last_token
+                    positions[slot] = seq.pos
+                extras = self._step_extras(active)
+            t0 = self._clock()
             self._cache, toks, _logits = self._device(
                 self.program.run_step, self._cache, tokens, positions,
-                **self._step_extras(active))
+                **extras)
         except _DegradedPath:
             self._degrade_inflight(active)
             return
@@ -1331,7 +1349,11 @@ class DecodeEngine:
                 self._retire(slot, seq, 'error')
             self._rebuild_cache()
             return
-        dt = self._clock() - t0
+        with _span('eng.tick.emit'):
+            self._emit_step(active, toks, self._clock() - t0)
+
+    def _emit_step(self, active, toks, dt):
+        """Book the step and hand each live slot its token."""
         with self._lock:
             self._counts['steps'] += 1
             self._counts['tokens'] += len(active)
@@ -1400,22 +1422,25 @@ class DecodeEngine:
         (prefix hits still consuming their prompt suffix) feed prompt
         tokens and emit nothing until the last prompt token's logits
         produce their first generated token."""
-        tokens = onp.zeros(self.slots, 'int32')
-        positions = onp.zeros(self.slots, 'int32')
-        tables = onp.zeros((self.slots, self.program.max_pages),
-                           'int32')
         t0 = self._clock()
         try:
-            active = self._page_faults(active)
+            with _span('eng.tick.page_faults'):
+                active = self._page_faults(active)
             if not active:
                 return
-            for slot, seq in active.items():
-                tokens[slot] = seq.last_token
-                positions[slot] = seq.pos
-                tables[slot] = seq.table
+            with _span('eng.tick.build_inputs'):
+                tokens = onp.zeros(self.slots, 'int32')
+                positions = onp.zeros(self.slots, 'int32')
+                tables = onp.zeros((self.slots, self.program.max_pages),
+                                   'int32')
+                for slot, seq in active.items():
+                    tokens[slot] = seq.last_token
+                    positions[slot] = seq.pos
+                    tables[slot] = seq.table
+                extras = self._step_extras(active)
             self._cache, toks, _logits = self._device(
                 self.program.run_step, self._cache, tokens, positions,
-                tables, **self._step_extras(active))
+                tables, **extras)
             if self._draft is not None:
                 # keep the draft's KV history in lockstep on
                 # non-speculative ticks (extension / near-max_len):
@@ -1441,7 +1466,11 @@ class DecodeEngine:
                 self._retire(slot, seq, 'error')
             self._rebuild_cache()
             return
-        dt = self._clock() - t0
+        with _span('eng.tick.emit'):
+            self._emit_paged_step(active, toks, self._clock() - t0)
+
+    def _emit_paged_step(self, active, toks, dt):
+        """Advance positions, stream each slot's token, book the step."""
         emitted = 0
         sampled = 0
         for slot, seq in active.items():
@@ -1488,19 +1517,21 @@ class DecodeEngine:
         masked behind each slot's position until overwritten."""
         k = self.spec_k
         C = k + 1
-        inputs = onp.zeros((self.slots, C), 'int32')
-        positions = onp.zeros(self.slots, 'int32')
-        tables = onp.zeros((self.slots, self.program.max_pages),
-                           'int32')
         t0 = self._clock()
         try:
-            active = self._page_faults(active, lookahead=k)
+            with _span('eng.tick.page_faults'):
+                active = self._page_faults(active, lookahead=k)
             if not active:
                 return
-            for slot, seq in active.items():
-                inputs[slot, 0] = seq.last_token
-                positions[slot] = seq.pos
-                tables[slot] = seq.table
+            with _span('eng.tick.build_inputs'):
+                inputs = onp.zeros((self.slots, C), 'int32')
+                positions = onp.zeros(self.slots, 'int32')
+                tables = onp.zeros((self.slots, self.program.max_pages),
+                                   'int32')
+                for slot, seq in active.items():
+                    inputs[slot, 0] = seq.last_token
+                    positions[slot] = seq.pos
+                    tables[slot] = seq.table
             # coupled proposals only when BOTH programs compiled with
             # sampling args — a greedy draft under sampled verify
             # stays correct (every emitted token is a target draw),
@@ -1546,7 +1577,15 @@ class DecodeEngine:
                 self._retire(slot, seq, 'error')
             self._rebuild_cache()
             return
-        dt = self._clock() - t0
+        with _span('eng.tick.emit'):
+            self._emit_spec_step(active, inputs, vtoks,
+                                 self._clock() - t0)
+
+    def _emit_spec_step(self, active, inputs, vtoks, dt):
+        """Walk each slot's verified chunk, stream what was accepted,
+        book the round."""
+        k = self.spec_k
+        C = k + 1
         emitted_total = 0
         sampled_total = 0
         accepted_total = 0

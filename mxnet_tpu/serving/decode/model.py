@@ -343,25 +343,62 @@ class TransformerLM(DecodeModel):
         return x.reshape(x.shape[:-1] + (self.heads,
                                          self.units // self.heads))
 
+    # Every operation of a compiled decode program carries the scope it
+    # was traced under in its ``op_name`` (metadata only: the compiled
+    # code is the same): ``embed``, ``layer<i>/attn``,
+    # ``layer<i>/kv_gather`` (paged.gather_pages), ``layer<i>/ffn``,
+    # ``lm_head``, and ``sampling`` (sampling.sample_tokens).
+
     def _embed(self, params, tokens, positions):
+        import jax
         import jax.numpy as jnp
-        return jnp.take(params['embed'], tokens, axis=0) \
-            + jnp.take(params['pos'], positions, axis=0)
+        with jax.named_scope('embed'):
+            return jnp.take(params['embed'], tokens, axis=0) \
+                + jnp.take(params['pos'], positions, axis=0)
 
     def _ffn_block(self, params, i, x, ad=None):
         import jax
         p = lambda n: params['l%d_%s' % (i, n)]           # noqa: E731
-        h = jax.nn.gelu(self._adapted(x, p('ffn1_w'), p('ffn1_b'),
-                                      ad, 'l%d_ffn1' % i),
-                        approximate=False)
-        return self._ln(x + self._adapted(h, p('ffn2_w'), p('ffn2_b'),
-                                          ad, 'l%d_ffn2' % i),
-                        p('ln2_g'), p('ln2_b'))
+        with jax.named_scope('ffn'):
+            h = jax.nn.gelu(self._adapted(x, p('ffn1_w'), p('ffn1_b'),
+                                          ad, 'l%d_ffn1' % i),
+                            approximate=False)
+            return self._ln(
+                x + self._adapted(h, p('ffn2_w'), p('ffn2_b'),
+                                  ad, 'l%d_ffn2' % i),
+                p('ln2_g'), p('ln2_b'))
 
     def _head(self, params, h):
+        import jax
         import jax.numpy as jnp
-        return jnp.einsum('...u,vu->...v', h, params['embed']) \
-            + params['out_bias']
+        with jax.named_scope('lm_head'):
+            return jnp.einsum('...u,vu->...v', h, params['embed']) \
+                + params['out_bias']
+
+    def _attend_rows(self, q, keys, values, bias):
+        """One query row a slot over its own (slots, L, units) keys
+        and values (the slot cache's rows, or the pages a table
+        gathered): q (slots, units) already scaled, bias (slots, 1, L)
+        masking what lies beyond each slot's position."""
+        import jax.numpy as jnp
+        qh = self._heads_split(q)                         # (S,H,D)
+        kh = self._heads_split(keys)                      # (S,L,H,D)
+        vh = self._heads_split(values)
+        scores = jnp.einsum('shd,slhd->shl', qh, kh) + bias
+        att = jnp.exp(scores - jnp.max(scores, axis=-1, keepdims=True))
+        att = att / jnp.sum(att, axis=-1, keepdims=True)
+        ctx = jnp.einsum('shl,slhd->shd', att, vh)
+        return ctx.reshape(q.shape[0], self.units)
+
+    def _attn_out(self, params, i, x, ctx):
+        """Output projection, residual and LayerNorm closing layer
+        ``i``'s attention."""
+        import jax
+        p = lambda n: params['l%d_%s' % (i, n)]           # noqa: E731
+        with jax.named_scope('attn'):
+            return self._ln(
+                x + self._dense(ctx, p('out_w'), p('out_b')),
+                p('ln1_g'), p('ln1_b'))
 
     def _full_pass(self, params, tokens, length, ad=None):
         """Whole-sequence causal pass: tokens (B, S) -> (logits
@@ -369,6 +406,7 @@ class TransformerLM(DecodeModel):
         keys (scalar or (B,)); the prefill AND reference path.
         ``ad`` — one shared adapter's (A, B) per target (prefill runs
         one sequence; its K/V land adapter-colored in the cache)."""
+        import jax
         import jax.numpy as jnp
         B, S = tokens.shape
         positions = jnp.arange(S)
@@ -384,37 +422,44 @@ class TransformerLM(DecodeModel):
         kvs = []
         for i in range(self.layers):
             p = lambda n: params['l%d_%s' % (i, n)]       # noqa: E731
-            qkv = self._adapted(x, p('qkv_w'), p('qkv_b'),
-                                ad, 'l%d_qkv' % i)
-            q, k, v = jnp.split(qkv, 3, axis=-1)
-            kvs.append((k, v))
-            if flash:
-                # blockwise online-softmax kernel over the padded
-                # prefix: masked keys carry exactly 0.0 weight and
-                # the key axis walks the same fixed blocks the
-                # decode-step kernel walks, so the cached path
-                # combines the same reduction tree over the real keys
-                # (the bit-identity argument, module docstring)
-                from ...ops.pallas import flash_attention
-                ctx = flash_attention(
-                    jnp.transpose(self._heads_split(q), (0, 2, 1, 3)),
-                    jnp.transpose(self._heads_split(k), (0, 2, 1, 3)),
-                    jnp.transpose(self._heads_split(v), (0, 2, 1, 3)),
-                    lengths=length, causal=True, scale=scale)
-                ctx = jnp.transpose(ctx, (0, 2, 1, 3))
-            else:
-                qh = self._heads_split(q * scale)         # (B,S,H,D)
-                kh = self._heads_split(k)
-                vh = self._heads_split(v)
-                scores = jnp.einsum('bqhd,bkhd->bhqk', qh, kh) + bias
-                att = jnp.exp(scores - jnp.max(scores, axis=-1,
-                                               keepdims=True))
-                att = att / jnp.sum(att, axis=-1, keepdims=True)
-                ctx = jnp.einsum('bhqk,bkhd->bqhd', att, vh)
-            ctx = ctx.reshape(B, S, self.units)
-            x = self._ln(x + self._dense(ctx, p('out_w'), p('out_b')),
-                         p('ln1_g'), p('ln1_b'))
-            x = self._ffn_block(params, i, x, ad)
+            with jax.named_scope('layer%d' % i):
+                with jax.named_scope('attn'):
+                    qkv = self._adapted(x, p('qkv_w'), p('qkv_b'),
+                                        ad, 'l%d_qkv' % i)
+                    q, k, v = jnp.split(qkv, 3, axis=-1)
+                    kvs.append((k, v))
+                    if flash:
+                        # blockwise online-softmax kernel over the
+                        # padded prefix: masked keys carry exactly 0.0
+                        # weight and the key axis walks the same fixed
+                        # blocks the decode-step kernel walks, so the
+                        # cached path combines the same reduction tree
+                        # over the real keys (the bit-identity
+                        # argument, module docstring)
+                        from ...ops.pallas import flash_attention
+                        ctx = flash_attention(
+                            jnp.transpose(self._heads_split(q),
+                                          (0, 2, 1, 3)),
+                            jnp.transpose(self._heads_split(k),
+                                          (0, 2, 1, 3)),
+                            jnp.transpose(self._heads_split(v),
+                                          (0, 2, 1, 3)),
+                            lengths=length, causal=True, scale=scale)
+                        ctx = jnp.transpose(ctx, (0, 2, 1, 3))
+                    else:
+                        qh = self._heads_split(q * scale)   # (B,S,H,D)
+                        kh = self._heads_split(k)
+                        vh = self._heads_split(v)
+                        scores = jnp.einsum('bqhd,bkhd->bhqk',
+                                            qh, kh) + bias
+                        att = jnp.exp(scores - jnp.max(
+                            scores, axis=-1, keepdims=True))
+                        att = att / jnp.sum(att, axis=-1,
+                                            keepdims=True)
+                        ctx = jnp.einsum('bhqk,bkhd->bqhd', att, vh)
+                    ctx = ctx.reshape(B, S, self.units)
+                x = self._attn_out(params, i, x, ctx)
+                x = self._ffn_block(params, i, x, ad)
         return self._head(params, x), kvs
 
     def prefill(self, params, cache, tokens, length, slot, ad=None):
@@ -437,8 +482,8 @@ class TransformerLM(DecodeModel):
         return cache, jnp.einsum('s,sv->v', sel, logits[0])
 
     def step(self, params, cache, tokens, positions, ad=None):
+        import jax
         import jax.numpy as jnp
-        slots = tokens.shape[0]
         x = self._embed(params, tokens, positions)        # (S, U)
         ar = jnp.arange(self.max_len)
         # each slot attends its own history: j <= own position
@@ -449,34 +494,32 @@ class TransformerLM(DecodeModel):
         cache = dict(cache)
         for i in range(self.layers):
             p = lambda n: params['l%d_%s' % (i, n)]       # noqa: E731
-            qkv = self._adapted(x, p('qkv_w'), p('qkv_b'),
-                                ad, 'l%d_qkv' % i)
-            q, k, v = jnp.split(qkv, 3, axis=-1)
-            ck = write_position(cache['l%d_k' % i], k, positions)
-            cv = write_position(cache['l%d_v' % i], v, positions)
-            cache['l%d_k' % i], cache['l%d_v' % i] = ck, cv
-            if flash:
-                # single-token kernel reading the slot cache in its
-                # native (slots, max_len, units) layout — no per-step
-                # head transpose of the cache, which is the per-token
-                # cache-traffic reduction
-                from ...ops.pallas import flash_decode_attention
-                ctx = flash_decode_attention(q, ck, cv, positions,
-                                             heads=self.heads,
-                                             scale=scale)
-            else:
-                qh = self._heads_split(q * scale)         # (S,H,D)
-                kh = self._heads_split(ck)                # (S,L,H,D)
-                vh = self._heads_split(cv)
-                scores = jnp.einsum('shd,slhd->shl', qh, kh) + bias
-                att = jnp.exp(scores - jnp.max(scores, axis=-1,
-                                               keepdims=True))
-                att = att / jnp.sum(att, axis=-1, keepdims=True)
-                ctx = jnp.einsum('shl,slhd->shd', att, vh)
-                ctx = ctx.reshape(slots, self.units)
-            x = self._ln(x + self._dense(ctx, p('out_w'), p('out_b')),
-                         p('ln1_g'), p('ln1_b'))
-            x = self._ffn_block(params, i, x, ad)
+            with jax.named_scope('layer%d' % i):
+                with jax.named_scope('attn'):
+                    qkv = self._adapted(x, p('qkv_w'), p('qkv_b'),
+                                        ad, 'l%d_qkv' % i)
+                    q, k, v = jnp.split(qkv, 3, axis=-1)
+                    ck = write_position(cache['l%d_k' % i], k,
+                                        positions)
+                    cv = write_position(cache['l%d_v' % i], v,
+                                        positions)
+                    cache['l%d_k' % i], cache['l%d_v' % i] = ck, cv
+                    if flash:
+                        # single-token kernel reading the slot cache
+                        # in its native (slots, max_len, units) layout
+                        # — no per-step head transpose of the cache,
+                        # which is the per-token cache-traffic
+                        # reduction
+                        from ...ops.pallas import \
+                            flash_decode_attention
+                        ctx = flash_decode_attention(
+                            q, ck, cv, positions, heads=self.heads,
+                            scale=scale)
+                    else:
+                        ctx = self._attend_rows(q * scale, ck, cv,
+                                                bias)
+                x = self._attn_out(params, i, x, ctx)
+                x = self._ffn_block(params, i, x, ad)
         return cache, self._head(params, x)
 
     def full_forward(self, params, tokens, ad=None):
@@ -531,8 +574,8 @@ class TransformerLM(DecodeModel):
         slot's position (incl. trash-page garbage) carry exactly 0.0
         attention weight, so the paged token stream is bit-identical
         to the slot cache's (module docstring argument)."""
+        import jax
         import jax.numpy as jnp
-        slots = tokens.shape[0]
         ps = pool[next(iter(pool))].shape[1]
         x = self._embed(params, tokens, positions)        # (S, U)
         page_ids = jnp.take_along_axis(
@@ -547,37 +590,35 @@ class TransformerLM(DecodeModel):
         pool = dict(pool)
         for i in range(self.layers):
             p = lambda n: params['l%d_%s' % (i, n)]       # noqa: E731
-            qkv = self._adapted(x, p('qkv_w'), p('qkv_b'),
-                                ad, 'l%d_qkv' % i)
-            q, k, v = jnp.split(qkv, 3, axis=-1)
-            pool['l%d_k' % i] = write_paged_rows(
-                pool['l%d_k' % i], k, page_ids, offsets)
-            pool['l%d_v' % i] = write_paged_rows(
-                pool['l%d_v' % i], v, page_ids, offsets)
-            if flash:
-                # page-table gather + the same single-token kernel
-                # the slot cache used — the kernel walks the gathered
-                # view in the fixed K_BLOCK steps, so the reduction
-                # tree over the real keys is unchanged
-                from ...ops.pallas import flash_paged_decode_attention
-                ctx = flash_paged_decode_attention(
-                    q, pool['l%d_k' % i], pool['l%d_v' % i], tables,
-                    positions, heads=self.heads, scale=scale)
-            else:
-                ck = gather_pages(pool['l%d_k' % i], tables)
-                cv = gather_pages(pool['l%d_v' % i], tables)
-                qh = self._heads_split(q * scale)         # (S,H,D)
-                kh = self._heads_split(ck)                # (S,Lp,H,D)
-                vh = self._heads_split(cv)
-                scores = jnp.einsum('shd,slhd->shl', qh, kh) + bias
-                att = jnp.exp(scores - jnp.max(scores, axis=-1,
-                                               keepdims=True))
-                att = att / jnp.sum(att, axis=-1, keepdims=True)
-                ctx = jnp.einsum('shl,slhd->shd', att, vh)
-                ctx = ctx.reshape(slots, self.units)
-            x = self._ln(x + self._dense(ctx, p('out_w'), p('out_b')),
-                         p('ln1_g'), p('ln1_b'))
-            x = self._ffn_block(params, i, x, ad)
+            with jax.named_scope('layer%d' % i):
+                with jax.named_scope('attn'):
+                    qkv = self._adapted(x, p('qkv_w'), p('qkv_b'),
+                                        ad, 'l%d_qkv' % i)
+                    q, k, v = jnp.split(qkv, 3, axis=-1)
+                    pool['l%d_k' % i] = write_paged_rows(
+                        pool['l%d_k' % i], k, page_ids, offsets)
+                    pool['l%d_v' % i] = write_paged_rows(
+                        pool['l%d_v' % i], v, page_ids, offsets)
+                if flash:
+                    # page-table gather + the same single-token kernel
+                    # the slot cache used — the kernel walks the
+                    # gathered view in the fixed K_BLOCK steps, so the
+                    # reduction tree over the real keys is unchanged
+                    from ...ops.pallas import \
+                        flash_paged_decode_attention
+                    with jax.named_scope('attn'):
+                        ctx = flash_paged_decode_attention(
+                            q, pool['l%d_k' % i], pool['l%d_v' % i],
+                            tables, positions, heads=self.heads,
+                            scale=scale)
+                else:
+                    ck = gather_pages(pool['l%d_k' % i], tables)
+                    cv = gather_pages(pool['l%d_v' % i], tables)
+                    with jax.named_scope('attn'):
+                        ctx = self._attend_rows(q * scale, ck, cv,
+                                                bias)
+                x = self._attn_out(params, i, x, ctx)
+                x = self._ffn_block(params, i, x, ad)
         return pool, self._head(params, x)
 
     def paged_verify(self, params, pool, tokens, positions, tables,
@@ -592,6 +633,7 @@ class TransformerLM(DecodeModel):
         float32 precision, not bitwise (greedy acceptance re-checks
         against the draft, and rejected rows are simply masked until
         overwritten — docs/DIVERGENCES.md)."""
+        import jax
         import jax.numpy as jnp
         slots, C = tokens.shape
         ps = pool[next(iter(pool))].shape[1]
@@ -608,27 +650,30 @@ class TransformerLM(DecodeModel):
         pool = dict(pool)
         for i in range(self.layers):
             p = lambda n: params['l%d_%s' % (i, n)]       # noqa: E731
-            qkv = self._adapted(x, p('qkv_w'), p('qkv_b'),
-                                ad, 'l%d_qkv' % i)
-            q, k, v = jnp.split(qkv, 3, axis=-1)
-            pool['l%d_k' % i] = write_paged_chunk(
-                pool['l%d_k' % i], k, page_ids, offsets)
-            pool['l%d_v' % i] = write_paged_chunk(
-                pool['l%d_v' % i], v, page_ids, offsets)
-            ck = gather_pages(pool['l%d_k' % i], tables)
-            cv = gather_pages(pool['l%d_v' % i], tables)
-            qh = self._heads_split(q * scale)             # (S,C,H,D)
-            kh = self._heads_split(ck)                    # (S,Lp,H,D)
-            vh = self._heads_split(cv)
-            scores = jnp.einsum('schd,slhd->shcl', qh, kh) + bias
-            att = jnp.exp(scores - jnp.max(scores, axis=-1,
-                                           keepdims=True))
-            att = att / jnp.sum(att, axis=-1, keepdims=True)
-            ctx = jnp.einsum('shcl,slhd->schd', att, vh)
-            ctx = ctx.reshape(slots, C, self.units)
-            x = self._ln(x + self._dense(ctx, p('out_w'), p('out_b')),
-                         p('ln1_g'), p('ln1_b'))
-            x = self._ffn_block(params, i, x, ad)
+            with jax.named_scope('layer%d' % i):
+                with jax.named_scope('attn'):
+                    qkv = self._adapted(x, p('qkv_w'), p('qkv_b'),
+                                        ad, 'l%d_qkv' % i)
+                    q, k, v = jnp.split(qkv, 3, axis=-1)
+                    pool['l%d_k' % i] = write_paged_chunk(
+                        pool['l%d_k' % i], k, page_ids, offsets)
+                    pool['l%d_v' % i] = write_paged_chunk(
+                        pool['l%d_v' % i], v, page_ids, offsets)
+                ck = gather_pages(pool['l%d_k' % i], tables)
+                cv = gather_pages(pool['l%d_v' % i], tables)
+                with jax.named_scope('attn'):
+                    qh = self._heads_split(q * scale)     # (S,C,H,D)
+                    kh = self._heads_split(ck)            # (S,Lp,H,D)
+                    vh = self._heads_split(cv)
+                    scores = jnp.einsum('schd,slhd->shcl',
+                                        qh, kh) + bias
+                    att = jnp.exp(scores - jnp.max(
+                        scores, axis=-1, keepdims=True))
+                    att = att / jnp.sum(att, axis=-1, keepdims=True)
+                    ctx = jnp.einsum('shcl,slhd->schd', att, vh)
+                    ctx = ctx.reshape(slots, C, self.units)
+                x = self._attn_out(params, i, x, ctx)
+                x = self._ffn_block(params, i, x, ad)
         return pool, self._head(params, x)              # (S, C, V)
 
     def init_params(self, seed=0):

@@ -156,10 +156,12 @@ def gather_pages(pool_arr, tables):
     the slot cache's per-step view cost, independent of pool size (the
     HLO-DECODE-PAGED lint asserts no O(pool) materializing copy
     appears instead)."""
+    import jax
     import jax.numpy as jnp
-    g = jnp.take(pool_arr, tables, axis=0)   # (S, P, ps, *row)
-    s, p, ps = g.shape[:3]
-    return g.reshape((s, p * ps) + g.shape[3:])
+    with jax.named_scope('kv_gather'):
+        g = jnp.take(pool_arr, tables, axis=0)   # (S, P, ps, *row)
+        s, p, ps = g.shape[:3]
+        return g.reshape((s, p * ps) + g.shape[3:])
 
 
 def _row_write(pool_arr, row, page_id, offset):

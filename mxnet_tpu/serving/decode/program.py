@@ -42,6 +42,7 @@ import threading
 
 import numpy as onp
 
+from ...observability.spans import span as _span
 from ..bucket import BucketPolicy, default_buckets
 from .cache import cache_avals, cache_bytes, init_cache
 from .model import DecodeModel, from_gluon_rnn_lm, model_from_config
@@ -378,8 +379,16 @@ class DecodeProgram:
         tag = _pallas_resolve()
         return base if tag == 'off' else '%s:pallas-%s' % (base, tag)
 
-    def _build(self, key, fn, *avals):
-        """jit -> lower -> compile with the freeze.py accounting."""
+    # prefix of every XLA module name this program compiles; the engine
+    # sets 'draft_' on the program it is given as a speculative draft
+    module_prefix = ''
+
+    def _build(self, key, name, fn, *avals):
+        """jit -> lower -> compile with the freeze.py accounting.
+        ``name`` becomes the XLA module's (``jit_<name>``): what a
+        profiler trace and the benchmark's readers tell the programs
+        apart by. It is no part of ``key``, which names the program in
+        ``_compiled`` and in a saved artifact."""
         import time
         import jax
         from ...ops import traceknobs as _traceknobs
@@ -392,6 +401,7 @@ class DecodeProgram:
                 return prog
             t0 = time.perf_counter()
             knobs = _traceknobs.snapshot()
+            fn.__name__ = fn.__qualname__ = self.module_prefix + name
             jitted = jax.jit(fn, donate_argnums=(1,)) if self._donate \
                 else jax.jit(fn)
             with _traceknobs.scope(knobs):
@@ -404,6 +414,12 @@ class DecodeProgram:
         _instrument_compile(key, self.compile_seconds[key])
         return prog
 
+    def _step_name(self):
+        """The step is the one module whose name matches ``^jit_fn``
+        (the benchmark's step metrics read that pattern); a draft's
+        step is ``jit_draft_step``."""
+        return 'step' if self.module_prefix else 'fn_step'
+
     def compile_prefill(self, bucket):
         import jax
         key = self._program_key('prefill:%d' % bucket)
@@ -412,7 +428,8 @@ class DecodeProgram:
                  jax.ShapeDtypeStruct((), 'int32')]
         if self._has_extras:
             avals.append(self._extra_avals('prefill'))
-        return self._build(key, self._prefill_fn(key), *avals)
+        return self._build(key, 'prefill_b%d' % bucket,
+                           self._prefill_fn(key), *avals)
 
     def compile_step(self):
         import jax
@@ -421,7 +438,8 @@ class DecodeProgram:
                  jax.ShapeDtypeStruct((self.slots,), 'int32')]
         if self._has_extras:
             avals.append(self._extra_avals('step'))
-        return self._build(key, self._step_fn(key), *avals)
+        return self._build(key, self._step_name(), self._step_fn(key),
+                           *avals)
 
     def warmup(self, buckets=None):
         """Compile the whole ladder + the step program (server start,
@@ -439,6 +457,20 @@ class DecodeProgram:
         cache, tok = out
         return cache, tok, None
 
+    def _call(self, prog, *args):
+        """One compiled call and the read of what it emitted: (cache',
+        tokens np, logits np | None). Two host spans on the profiler's
+        clock, for the scheduler thread that calls this (engine.py):
+        ``eng.tick.dispatch`` up to the call's return (arguments
+        parsed and put, program enqueued) and ``eng.tick.read_tokens``
+        for the blocking read, during which the device is busy."""
+        with _span('eng.tick.dispatch'):
+            cache, toks, logits = self._unpack(prog(self._params, *args))
+        with _span('eng.tick.read_tokens'):
+            toks = onp.asarray(toks)
+        return cache, toks, \
+            None if logits is None else onp.asarray(logits)
+
     def run_prefill(self, cache, tokens, slot, temps=None,
                     top_ps=None, keys=None, masks=None, apool=None,
                     aidx=None):
@@ -455,13 +487,11 @@ class DecodeProgram:
         padded = onp.zeros((1, bucket), 'int32')
         padded[0, :n] = tokens
         prog = self.compile_prefill(bucket)
-        cache, tok, logits = self._unpack(prog(
-            self._params, cache, padded, onp.int32(n),
-            onp.int32(slot),
+        cache, tok, logits = self._call(
+            prog, cache, padded, onp.int32(n), onp.int32(slot),
             *self._extra_args('prefill', temps, top_ps, keys, masks,
-                              apool, aidx)))
-        return cache, int(tok), \
-            None if logits is None else onp.asarray(logits)
+                              apool, aidx))
+        return cache, int(tok), logits
 
     def run_step(self, cache, tokens, positions, temps=None,
                  top_ps=None, keys=None, masks=None, apool=None,
@@ -469,14 +499,12 @@ class DecodeProgram:
         """Advance every slot one token. Returns (cache', tokens np
         (slots,), logits np (slots, V) | None)."""
         prog = self.compile_step()
-        cache, toks, logits = self._unpack(prog(
-            self._params, cache,
+        return self._call(
+            prog, cache,
             onp.asarray(tokens, 'int32').reshape(self.slots),
             onp.asarray(positions, 'int32').reshape(self.slots),
             *self._extra_args('step', temps, top_ps, keys, masks,
-                              apool, aidx)))
-        return cache, onp.asarray(toks), \
-            None if logits is None else onp.asarray(logits)
+                              apool, aidx))
 
     def max_prompt_len(self):
         return self.policy.max_batch
@@ -919,7 +947,8 @@ class PagedDecodeProgram(DecodeProgram):
                  jax.ShapeDtypeStruct((npages,), 'int32')]
         if self._has_extras:
             avals.append(self._extra_avals('prefill'))
-        return self._build(key, self._paged_prefill_fn(key), *avals)
+        return self._build(key, 'prefill_b%d' % bucket,
+                           self._paged_prefill_fn(key), *avals)
 
     def compile_step(self):
         import jax
@@ -930,7 +959,8 @@ class PagedDecodeProgram(DecodeProgram):
                                       'int32')]
         if self._has_extras:
             avals.append(self._extra_avals('step'))
-        return self._build(key, self._paged_step_fn(key), *avals)
+        return self._build(key, self._step_name(),
+                           self._paged_step_fn(key), *avals)
 
     def compile_verify(self):
         import jax
@@ -944,13 +974,14 @@ class PagedDecodeProgram(DecodeProgram):
                                       'int32')]
         if self._has_extras:
             avals.append(self._extra_avals('verify'))
-        return self._build(key, self._verify_fn(key), *avals)
+        return self._build(key, 'verify_k%d' % self.spec_k,
+                           self._verify_fn(key), *avals)
 
     def compile_copy_page(self):
         import jax
         key = self._program_key('copy')
         return self._build(
-            key, self._copy_fn(key),
+            key, 'page_copy', self._copy_fn(key),
             jax.ShapeDtypeStruct((), 'int32'),
             jax.ShapeDtypeStruct((), 'int32'))
 
@@ -988,29 +1019,25 @@ class PagedDecodeProgram(DecodeProgram):
         padded = onp.zeros((1, bucket), 'int32')
         padded[0, :n] = tokens
         prog = self.compile_prefill(bucket)
-        pool, tok, logits = self._unpack(prog(
-            self._params, pool, padded, onp.int32(n),
-            onp.asarray(ids, 'int32'),
+        pool, tok, logits = self._call(
+            prog, pool, padded, onp.int32(n), onp.asarray(ids, 'int32'),
             *self._extra_args('prefill', temps, top_ps, keys, masks,
-                              apool, aidx)))
-        return pool, int(tok), \
-            None if logits is None else onp.asarray(logits)
+                              apool, aidx))
+        return pool, int(tok), logits
 
     def run_step(self, pool, tokens, positions, tables, temps=None,
                  top_ps=None, keys=None, masks=None, apool=None,
                  aidx=None):
         """Advance every slot one token through its page table."""
         prog = self.compile_step()
-        pool, toks, logits = self._unpack(prog(
-            self._params, pool,
+        return self._call(
+            prog, pool,
             onp.asarray(tokens, 'int32').reshape(self.slots),
             onp.asarray(positions, 'int32').reshape(self.slots),
             onp.asarray(tables, 'int32').reshape(self.slots,
                                                  self.max_pages),
             *self._extra_args('step', temps, top_ps, keys, masks,
-                              apool, aidx)))
-        return pool, onp.asarray(toks), \
-            None if logits is None else onp.asarray(logits)
+                              apool, aidx))
 
     def run_verify(self, pool, tokens, positions, tables, temps=None,
                    top_ps=None, keys=None, masks=None, apool=None,
@@ -1021,17 +1048,15 @@ class PagedDecodeProgram(DecodeProgram):
         ``keys`` is (slots, spec_k+1, 2): one key per verify row at
         its absolute position, matching the plain path's keys."""
         prog = self.compile_verify()
-        pool, toks, logits = self._unpack(prog(
-            self._params, pool,
+        return self._call(
+            prog, pool,
             onp.asarray(tokens, 'int32').reshape(self.slots,
                                                  self.spec_k + 1),
             onp.asarray(positions, 'int32').reshape(self.slots),
             onp.asarray(tables, 'int32').reshape(self.slots,
                                                  self.max_pages),
             *self._extra_args('verify', temps, top_ps, keys, masks,
-                              apool, aidx)))
-        return pool, onp.asarray(toks), \
-            None if logits is None else onp.asarray(logits)
+                              apool, aidx))
 
     def run_copy_page(self, pool, src, dst):
         """Copy-on-write: duplicate page ``src`` into ``dst``."""

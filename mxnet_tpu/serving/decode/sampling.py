@@ -96,30 +96,31 @@ def sample_tokens(logits, temps, top_ps, keys, masks=None):
     """
     import jax
     import jax.numpy as jnp
-    logits = jnp.asarray(logits)
-    if masks is not None:
-        # additive grammar/JSON mask: 0.0 is the bitwise identity, so
-        # an all-zero mask leaves even the greedy branch unchanged
-        logits = logits + masks
-    greedy = jnp.argmax(logits, axis=-1).astype('int32')
-    temps = jnp.asarray(temps, 'float32')
-    top_ps = jnp.asarray(top_ps, 'float32')
-    safe_t = jnp.where(temps > 0, temps, 1.0)
-    logp = jax.nn.log_softmax(logits / safe_t[:, None], axis=-1)
-    probs = jnp.exp(logp)
-    # nucleus: keep the smallest prefix of the descending-prob order
-    # whose mass reaches top_p. (csum - p) < top_p keeps the first
-    # token unconditionally (0 < top_p), so the filter can never
-    # empty a row.
-    order = jnp.argsort(-probs, axis=-1)
-    sorted_p = jnp.take_along_axis(probs, order, axis=-1)
-    csum = jnp.cumsum(sorted_p, axis=-1)
-    keep_sorted = (csum - sorted_p) < top_ps[:, None]
-    rows = jnp.arange(logits.shape[0])[:, None]
-    keep = jnp.zeros(logits.shape, bool).at[rows, order].set(keep_sorted)
-    filtered = jnp.where(keep, logp, -jnp.inf)
-    gumbel = jax.vmap(
-        lambda k, shape=logits.shape[1:]: jax.random.gumbel(k, shape)
-    )(jnp.asarray(keys, 'uint32'))
-    sampled = jnp.argmax(filtered + gumbel, axis=-1).astype('int32')
-    return jnp.where(temps > 0, sampled, greedy).astype('int32')
+    with jax.named_scope('sampling'):
+        logits = jnp.asarray(logits)
+        if masks is not None:
+            # additive grammar/JSON mask: 0.0 is the bitwise identity, so
+            # an all-zero mask leaves even the greedy branch unchanged
+            logits = logits + masks
+        greedy = jnp.argmax(logits, axis=-1).astype('int32')
+        temps = jnp.asarray(temps, 'float32')
+        top_ps = jnp.asarray(top_ps, 'float32')
+        safe_t = jnp.where(temps > 0, temps, 1.0)
+        logp = jax.nn.log_softmax(logits / safe_t[:, None], axis=-1)
+        probs = jnp.exp(logp)
+        # nucleus: keep the smallest prefix of the descending-prob order
+        # whose mass reaches top_p. (csum - p) < top_p keeps the first
+        # token unconditionally (0 < top_p), so the filter can never
+        # empty a row.
+        order = jnp.argsort(-probs, axis=-1)
+        sorted_p = jnp.take_along_axis(probs, order, axis=-1)
+        csum = jnp.cumsum(sorted_p, axis=-1)
+        keep_sorted = (csum - sorted_p) < top_ps[:, None]
+        rows = jnp.arange(logits.shape[0])[:, None]
+        keep = jnp.zeros(logits.shape, bool).at[rows, order].set(keep_sorted)
+        filtered = jnp.where(keep, logp, -jnp.inf)
+        gumbel = jax.vmap(
+            lambda k, shape=logits.shape[1:]: jax.random.gumbel(k, shape)
+        )(jnp.asarray(keys, 'uint32'))
+        sampled = jnp.argmax(filtered + gumbel, axis=-1).astype('int32')
+        return jnp.where(temps > 0, sampled, greedy).astype('int32')

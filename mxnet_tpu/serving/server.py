@@ -603,10 +603,7 @@ class ServingHTTPServer:
                 elif path == '/drain':
                     q = parse_qs(parsed.query)
                     rid = (q.get('request_id') or [None])[0]
-                    tctx = None
-                    if _trace.enabled():
-                        tctx = _trace.parse_header(
-                            handler.headers.get(_trace.TRACE_HEADER))
+                    tctx = _trace.inbound(handler.headers)
                     with srv._trace_buf.span('srv.drain', tctx,
                                              request_id=rid):
                         handler._json(200, srv._drain_snapshot(rid))
@@ -879,14 +876,12 @@ class ServingHTTPServer:
                                  str(max(1, int(hint + 0.999)))})
                     return
                 # server-side request span: parent is the sender's
-                # relay span (X-Mxnet-Trace); the span covers parse,
-                # admission, execution, and the full streamed relay.
-                # Untraced requests get the shared null span (no
-                # header parse, no allocation)
-                tctx = None
-                if _trace.enabled():
-                    tctx = _trace.parse_header(
-                        handler.headers.get(_trace.TRACE_HEADER))
+                # relay span (X-Mxnet-Trace), or none (a root span)
+                # for a request that arrives without one; the span
+                # covers parse, admission, execution, and the full
+                # streamed relay. With tracing off every request gets
+                # the shared null span (no header read, no allocation)
+                tctx = _trace.inbound(handler.headers)
                 name = {'/generate': 'srv.generate',
                         '/import': 'srv.import'}.get(path,
                                                      'srv.predict')

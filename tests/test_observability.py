@@ -210,7 +210,44 @@ def test_span_records_phase_histogram():
     assert child.count == before + 1
 
 
-def test_span_unifies_with_profiler_scope(tmp_path):
+def _host_events(trace_dir):
+    """[(line index, name, start_ns, end_ns, stats)] of the host plane
+    of the one xplane file under ``trace_dir``."""
+    import glob
+    from jax.profiler import ProfileData
+    path, = glob.glob(os.path.join(str(trace_dir), 'plugins', 'profile',
+                                   '*', '*.xplane.pb'))
+    out = []
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name != '/host:CPU':
+            continue
+        for i, line in enumerate(plane.lines):
+            for ev in line.events:
+                out.append((i, ev.name, ev.start_ns,
+                            ev.start_ns + ev.duration_ns,
+                            dict(ev.stats)))
+    return out
+
+
+def _jax_trace(trace_dir):
+    """A plain ``jax.profiler`` session, as the benchmark opens it."""
+    import contextlib
+    import jax
+
+    @contextlib.contextmanager
+    def session():
+        options = jax.profiler.ProfileOptions()
+        options.python_tracer_level = 0
+        jax.profiler.start_trace(str(trace_dir),
+                                 profiler_options=options)
+        try:
+            yield
+        finally:
+            jax.profiler.stop_trace()
+    return session()
+
+
+def test_span_with_mx_profiler_running_writes_one_annotation(tmp_path):
     from mxnet_tpu import profiler
     profiler.set_config(filename=str(tmp_path / 'p.json'),
                         aggregate_stats=True)
@@ -221,7 +258,181 @@ def test_span_unifies_with_profiler_scope(tmp_path):
         table = profiler.aggregate_stats(reset=True)
     finally:
         profiler.set_state('stop')
-    assert 'phase:sync' in table
+    # the chrome trace keeps its phase:* row ...
+    assert table['phase:sync']['count'] == 1
+    assert table['phase:sync']['category'] == 'user'
+    # ... and the profiler's own trace holds the span once, under the
+    # span's own name (not a second time through profiler.scope)
+    names = [n for _, n, *_ in _host_events(tmp_path / 'p_xplane')]
+    assert names.count('sync') == 1
+    assert names.count('phase:sync') == 0
+
+
+def test_span_reaches_a_plain_jax_profiler_trace_with_its_arguments(
+        tmp_path):
+    from mxnet_tpu import profiler
+    assert not profiler.is_running()
+    child = spans.phase_histogram('checkpoint')
+    before = child.count
+    with _jax_trace(tmp_path):
+        with spans.span('checkpoint', step=7, wall=12.5):
+            pass
+    hits = [e for e in _host_events(tmp_path) if e[1] == 'checkpoint']
+    assert len(hits) == 1
+    assert hits[0][4] == {'step': 7, 'wall': 12.5}
+    # the arguments went to the annotation only: one histogram child
+    assert child.count == before + 1
+    assert spans.phase_histogram('checkpoint') is child
+
+
+def test_phases_lists_the_spans_the_package_opens():
+    assert len(set(spans.PHASES)) == len(spans.PHASES)
+    assert {'data_wait', 'compile', 'train.dispatch', 'eng.wait_work',
+            'eng.tick', 'eng.tick.emit'} <= set(spans.PHASES)
+
+
+# ---------------------------------------------------------------------------
+# the decode scheduler's tick on the profiler's clock; program names
+# ---------------------------------------------------------------------------
+
+def _toy_lm(layers=2, max_len=48):
+    from mxnet_tpu.serving.decode import init_transformer_lm
+    return init_transformer_lm(vocab=23, units=16, hidden=24,
+                               layers=layers, heads=4, max_len=max_len,
+                               seed=0)
+
+
+def test_engine_tick_and_its_phases_in_a_profiler_trace(tmp_path):
+    """A jax.profiler trace of a few ticks behind the HTTP server:
+    eng.tick with its phases nested inside it on one thread, no phase
+    twice under one tick (eng.tick.admit apart: one an admission), and
+    nothing from a handler thread."""
+    import urllib.request
+    from mxnet_tpu import serving
+    from mxnet_tpu.serving.decode import PagedDecodeProgram
+    from mxnet_tpu.serving.server import ServingHTTPServer
+    model, params = _toy_lm()
+    prog = PagedDecodeProgram(model, params, slots=4,
+                              prefill_buckets=(4, 8), page_size=8)
+    sess = serving.InferenceSession(prog, watchdog=False)
+    with ServingHTTPServer(sess, 0) as srv:
+        def post(prompt):
+            req = urllib.request.Request(
+                'http://127.0.0.1:%d/generate' % srv.port,
+                data=json.dumps({'tokens': prompt, 'max_new_tokens': 8,
+                                 'stream': True}).encode(),
+                headers={'Content-Type': 'application/json'})
+            with urllib.request.urlopen(req, timeout=60) as resp:
+                return resp.read()
+        post([1, 2, 3])                     # compile outside the trace
+        with _jax_trace(tmp_path):
+            threads = [threading.Thread(target=post, args=(p,))
+                       for p in ([5, 11, 7], [3, 1, 4, 1, 5], [9, 9])]
+            for t in threads:
+                t.start()
+            for t in threads:
+                t.join(60)
+            assert not any(t.is_alive() for t in threads)
+    sess.close()
+    events = _host_events(tmp_path)
+    ours = [e for e in events if e[1].startswith(('eng.', 'srv.'))]
+    assert {e[1] for e in ours} >= {
+        'eng.tick', 'eng.tick.retire', 'eng.tick.migrate',
+        'eng.tick.admit', 'eng.tick.page_faults',
+        'eng.tick.build_inputs', 'eng.tick.dispatch',
+        'eng.tick.read_tokens', 'eng.tick.emit', 'eng.tick.telemetry'}
+    assert not any(e[1].startswith('srv.') for e in ours)
+    assert len({e[0] for e in ours}) == 1      # the worker thread alone
+    ticks = [e for e in ours if e[1] == 'eng.tick']
+    assert len(ticks) >= 5
+    steps = [t[4]['step'] for t in ticks]
+    assert steps == sorted(steps) and all(t[4]['wall'] > 1e9
+                                          for t in ticks)
+    phases = [e for e in ours if e[1].startswith('eng.tick.')]
+    for _, _, a, b, _ in ticks:
+        inside = [p for p in phases if a <= p[2] and p[3] <= b]
+        # direct children: those no other phase of the tick contains
+        # (a prefill's dispatch, read and emit lie inside its
+        # eng.tick.admit, of which a tick has one an admission)
+        direct = [p[1] for p in inside
+                  if not any(q is not p and q[2] <= p[2] and p[3] <= q[3]
+                             for q in inside)]
+        once = [n for n in direct if n != 'eng.tick.admit']
+        assert len(once) == len(set(once)), direct
+        assert direct.count('eng.tick.admit') <= 4      # the slots
+        assert direct[0] == 'eng.tick.retire'
+        assert direct[-1] == 'eng.tick.telemetry'
+    # every phase lies inside some tick (the trace may have opened or
+    # closed in the middle of one, whose own span it then lacks)
+    lo, hi = ticks[0][2], ticks[-1][3]
+    assert all(any(a <= p[2] and p[3] <= b for _, _, a, b, _ in ticks)
+               for p in phases if lo <= p[2] and p[3] <= hi)
+
+
+def _module_names(prog):
+    import re
+    return sorted(re.match(r'HloModule (jit_\w+)', c.as_text()).group(1)
+                  for c in prog._compiled.values())
+
+
+def test_decode_programs_have_names_and_only_the_step_is_jit_fn():
+    import re
+    from mxnet_tpu.serving.decode import (DecodeEngine, DecodeProgram,
+                                          PagedDecodeProgram)
+    model, params = _toy_lm()
+    paged = PagedDecodeProgram(model, params, slots=2,
+                               prefill_buckets=(4, 8), page_size=8,
+                               spec_k=2).warmup()
+    names = _module_names(paged)
+    assert names == ['jit_fn_step', 'jit_page_copy', 'jit_prefill_b4',
+                     'jit_prefill_b8', 'jit_verify_k2']
+    assert [n for n in names if re.search('^jit_fn', n)] \
+        == ['jit_fn_step']
+    # the artifact's keys are the program keys, not the module names
+    assert sorted(paged._compiled) == ['copy', 'prefill:4', 'prefill:8',
+                                       'step', 'verify:3']
+    slot = DecodeProgram(model, params, slots=2, prefill_buckets=(4,))
+    assert _module_names(slot.warmup()) == ['jit_fn_step',
+                                            'jit_prefill_b4']
+    # a draft's programs say so, and leave ^jit_fn to the target's step
+    draft = DecodeProgram(model, params, slots=2, prefill_buckets=(4, 8))
+    eng = DecodeEngine(paged, draft=draft)
+    try:
+        assert _module_names(draft.warmup()) == [
+            'jit_draft_prefill_b4', 'jit_draft_prefill_b8',
+            'jit_draft_step']
+    finally:
+        eng.close()
+
+
+@pytest.fixture
+def fresh_compiles():
+    """The persistent compile cache keys a program without its
+    metadata, so a hit hands back whatever ``op_name``s the program had
+    when it was first compiled: compile afresh to read this tree's."""
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache
+    jax.config.update('jax_enable_compilation_cache', False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update('jax_enable_compilation_cache', True)
+    compilation_cache.reset_cache()
+
+
+def test_decode_step_operations_carry_their_scope(fresh_compiles):
+    from mxnet_tpu.serving.decode import PagedDecodeProgram
+    model, params = _toy_lm()
+    prog = PagedDecodeProgram(model, params, slots=2,
+                              prefill_buckets=(4,), page_size=8)
+    text = prog.compile_step().as_text()
+    for scope in ('embed', 'layer0/attn', 'layer0/kv_gather',
+                  'layer0/ffn', 'layer1/attn', 'layer1/kv_gather',
+                  'layer1/ffn', 'lm_head', 'sampling'):
+        assert 'op_name="jit(fn_step)/%s/' % scope in text, scope
+    prefill = prog.compile_prefill(4).as_text()
+    for scope in ('embed', 'layer1/attn', 'layer1/ffn', 'lm_head',
+                  'sampling'):
+        assert 'op_name="jit(prefill_b4)/%s/' % scope in prefill, scope
 
 
 # ---------------------------------------------------------------------------

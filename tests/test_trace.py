@@ -500,3 +500,109 @@ def test_tracing_off_forwards_nothing_and_streams_identically():
         gw.stop()
         a.close()
         b.close()
+
+
+# ---------------------------------------------------------------------------
+# a real replica: a request without a parent gets a root span
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize('header,parent', [
+    ('00-' + 'ab' * 16 + '-' + 'cd' * 8 + '-01', 'cd' * 8),
+    ('00-' + 'ab' * 16 + '-' + '0' * 16 + '-01', None),
+    (None, None),
+    ('garbage', None),
+])
+def test_inbound_context_parent_or_fresh_root(traced, header, parent):
+    headers = {} if header is None else {trace.TRACE_HEADER: header}
+    ctx = trace.inbound(headers)
+    assert ctx.span_id == parent and ctx.parent_id is None
+    if header and header != 'garbage':
+        assert ctx.trace_id == 'ab' * 16
+    else:       # nothing usable arrived: an identity of its own
+        assert len(ctx.trace_id) == 32
+        assert ctx.trace_id != trace.inbound(headers).trace_id
+
+
+def test_inbound_is_none_and_reads_no_header_when_tracing_is_off():
+    class Untouchable:
+        def get(self, key):
+            raise AssertionError('header read with tracing off')
+    assert not trace.enabled()
+    assert trace.inbound(Untouchable()) is None
+
+
+@pytest.fixture(scope='module')
+def replica():
+    """A toy paged decoder behind the real HTTP server."""
+    from mxnet_tpu import serving
+    from mxnet_tpu.serving.decode import (PagedDecodeProgram,
+                                          init_transformer_lm)
+    from mxnet_tpu.serving.server import ServingHTTPServer
+    model, params = init_transformer_lm(vocab=23, units=16, hidden=24,
+                                        layers=2, heads=4, max_len=48,
+                                        seed=0)
+    prog = PagedDecodeProgram(model, params, slots=4,
+                              prefill_buckets=(4, 8), page_size=8)
+    sess = serving.InferenceSession(prog, watchdog=False)
+    srv = ServingHTTPServer(sess, 0).start()
+    yield srv
+    srv.stop()
+    sess.close()
+
+
+def _replica_spans(srv, want, timeout=5.0):
+    """``srv.generate`` closes after the client has its last line."""
+    import time
+    deadline = time.monotonic() + timeout
+    while time.monotonic() < deadline:
+        recs = srv._trace_buf.read()
+        if sum(r['name'] == 'srv.generate' for r in recs) >= want:
+            return recs
+        time.sleep(0.02)
+    return srv._trace_buf.read()
+
+
+def test_headerless_generate_is_one_rooted_trace_a_request(replica,
+                                                            traced):
+    replica._trace_buf.clear()
+    for prompt in ([5, 11, 7], [3, 1, 4, 1, 5]):
+        tokens, done = _stream(replica.port,
+                               {'tokens': prompt, 'max_new_tokens': 4,
+                                'stream': True})
+        assert len(tokens) == 4 and done['finish_reason'] == 'length'
+    trees = trace.stitch(_replica_spans(replica, 2))
+    assert len(trees) == 2                  # one trace a request
+    for tree in trees.values():
+        assert trace.tree_verdict(tree) is True
+        by_name = {r['name']: r for r in tree['spans'].values()}
+        assert set(by_name) >= {'srv.generate', 'eng.queue_wait',
+                                'eng.prefill', 'eng.first_token',
+                                'eng.steps'}
+        root = by_name['srv.generate']
+        assert root['parent'] is None
+        assert tree['roots'] == [root['span']]
+        # the tick that admitted it: what the request's spans share
+        # with the eng.tick span on the profiler's clock
+        tick = by_name['eng.queue_wait']['attrs']['tick']
+        assert isinstance(tick, int) and tick >= 0
+        assert by_name['eng.prefill']['attrs']['tick'] == tick
+
+
+def test_headerless_generate_with_tracing_off_builds_nothing(
+        replica, monkeypatch):
+    assert not trace.enabled()
+    replica._trace_buf.clear()
+    built = []
+    real = trace.TraceContext.__init__
+
+    def counting(self, *a, **kw):
+        built.append(a)
+        real(self, *a, **kw)
+    monkeypatch.setattr(trace.TraceContext, '__init__', counting)
+    tokens, done = _stream(replica.port,
+                           {'tokens': [5, 11, 7], 'max_new_tokens': 3,
+                            'stream': True})
+    assert len(tokens) == 3 and done['finish_reason'] == 'length'
+    assert built == []
+    assert replica._trace_buf.read() == []

@@ -307,6 +307,12 @@ def serving_instruments():
                 'mxnet_tpu_serve_sampled_tokens_total',
                 help='tokens emitted under temperature>0 sampling '
                      '(greedy traffic is tokens_total minus this)'),
+            sampled_steps=counter(
+                'mxnet_tpu_serve_sampled_steps_total',
+                help='decode steps whose batch held a live slot with '
+                     'temperature>0, so the program ran the nucleus '
+                     'pipeline (decode_steps_total minus this ran '
+                     'the greedy argmax alone)'),
         )
     return _serving_inst
 

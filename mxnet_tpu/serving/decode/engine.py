@@ -101,6 +101,13 @@ def _record_event(kind, **fields):
         pass
 
 
+def _samples(active):
+    """1 when a live slot of ``active`` asks for a temperature above
+    0, so that the program it ran took the sampler's nucleus branch
+    (sampling.sample_tokens), else 0 — what ``sampled_steps`` books."""
+    return int(any(seq.temperature > 0 for seq in active.values()))
+
+
 def _flight_dump(reason):
     try:
         from ... import observability as _obs
@@ -324,7 +331,8 @@ class DecodeEngine:
                         'migrated_out': 0, 'migrated_in': 0,
                         'prefill_exports': 0,
                         'handoff_pages': 0, 'drain_timeouts': 0,
-                        'sampled_tokens': 0, 'adapter_rejects': 0}
+                        'sampled_tokens': 0, 'sampled_steps': 0,
+                        'adapter_rejects': 0}
         # live-migration requests serviced by the worker at tick
         # boundaries (the only thread that owns the device cache):
         # (op, arg, result_box, done_event)
@@ -1354,14 +1362,18 @@ class DecodeEngine:
 
     def _emit_step(self, active, toks, dt):
         """Book the step and hand each live slot its token."""
+        sampled_step = _samples(active)
         with self._lock:
             self._counts['steps'] += 1
+            self._counts['sampled_steps'] += sampled_step
             self._counts['tokens'] += len(active)
             self._ema_step_s = dt if self._ema_step_s is None \
                 else 0.7 * self._ema_step_s + 0.3 * dt
         inst = _serving_instruments()
         if inst is not None:
             inst.decode_steps.inc()
+            if sampled_step:
+                inst.sampled_steps.inc()
             inst.tokens.inc(len(active))
             inst.tpot.observe(dt)
         sampled = 0
@@ -1473,6 +1485,7 @@ class DecodeEngine:
         """Advance positions, stream each slot's token, book the step."""
         emitted = 0
         sampled = 0
+        sampled_step = _samples(active)
         for slot, seq in active.items():
             if seq.stream.done() or seq.stream._cancelled:
                 continue            # retired at the next tick
@@ -1495,6 +1508,7 @@ class DecodeEngine:
                 self._retire(slot, seq, reason)
         with self._lock:
             self._counts['steps'] += 1
+            self._counts['sampled_steps'] += sampled_step
             self._counts['tokens'] += emitted
             self._counts['sampled_tokens'] += sampled
             self._ema_step_s = dt if self._ema_step_s is None \
@@ -1502,6 +1516,8 @@ class DecodeEngine:
         inst = _serving_instruments()
         if inst is not None:
             inst.decode_steps.inc()
+            if sampled_step:
+                inst.sampled_steps.inc()
             inst.tokens.inc(emitted)
             inst.tpot.observe(dt)
             if sampled:
@@ -1590,6 +1606,7 @@ class DecodeEngine:
         sampled_total = 0
         accepted_total = 0
         proposed_total = 0
+        sampled_step = _samples(active)
         for slot, seq in active.items():
             if seq.stream.done() or seq.stream._cancelled:
                 continue            # its proposals were never judged
@@ -1632,6 +1649,7 @@ class DecodeEngine:
                 self._retire(slot, seq, reason)
         with self._lock:
             self._counts['steps'] += 1
+            self._counts['sampled_steps'] += sampled_step
             self._counts['spec_rounds'] += 1
             self._counts['spec_proposed'] += proposed_total
             self._counts['spec_accepted'] += accepted_total
@@ -1642,6 +1660,8 @@ class DecodeEngine:
         inst = _serving_instruments()
         if inst is not None:
             inst.decode_steps.inc()
+            if sampled_step:
+                inst.sampled_steps.inc()
             inst.tokens.inc(emitted_total)
             inst.tpot.observe(dt)
             inst.spec_proposed.inc(proposed_total)

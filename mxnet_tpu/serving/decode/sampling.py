@@ -17,6 +17,20 @@ so switching a slot between greedy and sampled traffic — or changing
 temperature mid-stream — is a plain array-value change, never a
 retrace.
 
+**The sampler costs what the batch asks.** The compiled program
+decides from the ``temps`` values it is handed: the greedy argmax is
+always taken, and everything else (softmax, the Gumbel draw, the sort
+over the vocabulary) sits in one branch of a ``lax.cond`` on
+``any(temps > 0)``, so a step in which no row samples runs the argmax
+alone. The branch that samples makes one stable sort by descending
+probability that carries the vocabulary ids and the perturbed scores
+``logp + gumbel`` along, takes the running sum and the top-p cut in
+that order, and picks the best kept score there — the smallest
+vocabulary id among equal maxima, as an argmax in vocabulary order
+would — so nothing is gathered into the sorted order or scattered back
+out of it. The engine books the steps that took that branch as
+``sampled_steps`` in ``decode.counts``.
+
 Three contracts the tests pin down:
 
 **Greedy stays byte-identical.** The emitted token is
@@ -89,10 +103,11 @@ def sample_tokens(logits, temps, top_ps, keys, masks=None):
     uncompiled test reference).
 
     Gumbel-max over the top-p-truncated, temperature-scaled
-    distribution: deterministic in (key, logits), exactly the
-    renormalized nucleus distribution in law, and a single argmax on
-    the accelerator — no host round-trip, no sort-free rejection loop.
-    Rows with ``temps == 0`` take the greedy branch byte-for-byte.
+    distribution: deterministic in (key, logits) and exactly the
+    renormalized nucleus distribution in law, with no host round-trip.
+    Rows with ``temps == 0`` take the greedy branch byte-for-byte, and
+    a batch in which no row samples runs the greedy argmax alone (see
+    the module docstring, "The sampler costs what the batch asks").
     """
     import jax
     import jax.numpy as jnp
@@ -104,23 +119,37 @@ def sample_tokens(logits, temps, top_ps, keys, masks=None):
             logits = logits + masks
         greedy = jnp.argmax(logits, axis=-1).astype('int32')
         temps = jnp.asarray(temps, 'float32')
-        top_ps = jnp.asarray(top_ps, 'float32')
-        safe_t = jnp.where(temps > 0, temps, 1.0)
-        logp = jax.nn.log_softmax(logits / safe_t[:, None], axis=-1)
-        probs = jnp.exp(logp)
-        # nucleus: keep the smallest prefix of the descending-prob order
-        # whose mass reaches top_p. (csum - p) < top_p keeps the first
-        # token unconditionally (0 < top_p), so the filter can never
-        # empty a row.
-        order = jnp.argsort(-probs, axis=-1)
-        sorted_p = jnp.take_along_axis(probs, order, axis=-1)
-        csum = jnp.cumsum(sorted_p, axis=-1)
-        keep_sorted = (csum - sorted_p) < top_ps[:, None]
-        rows = jnp.arange(logits.shape[0])[:, None]
-        keep = jnp.zeros(logits.shape, bool).at[rows, order].set(keep_sorted)
-        filtered = jnp.where(keep, logp, -jnp.inf)
-        gumbel = jax.vmap(
-            lambda k, shape=logits.shape[1:]: jax.random.gumbel(k, shape)
-        )(jnp.asarray(keys, 'uint32'))
-        sampled = jnp.argmax(filtered + gumbel, axis=-1).astype('int32')
-        return jnp.where(temps > 0, sampled, greedy).astype('int32')
+        return jax.lax.cond(
+            jnp.any(temps > 0), _nucleus_tokens,
+            lambda logits, greedy, *_: greedy,
+            logits, greedy, temps, jnp.asarray(top_ps, 'float32'),
+            jnp.asarray(keys, 'uint32'))
+
+
+def _nucleus_tokens(logits, greedy, temps, top_ps, keys):
+    """The sampling branch of :func:`sample_tokens`: every row's
+    nucleus draw, ``greedy`` kept for the rows with ``temps == 0``."""
+    import jax
+    import jax.numpy as jnp
+    vocab = logits.shape[-1]
+    safe_t = jnp.where(temps > 0, temps, 1.0)
+    logp = jax.nn.log_softmax(logits / safe_t[:, None], axis=-1)
+    gumbel = jax.vmap(lambda k: jax.random.gumbel(k, (vocab,)))(keys)
+    # the ids and the perturbed scores ride the sort: no gather into
+    # the sorted order, no scatter back out of it
+    neg_p, order, z = jax.lax.sort(
+        (-jnp.exp(logp), jax.lax.broadcasted_iota('int32', logits.shape, 1),
+         logp + gumbel), dimension=1, is_stable=True, num_keys=1)
+    sorted_p = -neg_p
+    # nucleus: keep the smallest prefix of the descending-prob order
+    # whose mass reaches top_p. (csum - p) < top_p keeps the first
+    # token unconditionally (0 < top_p), so the filter can never
+    # empty a row.
+    csum = jnp.cumsum(sorted_p, axis=-1)
+    keep = (csum - sorted_p) < top_ps[:, None]
+    z = jnp.where(keep, z, -jnp.inf)
+    # argmax in sorted space; among equal maxima the smallest
+    # vocabulary id, as an argmax in vocabulary order would take
+    best = jnp.max(z, axis=-1, keepdims=True)
+    sampled = jnp.min(jnp.where(z == best, order, vocab - 1), axis=-1)
+    return jnp.where(temps > 0, sampled, greedy).astype('int32')

@@ -402,6 +402,173 @@ def test_sample_tokens_temp0_is_greedy_and_mask_hook_applies():
     assert out2[0] != logits[0].argmax()
 
 
+def _sample_tokens_parent(logits, temps, top_ps, keys, masks=None):
+    """The sampler as it stood before the gate (argsort, gather,
+    cumsum, scatter, argmax in vocabulary order): the plain reference
+    ``sample_tokens`` has to match token for token."""
+    import jax
+    import jax.numpy as jnp
+    logits = jnp.asarray(logits)
+    if masks is not None:
+        logits = logits + masks
+    greedy = jnp.argmax(logits, axis=-1).astype('int32')
+    temps = jnp.asarray(temps, 'float32')
+    top_ps = jnp.asarray(top_ps, 'float32')
+    safe_t = jnp.where(temps > 0, temps, 1.0)
+    logp = jax.nn.log_softmax(logits / safe_t[:, None], axis=-1)
+    probs = jnp.exp(logp)
+    order = jnp.argsort(-probs, axis=-1)
+    sorted_p = jnp.take_along_axis(probs, order, axis=-1)
+    csum = jnp.cumsum(sorted_p, axis=-1)
+    keep_sorted = (csum - sorted_p) < top_ps[:, None]
+    rows = jnp.arange(logits.shape[0])[:, None]
+    keep = jnp.zeros(logits.shape, bool).at[rows, order].set(keep_sorted)
+    filtered = jnp.where(keep, logp, -jnp.inf)
+    gumbel = jax.vmap(
+        lambda k, shape=logits.shape[1:]: jax.random.gumbel(k, shape)
+    )(jnp.asarray(keys, 'uint32'))
+    sampled = jnp.argmax(filtered + gumbel, axis=-1).astype('int32')
+    return jnp.where(temps > 0, sampled, greedy).astype('int32')
+
+
+def _sampler_rows(shape, rows, seed):
+    """Logits for the equivalence cases. ``tied``: a few levels only,
+    so equal probabilities sit wherever the nucleus ends; the first
+    row is flat (every entry tied), the second holds its maximum at
+    five ids."""
+    n, vocab = shape
+    rs = np.random.RandomState(seed)
+    if rows == 'random':
+        return (3.0 * rs.randn(n, vocab)).astype('float32')
+    logits = rs.randint(0, 4, size=shape).astype('float32')
+    logits[0] = 1.0
+    logits[1, rs.choice(vocab, 5, replace=False)] = 6.0
+    return logits
+
+
+_TEMPS = {'greedy': lambda n: np.zeros(n, 'float32'),
+          'sampled': lambda n: np.linspace(0.3, 1.5, n).astype('float32'),
+          'mixed': lambda n: np.where(np.arange(n) % 3 == 1, 0.8,
+                                      0.0).astype('float32')}
+
+
+@pytest.mark.parametrize('rows', ['random', 'tied'])
+@pytest.mark.parametrize('with_masks', [False, True])
+@pytest.mark.parametrize('batch', ['greedy', 'sampled', 'mixed'])
+@pytest.mark.parametrize('top_p', [0.1, 0.5, 0.9, 1.0])
+@pytest.mark.parametrize('shape', [(4, 9), (16, 4099)])
+def test_sample_tokens_equals_parent(shape, top_p, batch, with_masks,
+                                     rows):
+    import jax
+    n, vocab = shape
+    logits = _sampler_rows(shape, rows, seed=vocab + int(10 * top_p))
+    temps = _TEMPS[batch](n)
+    top_ps = np.full(n, top_p, 'float32')
+    keys = np.stack([key_for(5, p) for p in range(n)])
+    masks = None
+    if with_masks:
+        # forbid each row's best id and every fourth id besides
+        masks = np.zeros(shape, 'float32')
+        masks[np.arange(n), logits.argmax(-1)] = -np.inf
+        masks[:, ::4] = -1e9
+    got = np.asarray(jax.jit(sample_tokens)(logits, temps, top_ps,
+                                            keys, masks))
+    want = np.asarray(jax.jit(_sample_tokens_parent)(
+        logits, temps, top_ps, keys, masks))
+    assert got.dtype == want.dtype == np.int32
+    assert got.tolist() == want.tolist()
+    if batch != 'greedy' and top_p == 1.0 and vocab == 4099:
+        live = logits if masks is None else logits + masks
+        assert (got != live.argmax(-1)).any(), 'nothing was sampled'
+
+
+@pytest.mark.parametrize('top_p', [0.5, 1.0])
+def test_sample_tokens_tied_scores_take_smallest_id(monkeypatch, top_p):
+    """Equal perturbed scores: with the Gumbel noise held at zero the
+    score is the log-probability, so tied logits tie the argmax, and
+    the smallest vocabulary id among the kept maxima has to win as in
+    the parent's argmax over the vocabulary order."""
+    import jax
+    import jax.numpy as jnp
+    monkeypatch.setattr(jax.random, 'gumbel',
+                        lambda key, shape: jnp.zeros(shape, 'float32'))
+    logits = _sampler_rows((6, 37), 'tied', seed=3)
+    temps = np.full(6, 0.7, 'float32')
+    top_ps = np.full(6, top_p, 'float32')
+    keys = np.zeros((6, 2), 'uint32')
+    got = np.asarray(sample_tokens(logits, temps, top_ps, keys))
+    want = np.asarray(_sample_tokens_parent(logits, temps, top_ps,
+                                            keys))
+    assert got.tolist() == want.tolist()
+    assert got[0] == 0 and logits[1, got[1]] == 6.0
+
+
+def _primitives(jaxpr, skip=()):
+    """Names of every primitive in ``jaxpr`` and below, the bodies of
+    the primitives in ``skip`` left out."""
+    names = []
+    for eqn in jaxpr.eqns:
+        names.append(eqn.primitive.name)
+        if eqn.primitive.name in skip:
+            continue
+        for val in eqn.params.values():
+            for sub in (val if isinstance(val, (list, tuple))
+                        else [val]):
+                sub = getattr(sub, 'jaxpr', sub)
+                if hasattr(sub, 'eqns'):
+                    names.extend(_primitives(sub, skip))
+    return names
+
+
+@pytest.mark.parametrize('with_masks', [False, True])
+def test_sample_tokens_pays_for_the_nucleus_inside_a_cond_only(
+        with_masks):
+    import jax
+    n, vocab = 4, 9
+    args = [np.zeros((n, vocab), 'float32'), np.zeros(n, 'float32'),
+            np.ones(n, 'float32'), np.zeros((n, 2), 'uint32')]
+    if with_masks:
+        args.append(np.zeros((n, vocab), 'float32'))
+    jaxpr = jax.make_jaxpr(sample_tokens)(*args).jaxpr
+    outside = _primitives(jaxpr, skip=('cond',))
+    assert outside.count('cond') == 1
+    costly = [p for p in outside
+              if p.startswith(('sort', 'gather', 'scatter', 'cumsum',
+                               'exp', 'random', 'threefry'))]
+    assert costly == [], costly
+    # the branch that samples: one sort carries ids and scores along,
+    # so nothing is gathered into that order or scattered out of it
+    inside = _primitives(jaxpr)
+    assert inside.count('sort') == 1
+    assert not [p for p in inside
+                if p.startswith(('gather', 'scatter'))]
+
+
+def test_sampled_steps_counts_the_steps_that_held_a_sampler(
+        slot_extras, paged_prog, draft_prog):
+    for prog, kw in ((slot_extras, {}), (paged_prog, {}),
+                     (paged_prog, {'draft': draft_prog})):
+        with DecodeEngine(prog, name='ss', **kw) as eng:
+            for s in [eng.generate([2 + i, 9, 4], max_new_tokens=6)
+                      for i in range(3)]:
+                list(s)
+            c0 = eng.stats()['counts']
+            assert c0['steps'] > 0 and c0['sampled_steps'] == 0
+            # the sampler is admitted first and decodes longest, so
+            # every step of this stretch holds it among greedy rows
+            streams = [eng.generate(PROMPT, max_new_tokens=12,
+                                    temperature=0.8, top_p=0.9,
+                                    seed=3)]
+            streams += [eng.generate([2 + i, 9, 4], max_new_tokens=5)
+                        for i in range(2)]
+            for s in streams:
+                list(s)
+            c1 = eng.stats()['counts']
+            assert c1['steps'] > c0['steps']
+            assert c1['sampled_steps'] == c1['steps'] - c0['steps']
+            assert c1['sampled_tokens'] == 12
+
+
 # ---------------------------------------------------------------------------
 # prefix isolation + migration
 # ---------------------------------------------------------------------------

@@ -42,7 +42,8 @@ PHASES = (
     # the decode scheduler's worker thread (serving/decode/engine.py)
     'eng.wait_work', 'eng.tick', 'eng.tick.retire', 'eng.tick.migrate',
     'eng.tick.admit', 'eng.tick.prefix_register',
-    'eng.tick.page_faults', 'eng.tick.build_inputs',
+    'eng.tick.page_faults', 'eng.tick.release_window',
+    'eng.tick.build_inputs',
     'eng.tick.dispatch', 'eng.tick.read_tokens', 'eng.tick.after_call',
     'eng.tick.emit', 'eng.tick.telemetry',
 )

@@ -20,6 +20,8 @@ Module map: ``cache`` (slot-addressed preallocated caches + O(1)
 update helpers), ``model`` (RNN-LM and causal-transformer families —
 one math path shared by prefill, step, and the uncached reference so
 cached decode is bit-identical to the whole-sequence forward),
+``cohere2`` (the ``cohere2_moe`` family: parallel attention + expert
+block, sliding-window and full layers in one paged cache manager),
 ``program`` (AOT compile + frozen.v1 persistence + CPU fallback),
 ``engine`` (continuous batching, admission control, breaker/watchdog
 at site ``serving.decode``).
@@ -28,9 +30,11 @@ from __future__ import annotations
 
 from .cache import CacheSpec, cache_bytes, init_cache, write_position, \
     write_slot
+from .cohere2 import Cohere2MoELM, init_cohere2_moe_lm
 from .engine import DecodeEngine, DrainTimeout, GenerateStream
-from .model import (DecodeModel, RNNLM, TransformerLM, from_gluon_rnn_lm,
-                    init_rnn_lm, init_transformer_lm, model_from_config)
+from .model import (DecodeModel, FamilyUnsupported, RNNLM, TransformerLM,
+                    from_gluon_rnn_lm, init_rnn_lm, init_transformer_lm,
+                    model_from_config)
 from .paged import (PageAllocator, PagedCacheSpec, PrefixCache,
                     pool_bytes)
 from .program import (DecodeProgram, PagedDecodeProgram, freeze_decode,
@@ -40,7 +44,8 @@ from .seqstate import SEQSTATE_SCHEMA, SeqStateError
 __all__ = [
     'CacheSpec', 'cache_bytes', 'init_cache', 'write_position',
     'write_slot', 'DecodeEngine', 'DrainTimeout', 'GenerateStream',
-    'DecodeModel', 'RNNLM', 'TransformerLM', 'from_gluon_rnn_lm',
+    'DecodeModel', 'RNNLM', 'TransformerLM', 'Cohere2MoELM',
+    'init_cohere2_moe_lm', 'FamilyUnsupported', 'from_gluon_rnn_lm',
     'init_rnn_lm', 'init_transformer_lm', 'model_from_config',
     'DecodeProgram', 'PagedDecodeProgram', 'PageAllocator',
     'PagedCacheSpec', 'PrefixCache', 'pool_bytes', 'freeze_decode',

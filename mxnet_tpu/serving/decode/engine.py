@@ -64,7 +64,8 @@ import numpy as onp
 
 from ...observability.spans import span as _span
 from ..batcher import BackpressureError, BatcherClosed, RequestTimeout
-from .paged import TRASH_PAGE, PageAllocator, PrefixCache, pages_for
+from .paged import (TRASH_PAGE, PageAllocator, PrefixCache, pages_for,
+                    pool_bytes)
 from .sampling import key_for
 from .seqstate import SeqStateError, build_payload, decode_payload
 
@@ -144,6 +145,7 @@ class GenerateStream:
         self._done = threading.Event()
         self._exc = None
         self._cancelled = False
+        self._outbox = None             # the admitting engine's
 
     # -- consumer side -----------------------------------------------------
 
@@ -181,7 +183,7 @@ class GenerateStream:
 
     def _emit(self, token):
         self.tokens.append(int(token))
-        self._q.put(int(token))
+        self._put(int(token))
 
     def _finish(self, reason, exc=None):
         if self._done.is_set():
@@ -189,7 +191,51 @@ class GenerateStream:
         self.finish_reason = reason
         self._exc = exc
         self._done.set()
-        self._q.put(_DONE)
+        self._put(_DONE)
+
+    def _put(self, item):
+        """Hand ``item`` to whoever iterates this stream: at once, or,
+        from the scheduler thread of the engine that admitted it,
+        through that engine's outbox (:class:`_Outbox`)."""
+        box = self._outbox
+        if box is None or not box.take(self._q, item):
+            self._q.put(item)
+
+
+class _Outbox:
+    """What the scheduler thread has emitted and its streams' readers
+    have not been woken for yet.
+
+    Waking a reader costs the scheduler the interpreter lock: a step
+    that ends by waking 128 HTTP handler threads prepares the next
+    device call while they, and their client, work through their
+    tokens. So the scheduler thread's tokens and end marks wait here,
+    in order, and go to their queues right after the next program is
+    enqueued (``program.while_device_runs``), when the device has work
+    and the scheduler is about to block on it; or at the end of a tick
+    that enqueued nothing, or when the engine falls idle or hands its
+    sequences to other threads. ``stream.tokens``, ``finish_reason``
+    and ``done()`` are up to date at once: only the wake-up waits.
+    Other threads (the reaper, ``close``, the CPU fallback) put
+    directly."""
+
+    def __init__(self):
+        self.owner = None               # the scheduler thread's ident
+        self.items = []
+        self.flushed = False            # since the tick began
+
+    def take(self, queue, item):
+        if threading.get_ident() != self.owner:
+            return False
+        self.items.append((queue, item))
+        return True
+
+    def flush(self):
+        """Scheduler thread only."""
+        self.flushed = True
+        items, self.items = self.items, []
+        for queue, item in items:
+            queue.put(item)
 
 
 class _Seq:
@@ -199,7 +245,7 @@ class _Seq:
                  'pos', 'last_token', 'enqueued_at', 'deadline_at',
                  'first_token_at', 'table', 'pages', 'prefill_only',
                  'trace', 'adapter_id', 'adapter_idx', 'temperature',
-                 'top_p', 'seed')
+                 'top_p', 'seed', 'wtable', 'wpages', 'wtop')
 
     def __init__(self, stream, prompt, max_new, eos_id, enqueued_at,
                  deadline_at, prefill_only=False, adapter_id=None,
@@ -219,6 +265,13 @@ class _Seq:
         # sequence holds allocator refs on
         self.table = None
         self.pages = []
+        # a model with sliding-window layers: their ring table
+        # (window_pages columns, logical page p in column p %
+        # window_pages), the window pool's pages this sequence holds,
+        # and the highest logical page the ring has been given
+        self.wtable = None
+        self.wpages = []
+        self.wtop = -1
         # disaggregated serving: export the seqstate at the prefill
         # boundary instead of entering the step loop
         self.prefill_only = prefill_only
@@ -341,14 +394,35 @@ class DecodeEngine:
         self.paged = bool(getattr(program, 'paged', False))
         self._allocator = None
         self._prefix = None
+        # two kinds of layer in one manager: the sliding-window
+        # layers' pools have an allocator and a prefix registry of
+        # their own (page ids index other pools); 0 columns = the
+        # model has one kind and none of this exists
+        self._wcols = int(getattr(program, 'window_pages', 0) or 0) \
+            if self.paged else 0
+        self._wallocator = None
+        self._wprefix = None
         if self.paged:
             self._allocator = PageAllocator(program.pages)
+            if self._wcols:
+                self._wallocator = PageAllocator(
+                    program.window_pool_pages)
+                self._counts['window_pages_released'] = 0
             if prefix_cache is None:
                 prefix_cache = bool(
                     _knob('MXNET_TPU_SERVE_PREFIX_CACHE', True))
             if prefix_cache:
                 self._prefix = PrefixCache(program.page_size,
                                            self._allocator)
+                if self._wcols:
+                    self._wprefix = PrefixCache(program.page_size,
+                                                self._wallocator)
+        # device-side counts the step program brings back behind its
+        # tokens (program.last_step_stats), booked beside the host's
+        self._step_stats = tuple(getattr(
+            getattr(program, 'model', None), 'step_stats', ()))
+        for name in self._step_stats:
+            self._counts[name] = 0
         # speculative decoding: draft proposes spec_k tokens per tick,
         # the target verifies them in one batched call
         self._draft = None
@@ -418,6 +492,7 @@ class DecodeEngine:
         # whether the compiled programs carry the extras argument at
         # all (per-slot array build is skipped entirely when not)
         self._extras_on = self.sample_args or aspec is not None
+        self._outbox = _Outbox()
         self._worker = threading.Thread(
             target=self._run, daemon=True,
             name='mxnet-tpu-%s-decode' % name)
@@ -492,6 +567,9 @@ class DecodeEngine:
         prompt = [int(t) for t in onp.asarray(tokens).reshape(-1)]
         if not prompt:
             raise ValueError('empty prompt')
+        if prefill_only and self._wcols:
+            self.program._no_window('prefill_only admission (its '
+                                    'seqstate export)')
         if len(prompt) > self.program.max_prompt_len():
             raise ValueError(
                 'prompt of %d tokens exceeds the top prefill bucket %d'
@@ -520,6 +598,7 @@ class DecodeEngine:
                 % (adapter,))
         now = self._clock()
         stream = GenerateStream(len(prompt))
+        stream._outbox = self._outbox
         seq = _Seq(stream, prompt, max_new, eos_id, now,
                    now + self.timeout_s if self.timeout_s else None,
                    prefill_only=bool(prefill_only),
@@ -622,10 +701,15 @@ class DecodeEngine:
             and not self._migrations
 
     def _run(self):
+        from .program import while_device_runs
+        self._outbox.owner = threading.get_ident()
+        while_device_runs.hook = self._outbox.flush
         while True:
             with self._lock:
                 idle = self._idle()
             if idle:
+                # no device call is coming for them to wait behind
+                self._outbox.flush()
                 # the span opens outside the lock: its own emits never
                 # extend the critical section
                 with _span('eng.wait_work'):
@@ -634,6 +718,7 @@ class DecodeEngine:
                             self._wake.wait(0.05)
             with self._lock:
                 if self._closed and self._idle():
+                    self._outbox.flush()
                     return
             try:
                 self._tick()
@@ -656,6 +741,7 @@ class DecodeEngine:
         with self._lock:
             step = self._counts['steps']
             active, pending = len(self._active), len(self._pending)
+        self._outbox.flushed = False
         with _span('eng.tick', step=step, active=active,
                    pending=pending, wall=time.time()):
             with _span('eng.tick.retire'):
@@ -682,6 +768,10 @@ class DecodeEngine:
                 budget -= 1
             if self._active:
                 self._step()
+            if not self._outbox.flushed:
+                # a tick that enqueued no program (every admission
+                # refused, every slot done): nothing to wait behind
+                self._outbox.flush()
             with _span('eng.tick.telemetry'):
                 inst = _serving_instruments()
                 if inst is not None:
@@ -717,10 +807,7 @@ class DecodeEngine:
                 # drop the sequence's page holds; pages whose prefix
                 # registration still holds a ref stay resident for
                 # future hits (evicted LRU under pool pressure)
-                if self._allocator is not None and seq.pages:
-                    for p in seq.pages:
-                        self._allocator.release(p)
-                    seq.pages = []
+                self._drop_pages(seq)
         # adapter pool unpin outside the lock (the pool has its own)
         self._release_adapter(seq)
         _record_event('decode_retire', slot=slot, reason=reason,
@@ -756,25 +843,41 @@ class DecodeEngine:
                 self._allocator.reset()
                 if self._prefix is not None:
                     self._prefix.clear()
+                if self._wallocator is not None:
+                    self._wallocator.reset()
+                if self._wprefix is not None:
+                    self._wprefix.clear()
         if self._draft is not None:
             self._draft_cache = self._draft.new_cache()
 
+    def _drop_pages(self, seq):
+        """Give back every page hold of ``seq``, of both kinds of
+        layer (caller holds the lock)."""
+        if self._allocator is not None and seq.pages:
+            for p in seq.pages:
+                self._allocator.release(p)
+            seq.pages = []
+        if seq.wpages:
+            for p in seq.wpages:
+                self._wallocator.release(p)
+            seq.wpages = []
+
     def _release_seq_pages(self, seq):
         with self._lock:
-            if self._allocator is not None and seq.pages:
-                for p in seq.pages:
-                    self._allocator.release(p)
-                seq.pages = []
+            self._drop_pages(seq)
 
-    def _alloc_pages(self, n, slot):
-        """``n`` fresh pages, evicting LRU cached prefixes under pool
-        pressure; None on exhaustion (the caller fails TYPED)."""
+    def _alloc_pages(self, n, slot, window=False):
+        """``n`` fresh pages (of the window layers' pool with
+        ``window``), evicting LRU cached prefixes under pool pressure;
+        None on exhaustion (the caller fails TYPED)."""
+        allocator = self._wallocator if window else self._allocator
+        prefix = self._wprefix if window else self._prefix
         with self._lock:
-            ids = self._allocator.alloc(n)
+            ids = allocator.alloc(n)
             evicted = []
-            if ids is None and self._prefix is not None:
-                evicted = self._prefix.evict_lru(n)
-                ids = self._allocator.alloc(n)
+            if ids is None and prefix is not None:
+                evicted = prefix.evict_lru(n)
+                ids = allocator.alloc(n)
             if evicted:
                 self._counts['page_evictions'] += len(evicted)
         for p in evicted:
@@ -810,41 +913,90 @@ class DecodeEngine:
         degrade/abort handling."""
         ps = self.program.page_size
         for pi in range(int(first_pos) // ps, int(last_pos) // ps + 1):
-            page = int(seq.table[pi])
-            if page == TRASH_PAGE:
-                ids = self._alloc_pages(1, seq.slot)
-                if ids is None:
-                    return False
-                seq.table[pi] = ids[0]
-                with self._lock:
-                    seq.pages.append(ids[0])
-                continue
-            with self._lock:
-                shared = self._allocator.refcount(page) > 1
-                if shared and self._prefix is not None \
-                        and self._allocator.refcount(page) == 2:
-                    # only co-holder is the prefix registry: steal the
-                    # registration back instead of copying — the
-                    # write is private, no extra page burned (real
-                    # sharers keep the full copy-on-write below)
-                    if self._prefix.release_leaf(page):
-                        shared = self._allocator.refcount(page) > 1
-            if not shared:
-                continue
-            # copy-on-write: the first divergent write into a shared
-            # page lands in this sequence's private copy
-            ids = self._alloc_pages(1, seq.slot)
+            if not self._writable_page(seq, pi, False):
+                return False
+            # a window layer's table is a ring: _release_window has
+            # emptied the column of a page this position opens
+            if self._wcols and not self._writable_page(
+                    seq, pi % self._wcols, True):
+                return False
+        return True
+
+    def _writable_page(self, seq, col, window):
+        """One table column of ``seq``, of the full layers' table or
+        of the window layers' ring: fill it if it is empty, make it
+        private if it is shared (:meth:`_ensure_writable`)."""
+        table = seq.wtable if window else seq.table
+        pages = seq.wpages if window else seq.pages
+        allocator = self._wallocator if window else self._allocator
+        prefix = self._wprefix if window else self._prefix
+        page = int(table[col])
+        if page == TRASH_PAGE:
+            ids = self._alloc_pages(1, seq.slot, window)
             if ids is None:
                 return False
+            table[col] = ids[0]
+            with self._lock:
+                pages.append(ids[0])
+            return True
+        with self._lock:
+            shared = allocator.refcount(page) > 1
+            if shared and prefix is not None \
+                    and allocator.refcount(page) == 2:
+                # only co-holder is the prefix registry: steal the
+                # registration back instead of copying — the
+                # write is private, no extra page burned (real
+                # sharers keep the full copy-on-write below)
+                if prefix.release_leaf(page):
+                    shared = allocator.refcount(page) > 1
+        if not shared:
+            return True
+        # copy-on-write: the first divergent write into a shared
+        # page lands in this sequence's private copy
+        ids = self._alloc_pages(1, seq.slot, window)
+        if ids is None:
+            return False
+        if window:
+            self._cache = self._device(
+                self.program.run_copy_page, self._cache, TRASH_PAGE,
+                TRASH_PAGE, wsrc=page, wdst=ids[0])
+        else:
             self._cache = self._device(self.program.run_copy_page,
                                        self._cache, page, ids[0])
-            with self._lock:
-                self._allocator.release(page)
-                seq.pages.remove(page)
-                seq.pages.append(ids[0])
-                self._counts['cow_copies'] += 1
-            seq.table[pi] = ids[0]
+        with self._lock:
+            allocator.release(page)
+            pages.remove(page)
+            pages.append(ids[0])
+            self._counts['cow_copies'] += 1
+        table[col] = ids[0]
         return True
+
+    def _release_window(self, active):
+        """A sequence whose next position opens a logical page its
+        window ring has not held yet gives back the page that column
+        held: by then it lies wholly behind the window (the ring is
+        ``ceil(window / page) + 1`` columns). The registry's own hold,
+        if the page was a shared prefix, keeps it for later hits."""
+        ps, cols = self.program.page_size, self._wcols
+        released = 0
+        for seq in active.values():
+            top = int(seq.pos) // ps
+            if top <= seq.wtop or seq.stream.done() \
+                    or seq.stream._cancelled:
+                continue
+            for page_no in range(seq.wtop + 1, top + 1):
+                col = page_no % cols
+                page = int(seq.wtable[col])
+                if page != TRASH_PAGE:
+                    with self._lock:
+                        self._wallocator.release(page)
+                        seq.wpages.remove(page)
+                    seq.wtable[col] = TRASH_PAGE
+                    released += 1
+            seq.wtop = top
+        if released:
+            with self._lock:
+                self._counts['window_pages_released'] += released
 
     # -- device calls under breaker + watchdog -----------------------------
 
@@ -1150,7 +1302,11 @@ class DecodeEngine:
         n = len(prompt)
         seq.table = onp.full(self.program.max_pages, TRASH_PAGE,
                              'int32')
-        shared, covered = [], 0
+        ps = self.program.page_size
+        if self._wcols:
+            seq.wtable = onp.full(self._wcols, TRASH_PAGE, 'int32')
+            seq.wtop = -1
+        shared, wshared, covered = [], [], 0
         if self._prefix is not None:
             # namespaced by adapter id: an adapter's KV rows for the
             # same tokens differ from the base's — a warm hit must
@@ -1158,6 +1314,16 @@ class DecodeEngine:
             with self._lock:
                 shared, covered = self._prefix.lookup(
                     prompt, namespace=seq.adapter_id)
+                if self._wprefix is not None:
+                    # a hit reaches as far as BOTH kinds of layer
+                    # still hold the prefix (the window layers
+                    # register only prompts their ring holds whole,
+                    # and evict on their own)
+                    wshared, wcovered = self._wprefix.lookup(
+                        prompt, namespace=seq.adapter_id)
+                    covered = min(covered, wcovered)
+                    shared = shared[:pages_for(covered, ps)]
+                    wshared = wshared[:pages_for(covered, ps)]
             # always leave >= 1 suffix token to step on: its logits
             # are the first generated token
             covered = min(covered, n - 1)
@@ -1169,9 +1335,16 @@ class DecodeEngine:
                     for p in shared:
                         self._allocator.ref(p)
                     seq.pages = list(shared)
+                    for p in wshared:
+                        self._wallocator.ref(p)
+                    seq.wpages = list(wshared)
                     self._counts['prefix_hits'] += 1
                     self._counts['prefix_tokens_saved'] += covered
                 seq.table[:len(shared)] = shared
+                if self._wcols:
+                    # a registered prefix fits the ring: column = page
+                    seq.wtable[:len(wshared)] = wshared
+                    seq.wtop = len(wshared) - 1
                 seq.slot = slot
                 seq.pos = covered
                 seq.last_token = int(prompt[covered])
@@ -1198,10 +1371,18 @@ class DecodeEngine:
                     # un-shared suffix through ITS decode step
                     self._export_at_boundary(seq, slot)
                 return
-            ids = self._alloc_pages(pages_for(n,
-                                              self.program.page_size),
-                                    slot)
-            if ids is None:
+            npages = pages_for(n, ps)
+            ids = self._alloc_pages(npages, slot)
+            wids, extras = None, self._prefill_extras(seq)
+            if ids is not None and self._wcols:
+                # the window layers keep the prompt's last pages only:
+                # what lies behind the ring is never written
+                behind = max(0, npages - self._wcols)
+                wids = self._alloc_pages(npages - behind, slot, True)
+            if ids is None or (self._wcols and wids is None):
+                with self._lock:
+                    seq.pages = list(ids or ())
+                self._release_seq_pages(seq)
                 self._fail_pool_exhausted(seq, slot, where='admit')
                 self._release_adapter(seq)
                 with self._lock:
@@ -1210,10 +1391,16 @@ class DecodeEngine:
             with self._lock:
                 seq.pages = list(ids)
             seq.table[:len(ids)] = ids
+            if self._wcols:
+                with self._lock:
+                    seq.wpages = list(wids)
+                for j, page in enumerate(wids):
+                    seq.wtable[(behind + j) % self._wcols] = page
+                seq.wtop = npages - 1
+                extras['wpage_ids'] = [TRASH_PAGE] * behind + wids
             self._cache, tok, _logits = self._device(
                 self.program.run_prefill, self._cache,
-                onp.asarray(prompt, 'int32'), ids,
-                **self._prefill_extras(seq))
+                onp.asarray(prompt, 'int32'), ids, **extras)
             if self._draft is not None:
                 self._draft_cache, _dt, _dl = self._device(
                     self._draft.run_prefill, self._draft_cache,
@@ -1222,6 +1409,12 @@ class DecodeEngine:
                 with _span('eng.tick.prefix_register'), self._lock:
                     self._prefix.register(prompt, ids,
                                           namespace=seq.adapter_id)
+                    if self._wprefix is not None:
+                        # registers nothing where the prompt outran
+                        # the ring: its first page is the trash page
+                        self._wprefix.register(
+                            prompt, extras['wpage_ids'],
+                            namespace=seq.adapter_id)
         except _DegradedPath:
             self._release_adapter(seq)
             self._release_seq_pages(seq)
@@ -1419,6 +1612,9 @@ class DecodeEngine:
         allocation at boundary crossings + copy-on-write of shared
         pages. Pool exhaustion fails THAT stream typed and drops it
         from this tick; device errors propagate to the caller."""
+        if self._wcols:
+            with _span('eng.tick.release_window'):
+                self._release_window(active)
         for slot, seq in list(active.items()):
             if seq.stream.done() or seq.stream._cancelled:
                 continue
@@ -1450,6 +1646,12 @@ class DecodeEngine:
                     positions[slot] = seq.pos
                     tables[slot] = seq.table
                 extras = self._step_extras(active)
+                if self._wcols:
+                    wtables = onp.zeros((self.slots, self._wcols),
+                                        'int32')
+                    for slot, seq in active.items():
+                        wtables[slot] = seq.wtable
+                    extras['wtables'] = wtables
             self._cache, toks, _logits = self._device(
                 self.program.run_step, self._cache, tokens, positions,
                 tables, **extras)
@@ -1511,6 +1713,10 @@ class DecodeEngine:
             self._counts['sampled_steps'] += sampled_step
             self._counts['tokens'] += emitted
             self._counts['sampled_tokens'] += sampled
+            if self._step_stats:
+                # what the device counted came back behind the tokens
+                for name, v in self.program.last_step_stats.items():
+                    self._counts[name] += v
             self._ema_step_s = dt if self._ema_step_s is None \
                 else 0.7 * self._ema_step_s + 0.3 * dt
         inst = _serving_instruments()
@@ -1864,6 +2070,8 @@ class DecodeEngine:
         incompatible payloads, :class:`BackpressureError` when no
         slot/pages are available, :class:`BatcherClosed` after
         :meth:`close`."""
+        if self._wcols:
+            self.program._no_window('import_sequence')
         state = decode_payload(payload)
         state['trace'] = trace
         # a pinned adapter / sampled stream must land in an engine
@@ -1983,6 +2191,7 @@ class DecodeEngine:
             raise
         now = self._clock()
         stream = GenerateStream(len(prompt))
+        stream._outbox = self._outbox
         # already streamed by the SOURCE engine: the full token list
         # stays intact (finish budgets, done-line tokens) while the
         # iterator yields only the continuation
@@ -2111,6 +2320,10 @@ class DecodeEngine:
         availability hole the chaos soak measures. The scheduler
         retires the slots, rebuilds the cache, and keeps serving at
         device speed while this thread finishes the degraded work."""
+        # the fallback thread puts directly: what this thread emitted
+        # for these streams goes first
+        self._outbox.flush()
+
         def _complete():
             for seq in seqs:
                 self._fallback_complete(seq)
@@ -2170,6 +2383,8 @@ class DecodeEngine:
                 live = len(self._active)
                 live_pages = sum(len(s.pages)
                                  for s in self._active.values())
+                live_wpages = sum(len(s.wpages)
+                                  for s in self._active.values())
             out['pool'] = pool
             page_bytes = getattr(prog, 'page_bytes', None)
             if callable(page_bytes):
@@ -2179,6 +2394,11 @@ class DecodeEngine:
                 # holds, per sequence (falls back to one page when
                 # idle — the floor a new sequence costs)
                 amort = (live_pages * pb // live) if live else pb
+                if self._wcols and live:
+                    # a page of the window layers and a page of the
+                    # full layers hold different bytes
+                    amort = pool_bytes(prog._pspec, live_pages,
+                                       live_wpages) // live
                 out['per_sequence_bytes_amortized'] = int(amort)
                 if amort:
                     out['max_concurrent_sequences_per_gb'] = \
@@ -2210,6 +2430,17 @@ class DecodeEngine:
                 out['pages'] = self._allocator.stats()
                 if self._prefix is not None:
                     out['pages']['prefix_entries'] = len(self._prefix)
+            if self._wallocator is not None:
+                out['pages_window'] = self._wallocator.stats()
+                if self._wprefix is not None:
+                    out['pages_window']['prefix_entries'] = \
+                        len(self._wprefix)
+                # pages in use by kind of layer, sequences' holds and
+                # the prefix registry's alike (gauges, not sums)
+                out['counts']['pages_live.full'] = \
+                    self._allocator.used_pages
+                out['counts']['pages_live.window'] = \
+                    self._wallocator.used_pages
             if self._draft is not None:
                 proposed = self._counts['spec_proposed']
                 out['spec'] = {

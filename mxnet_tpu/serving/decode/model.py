@@ -36,7 +36,20 @@ from .paged import (PagedCacheSpec, gather_pages, write_paged_chunk,
                     write_paged_rows, write_prefill_pages)
 
 __all__ = ['DecodeModel', 'RNNLM', 'TransformerLM', 'from_gluon_rnn_lm',
-           'model_from_config', 'init_rnn_lm', 'init_transformer_lm']
+           'model_from_config', 'init_rnn_lm', 'init_transformer_lm',
+           'FamilyUnsupported']
+
+
+class FamilyUnsupported(NotImplementedError):
+    """A decode family was asked for a path it does not implement
+    (docs/SERVING.md lists them per family): names the family and the
+    path, and is raised where the path is asked for, at freeze time
+    wherever that is possible."""
+
+    def __init__(self, family, what):
+        super().__init__('decode family %r does not implement %s'
+                         % (family, what))
+        self.family, self.what = family, what
 
 
 def _as_numpy(arr):
@@ -713,6 +726,7 @@ _FAMILIES = {RNNLM.family: RNNLM, TransformerLM.family: TransformerLM}
 
 def model_from_config(family, config):
     """Factory the frozen-artifact loader dispatches through."""
+    from . import cohere2  # noqa: F401  (registers its family)
     cls = _FAMILIES.get(family)
     if cls is None:
         raise ValueError('unknown decode family %r (have %s)'

@@ -47,12 +47,16 @@ lazily — the cache.py discipline.
 """
 from __future__ import annotations
 
+import heapq
+
 import numpy as onp
 
 __all__ = ['PagedCacheSpec', 'PageAllocator', 'PrefixCache',
            'TRASH_PAGE', 'init_pool', 'pool_avals', 'pool_bytes',
            'gather_pages', 'write_paged_rows', 'write_paged_chunk',
-           'write_prefill_pages', 'copy_page', 'pages_for']
+           'write_prefill_pages', 'copy_page', 'pages_for',
+           'scatter_rows', 'scatter_pages', 'ring_key_positions',
+           'window_table_pages']
 
 # pool page index 0 is never allocated: unused page-table entries and
 # padded prefill writes target it (reads of it are mask-zeroed)
@@ -64,6 +68,14 @@ def pages_for(n_tokens, page_size):
     return -(-int(n_tokens) // int(page_size))
 
 
+def window_table_pages(window, page_size, max_len):
+    """Table width of a sliding-window layer: the pages a window of
+    ``window`` tokens can straddle, ``ceil(window / page) + 1``, and
+    never more than a full layer's ``ceil(max_len / page)``."""
+    return min(pages_for(window, page_size) + 1,
+               pages_for(max_len, page_size))
+
+
 class PagedCacheSpec:
     """Metadata for one paged cache: ``{name: (row_shape, dtype)}`` —
     the pool array for ``pages`` pages of ``page_size`` rows each is
@@ -72,11 +84,22 @@ class PagedCacheSpec:
     ``row_shape`` is the per-token shape (``(units,)`` for a
     transformer K or V entry); ``max_pages`` is the per-sequence page
     table length, ``ceil(max_len / page_size)``.
+
+    Two kinds of layer: the entries named in ``window_entries`` belong
+    to sliding-window layers. They live in pools and tables of their
+    own, sized by the window: a sequence's table for them is a ring of
+    ``window_pages`` columns (logical page ``p`` sits in column
+    ``p % window_pages``), and the page that falls behind the window
+    goes back to their allocator. A spec without window entries
+    (``window_pages == 0``) is the one-kind case: one pool size, one
+    table, nothing else changes.
     """
 
-    __slots__ = ('entries', 'page_size', 'max_pages')
+    __slots__ = ('entries', 'page_size', 'max_pages', 'window',
+                 'window_pages', 'window_entries')
 
-    def __init__(self, entries, page_size, max_len):
+    def __init__(self, entries, page_size, max_len, window=None,
+                 window_entries=()):
         self.page_size = int(page_size)
         if self.page_size < 1 or (self.page_size
                                   & (self.page_size - 1)):
@@ -85,60 +108,88 @@ class PagedCacheSpec:
         self.max_pages = pages_for(int(max_len), self.page_size)
         self.entries = {str(k): (tuple(int(d) for d in shape), str(dt))
                         for k, (shape, dt) in dict(entries).items()}
+        self.window_entries = frozenset(str(k) for k in window_entries)
+        if self.window_entries - set(self.entries):
+            raise ValueError('window entries %r are no cache entries'
+                             % sorted(self.window_entries
+                                      - set(self.entries)))
+        self.window = int(window) if self.window_entries else 0
+        self.window_pages = window_table_pages(
+            self.window, self.page_size, max_len) \
+            if self.window_entries else 0
 
     def items(self):
         return self.entries.items()
+
+    def pages_of(self, name, pages, window_pool):
+        """Pool size of one entry: ``window_pool`` for a window
+        layer's, ``pages`` for a full layer's."""
+        return window_pool if name in self.window_entries else pages
 
     def full_shape(self, name, pages):
         shape, _ = self.entries[name]
         return (int(pages), self.page_size) + shape
 
     def to_json(self):
-        return {'page_size': self.page_size,
-                'max_pages': self.max_pages,
-                'entries': {k: [list(s), dt]
-                            for k, (s, dt) in self.entries.items()}}
+        out = {'page_size': self.page_size,
+               'max_pages': self.max_pages,
+               'entries': {k: [list(s), dt]
+                           for k, (s, dt) in self.entries.items()}}
+        if self.window_entries:
+            out['window'] = self.window
+            out['window_entries'] = sorted(self.window_entries)
+        return out
 
     @classmethod
     def from_json(cls, obj):
         entries = {k: (tuple(s), dt)
                    for k, (s, dt) in obj['entries'].items()}
         return cls(entries, obj['page_size'],
-                   obj['max_pages'] * obj['page_size'])
+                   obj['max_pages'] * obj['page_size'],
+                   window=obj.get('window'),
+                   window_entries=obj.get('window_entries', ()))
 
     def __repr__(self):
         return ('PagedCacheSpec(page_size=%d, max_pages=%d, %r)'
                 % (self.page_size, self.max_pages, self.entries))
 
 
-def pool_bytes(spec, pages):
+def pool_bytes(spec, pages, window_pool=0):
     """Static pool footprint in bytes for ``pages`` pages — the REAL
     device residency of the paged cache (the slot cache's
     ``slots × max_len`` figure this replaces reserved worst case per
-    sequence whether it was used or not)."""
+    sequence whether it was used or not). Window layers' entries
+    count ``window_pool`` pages each."""
     total = 0
     for name, (shape, dt) in spec.items():
-        n = int(pages) * spec.page_size
+        n = int(spec.pages_of(name, pages, window_pool)) * spec.page_size
         for d in shape:
             n *= d
-        total += n * onp.dtype(dt).itemsize
+        total += n * _itemsize(dt)
     return total
 
 
-def init_pool(spec, pages):
+def _itemsize(dt):
+    if dt == 'bfloat16':         # numpy alone does not know the name
+        return 2
+    return onp.dtype(dt).itemsize
+
+
+def init_pool(spec, pages, window_pool=0):
     """Preallocated zeros pool pytree ``{name: (pages, page_size,
     *row_shape)}`` — zeros so stale rows stay finite under the
     attention mask (cache.py's argument)."""
     import jax.numpy as jnp
-    return {name: jnp.zeros(spec.full_shape(name, pages), dt)
+    return {name: jnp.zeros(spec.full_shape(
+                name, spec.pages_of(name, pages, window_pool)), dt)
             for name, (_, dt) in spec.items()}
 
 
-def pool_avals(spec, pages):
+def pool_avals(spec, pages, window_pool=0):
     """ShapeDtypeStructs for AOT lowering (freeze.py idiom)."""
     import jax
-    return {name: jax.ShapeDtypeStruct(spec.full_shape(name, pages),
-                                       dt)
+    return {name: jax.ShapeDtypeStruct(spec.full_shape(
+                name, spec.pages_of(name, pages, window_pool)), dt)
             for name, (_, dt) in spec.items()}
 
 
@@ -222,6 +273,44 @@ def write_prefill_pages(pool_arr, rows, page_ids):
         pool_arr = lax.dynamic_update_slice(
             pool_arr, blk[None].astype(pool_arr.dtype), start)
     return pool_arr
+
+
+def scatter_rows(pool_arr, rows, page_ids, offsets):
+    """The decode-step KV append as one scatter: ``rows`` (slots,
+    *row) to ``(page_ids[s], offsets[s])``. Free slots all target the
+    trash page, where whichever write wins is masked anyway."""
+    return pool_arr.at[page_ids, offsets].set(
+        rows.astype(pool_arr.dtype), mode='promise_in_bounds')
+
+
+def scatter_pages(pool_arr, rows, page_ids):
+    """The prefill landing as one scatter: ``rows`` (npages *
+    page_size, *row) to the whole pages ``page_ids`` (npages,). Pages
+    the host does not keep (padding, or a window layer's pages behind
+    the window) point at the trash page."""
+    npages = page_ids.shape[0]
+    blocks = rows.reshape((npages, rows.shape[0] // npages)
+                          + rows.shape[1:])
+    return pool_arr.at[page_ids].set(blocks.astype(pool_arr.dtype),
+                                     mode='promise_in_bounds')
+
+
+def ring_key_positions(positions, window_pages, page_size):
+    """Absolute position of every row a window layer's table gathers.
+
+    ``positions`` (slots,) is each slot's newest position. Column
+    ``c`` of its ring holds the newest logical page ``p <= positions
+    // page_size`` with ``p % window_pages == c``; row ``o`` of it is
+    position ``p * page_size + o``. Returns (slots, window_pages *
+    page_size) int32, negative where the column has held no page yet.
+    """
+    import jax.numpy as jnp
+    top = (positions // page_size)[:, None]              # (S, 1)
+    col = jnp.arange(window_pages)[None, :]              # (1, W)
+    page = top - (top - col) % window_pages              # (S, W)
+    pos = page[:, :, None] * page_size \
+        + jnp.arange(page_size)[None, None, :]
+    return pos.reshape(positions.shape[0], -1).astype('int32')
 
 
 def copy_page(pool_arr, src, dst):
@@ -332,28 +421,30 @@ class PageAllocator:
 
 
 class _PrefixNode:
-    __slots__ = ('page', 'tokens', 'parent', 'children', 'last_used',
-                 'seq')
+    __slots__ = ('id', 'key', 'page', 'parent', 'children', 'touch')
 
-    def __init__(self, page, tokens, parent, seq):
+    def __init__(self, ident, key, page, parent):
+        self.id = ident               # serial of this registration
+        self.key = key                # (parent's id or root, tokens)
         self.page = page
-        self.tokens = tokens
-        self.parent = parent          # parent key or None
+        self.parent = parent          # parent node or None
         self.children = 0
-        self.last_used = seq
-        self.seq = seq
+        self.touch = 0                # serial of its last touch
 
 
 class PrefixCache:
     """Exact-match trie of prompt pages → pool page indices.
 
-    Keys are ``(parent_key, tokens_tuple)`` — the chain itself is the
-    key, so two different prefixes can never collide the way a rolling
-    hash could. Full pages chain with ``len(tokens) == page_size``;
-    the prompt's partial tail page registers with its shorter token
-    tuple (shared only on an exact remaining-token match — a
-    divergence INSIDE a page can therefore never alias, and a sharer
-    writing past the shared rows copy-on-writes first).
+    Keys are ``(parent, tokens_tuple)``, ``parent`` being the serial
+    number of the parent's registration (never used twice) or the
+    namespace's root: the chain itself is the key, so two different
+    prefixes can never collide the way a rolling hash could, and a key
+    hashes in the time of one page, not of the chain behind it. Full
+    pages chain with ``len(tokens) == page_size``; the prompt's
+    partial tail page registers with its shorter token tuple (shared
+    only on an exact remaining-token match — a divergence INSIDE a
+    page can therefore never alias, and a sharer writing past the
+    shared rows copy-on-writes first).
 
     Each registered node holds one allocator ref on its page, so a
     retired owner's pages survive for future hits until
@@ -365,9 +456,14 @@ class PrefixCache:
     def __init__(self, page_size, allocator):
         self.page_size = int(page_size)
         self._alloc = allocator
-        self._nodes = {}
-        self._by_page = {}      # page id -> node key (pages are
-        self._seq = 0           # registered under at most one node)
+        self._nodes = {}        # key -> node
+        self._by_page = {}      # page id -> node (pages are
+        self._serial = 0        # registered under at most one node)
+        # (touch, serial, node) for every node as it was when last it
+        # was touched as a leaf or lost its last child. The smallest
+        # entry that still describes its node is the least recently
+        # used leaf: an eviction pops it and walks nothing
+        self._leaves = []
         self.evictions = 0      # hit/token counters live in the
                                 # engine's _counts, not here
 
@@ -379,10 +475,37 @@ class PrefixCache:
         the allocator itself was reset (pool rebuilt)."""
         self._nodes = {}
         self._by_page = {}
+        self._leaves = []
 
-    def _tick(self):
-        self._seq += 1
-        return self._seq
+    def _next(self):
+        self._serial += 1
+        return self._serial
+
+    def _touch(self, node):
+        node.touch = self._next()
+        if not node.children:
+            self._push_leaf(node)
+
+    def _push_leaf(self, node):
+        if len(self._leaves) > 4 * len(self._nodes) + 64:
+            # mostly entries that a later touch or a child overtook
+            self._leaves = [(n.touch, n.id, n)
+                            for n in self._nodes.values()
+                            if not n.children and n is not node]
+            heapq.heapify(self._leaves)
+        heapq.heappush(self._leaves, (node.touch, self._next(), node))
+
+    def _drop(self, node):
+        """Forget one leaf and give back the registry's hold on its
+        page; its parent may be a leaf now."""
+        del self._nodes[node.key]
+        del self._by_page[node.page]
+        parent = node.parent
+        if parent is not None:
+            parent.children -= 1
+            if not parent.children:
+                self._push_leaf(parent)
+        self._alloc.release(node.page)
 
     def _chunks(self, prompt):
         ps = self.page_size
@@ -394,12 +517,11 @@ class PrefixCache:
 
     @staticmethod
     def _root(namespace):
-        """Root parent key for one namespace. ``None`` keeps the
-        pre-namespace keys (old chains stay warm); anything else —
-        the engine passes the adapter id — roots a disjoint trie, so
-        a warm prefix hit can NEVER splice base-model KV rows into an
-        adapter sequence or cross two adapters: their K/V for the
-        same tokens differ."""
+        """Root parent key for one namespace. ``None`` is the base
+        model's; anything else — the engine passes the adapter id —
+        roots a disjoint trie, so a warm prefix hit can NEVER splice
+        base-model KV rows into an adapter sequence or cross two
+        adapters: their K/V for the same tokens differ."""
         return None if namespace is None else ('ns', str(namespace))
 
     def register(self, prompt, page_ids, namespace=None):
@@ -408,24 +530,23 @@ class PrefixCache:
         page. ``page_ids[i]`` holds prompt positions
         ``[i*ps, (i+1)*ps)``. ``namespace`` isolates the chain (the
         engine namespaces by adapter id)."""
-        now = self._tick()
         chunks, tail = self._chunks(prompt)
-        parent = self._root(namespace)
+        parent, above = None, self._root(namespace)
         for i, chunk in enumerate(chunks + ([tail] if tail else [])):
-            key = (parent, chunk)
+            key = (above, chunk)
             node = self._nodes.get(key)
             if node is None:
                 page = page_ids[i]
                 if page == TRASH_PAGE:
                     break              # prompt outran the page list
                 self._alloc.ref(page)
-                node = _PrefixNode(page, chunk, parent, now)
+                node = _PrefixNode(self._next(), key, page, parent)
                 self._nodes[key] = node
-                self._by_page[page] = key
-                if parent is not None and parent in self._nodes:
-                    self._nodes[parent].children += 1
-            node.last_used = now
-            parent = key
+                self._by_page[page] = node
+                if parent is not None:
+                    parent.children += 1
+            self._touch(node)
+            parent, above = node, node.id
 
     def lookup(self, prompt, namespace=None):
         """Longest registered chain covering ``prompt``'s head IN
@@ -433,26 +554,18 @@ class PrefixCache:
         taking refs (the engine refs the pages it actually uses). Full
         pages chain first; a partial tail matches only when the
         remaining prompt tokens equal a registered tail exactly."""
-        now = self._tick()
         chunks, tail = self._chunks(prompt)
         pages = []
-        parent = self._root(namespace)
+        above = self._root(namespace)
         covered = 0
-        for chunk in chunks:
-            node = self._nodes.get((parent, chunk))
+        for chunk in chunks + ([tail] if tail else []):
+            node = self._nodes.get((above, chunk))
             if node is None:
                 break
-            node.last_used = now
+            self._touch(node)
             pages.append(node.page)
             covered += len(chunk)
-            parent = (parent, chunk)
-        else:
-            if tail:
-                node = self._nodes.get((parent, tail))
-                if node is not None:
-                    node.last_used = now
-                    pages.append(node.page)
-                    covered += len(tail)
+            above = node.id
         return pages, covered
 
     def release_leaf(self, page):
@@ -466,17 +579,10 @@ class PrefixCache:
         own generation — are always leaves. Returns True when a leaf
         registration was dropped. O(1) via the page->node index (this
         runs per page-boundary write on the scheduler hot path)."""
-        key = self._by_page.get(page)
-        if key is None:
+        node = self._by_page.get(page)
+        if node is None or node.children:
             return False
-        node = self._nodes.get(key)
-        if node is None or node.page != page or node.children:
-            return False
-        del self._nodes[key]
-        del self._by_page[page]
-        if node.parent is not None and node.parent in self._nodes:
-            self._nodes[node.parent].children -= 1
-        self._alloc.release(page)
+        self._drop(node)
         return True
 
     def evict_lru(self, want_pages=1):
@@ -485,19 +591,13 @@ class PrefixCache:
         evictable remains). Returns the freed page ids (pages whose
         only remaining hold was the registry's)."""
         freed = []
-        while not self._alloc.can_alloc(want_pages):
-            leaves = [(node.last_used, key)
-                      for key, node in self._nodes.items()
-                      if node.children == 0]
-            if not leaves:
-                break
-            _, key = min(leaves)
-            node = self._nodes.pop(key)
-            self._by_page.pop(node.page, None)
-            if node.parent is not None and node.parent in self._nodes:
-                self._nodes[node.parent].children -= 1
+        while not self._alloc.can_alloc(want_pages) and self._leaves:
+            touch, _serial, node = heapq.heappop(self._leaves)
+            if self._nodes.get(node.key) is not node or node.children \
+                    or node.touch != touch:
+                continue               # an entry its node has outlived
             before = self._alloc.free_pages
-            self._alloc.release(node.page)
+            self._drop(node)
             if self._alloc.free_pages > before:
                 freed.append(node.page)
             self.evictions += 1

@@ -45,10 +45,16 @@ import numpy as onp
 from ...observability.spans import span as _span
 from ..bucket import BucketPolicy, default_buckets
 from .cache import cache_avals, cache_bytes, init_cache
-from .model import DecodeModel, from_gluon_rnn_lm, model_from_config
+from .model import (DecodeModel, FamilyUnsupported, from_gluon_rnn_lm,
+                    model_from_config)
 from .paged import (TRASH_PAGE, init_pool, pages_for, pool_avals,
                     pool_bytes, write_prefill_pages)
 from . import paged as _paged
+
+# per thread: ``hook``, a callable that :meth:`DecodeProgram._call` runs
+# once a program is enqueued and before it blocks on the tokens. The
+# engine's scheduler thread hands its streams their tokens there.
+while_device_runs = threading.local()
 
 __all__ = ['DecodeProgram', 'PagedDecodeProgram', 'freeze_decode',
            'load_decode']
@@ -133,10 +139,19 @@ class DecodeProgram:
                 'within max_len %d'
                 % (self.policy.max_batch, model.max_len))
         self.max_len = model.max_len
-        self._params_np = {k: onp.asarray(v) for k, v in params.items()}
-        self._params = {k: jnp.asarray(v)
-                        for k, v in self._params_np.items()}
-        self._spec = model.cache_spec()
+        # leaves that arrive on the device stay there (a host copy of
+        # them is made only where one is asked for: save())
+        self._params_host = {k: v for k, v in params.items()
+                             if isinstance(v, onp.ndarray)}
+        self._params = {k: jnp.asarray(v) for k, v in params.items()}
+        try:
+            self._spec = model.cache_spec()
+        except FamilyUnsupported:
+            # a family with no slot cache serves paged only, and has no
+            # single-slot CPU replay (fallback_generate)
+            if not self.paged:
+                raise
+            self._spec = None
         if donate is None:
             donate = jax.default_backend() != 'cpu'
         self._donate = bool(donate)
@@ -161,6 +176,12 @@ class DecodeProgram:
         self.retraced_buckets = []
 
     # -- program construction ----------------------------------------------
+
+    @property
+    def _params_np(self):
+        """The parameters as host arrays."""
+        return {k: self._params_host[k] if k in self._params_host
+                else onp.asarray(v) for k, v in self._params.items()}
 
     @property
     def prefill_buckets(self):
@@ -463,9 +484,14 @@ class DecodeProgram:
         clock, for the scheduler thread that calls this (engine.py):
         ``eng.tick.dispatch`` up to the call's return (arguments
         parsed and put, program enqueued) and ``eng.tick.read_tokens``
-        for the blocking read, during which the device is busy."""
+        for the blocking read, during which the device is busy. What
+        the calling thread left in ``while_device_runs.hook`` runs
+        between the two: host work the device need not wait for."""
         with _span('eng.tick.dispatch'):
             cache, toks, logits = self._unpack(prog(self._params, *args))
+            hook = getattr(while_device_runs, 'hook', None)
+            if hook is not None:
+                hook()
         with _span('eng.tick.read_tokens'):
             toks = onp.asarray(toks)
         return cache, toks, \
@@ -554,6 +580,11 @@ class DecodeProgram:
         import jax
         import jax.numpy as jnp
         from .sampling import key_for, sample_tokens
+        if self._spec is None:
+            raise FamilyUnsupported(
+                self.model.family, 'the CPU fallback (fallback_generate '
+                'replays through a single-slot cache, which the family '
+                'does not have)')
         cpu = jax.devices('cpu')[0]
         with self._build_lock:
             if self._cpu_params is None:
@@ -777,6 +808,15 @@ class PagedDecodeProgram(DecodeProgram):
             else _knob('MXNET_TPU_SERVE_PAGE_SIZE', 16))
         self._pspec = model.paged_spec(self.page_size)
         self.max_pages = self._pspec.max_pages
+        # two kinds of layer (paged.PagedCacheSpec): a sliding-window
+        # layer's table is a ring of ``window_pages`` columns and its
+        # pools hold every slot's ring plus the trash page; 0 where
+        # the model has one kind
+        self.window_pages = self._pspec.window_pages
+        self.window_pool_pages = self.slots * self.window_pages + 1 \
+            if self.window_pages else 0
+        self._n_stats = len(getattr(model, 'step_stats', ()))
+        self.last_step_stats = {}
         if pages is None:
             # default pool = the slot cache's worst-case capacity
             # (every slot filling max_len) + the trash page; shrink it
@@ -791,37 +831,57 @@ class PagedDecodeProgram(DecodeProgram):
                           else _knob('MXNET_TPU_SERVE_SPEC_K', 0))
         if self.spec_k < 0:
             raise ValueError('spec_k must be >= 0')
+        if self.spec_k and self.window_pages:
+            # at freeze time, not at the first verify call
+            raise FamilyUnsupported(
+                model.family, 'paged_verify (speculative decoding, '
+                'spec_k > 0) over a window layer\'s ring of pages')
 
     # -- accounting (the satellite fix: report POOL bytes, not the
     # slots × max_len worst case the slot cache reserved) ------------------
 
     def cache_bytes(self):
-        return pool_bytes(self._pspec, self.pages)
+        return pool_bytes(self._pspec, self.pages, self.window_pool_pages)
 
     def page_bytes(self):
         """Bytes one page holds across every cache entry."""
-        return pool_bytes(self._pspec, 1)
+        return pool_bytes(self._pspec, 1, 1)
 
     def per_sequence_bytes(self, seq_len=None):
         """Amortized cache bytes for a sequence of ``seq_len`` tokens
         (default: the worst case, max_len): pages are the granularity,
         so a 12-token sequence at page_size 16 holds ONE page, not
-        max_len rows."""
+        max_len rows. A window layer never holds more than its ring.
+        """
         n = self.model.max_len if seq_len is None else int(seq_len)
-        return pages_for(n, self.page_size) * self.page_bytes()
+        held = pages_for(n, self.page_size)
+        return pool_bytes(self._pspec, held,
+                          min(held, self.window_pages))
 
     def new_cache(self):
         """Fresh zeroed page pool."""
-        return init_pool(self._pspec, self.pages)
+        return init_pool(self._pspec, self.pages, self.window_pool_pages)
 
     def _cache_avals(self):
-        return pool_avals(self._pspec, self.pages)
+        return pool_avals(self._pspec, self.pages,
+                          self.window_pool_pages)
 
     def _manifest_extra(self):
-        return {'paged': True, 'page_size': self.page_size,
-                'pages': self.pages, 'spec_k': self.spec_k,
-                'max_pages': self.max_pages,
-                'page_bytes': self.page_bytes()}
+        out = {'paged': True, 'page_size': self.page_size,
+               'pages': self.pages, 'spec_k': self.spec_k,
+               'max_pages': self.max_pages,
+               'page_bytes': self.page_bytes()}
+        if self.window_pages:
+            out.update(window_pages=self.window_pages,
+                       window_pool_pages=self.window_pool_pages)
+        return out
+
+    def _by_kind(self, full, window):
+        """Page ids, tables or avals of them as the programs take
+        them: the full layers' alone where the model has one kind of
+        layer, ``{'full': ..., 'window': ...}`` where it has two."""
+        return {'full': full, 'window': window} if self.window_pages \
+            else full
 
     # -- program construction ----------------------------------------------
 
@@ -863,26 +923,32 @@ class PagedDecodeProgram(DecodeProgram):
         model, emit = self.model, self.emit_logits
         sample, gather = self.sample_args, self._gather_ad
 
+        def with_stats(tok, stats):
+            # a family's device-side counts (model.step_stats) ride
+            # behind the tokens: one array, one read a tick
+            return jnp.concatenate([tok, stats[0]]) if stats else tok
+
         if not self._has_extras:
             def fn(params, pool, tokens, positions, tables):
                 counts[key] = counts.get(key, 0) + 1
-                pool, logits = model.paged_step(params, pool, tokens,
-                                                positions, tables)
+                pool, logits, *stats = model.paged_step(
+                    params, pool, tokens, positions, tables)
                 tok = jnp.argmax(logits, axis=-1).astype('int32')
+                tok = with_stats(tok, stats)
                 return (pool, tok, logits) if emit else (pool, tok)
             return fn
 
         def fn(params, pool, tokens, positions, tables, extras):
             counts[key] = counts.get(key, 0) + 1
-            pool, logits = model.paged_step(params, pool, tokens,
-                                            positions, tables,
-                                            gather(extras))
+            pool, logits, *stats = model.paged_step(
+                params, pool, tokens, positions, tables, gather(extras))
             if sample:
                 tok = sample_tokens(logits, extras['temps'],
                                     extras['top_ps'], extras['keys'],
                                     extras.get('masks'))
             else:
                 tok = jnp.argmax(logits, axis=-1).astype('int32')
+            tok = with_stats(tok, stats)
             return (pool, tok, logits) if emit else (pool, tok)
         return fn
 
@@ -931,10 +997,19 @@ class PagedDecodeProgram(DecodeProgram):
     def _copy_fn(self, key):
         counts = self.trace_counts
 
+        windowed = self._pspec.window_entries
+
         def fn(params, pool, src, dst):
             counts[key] = counts.get(key, 0) + 1
             del params
-            return {name: _paged.copy_page(arr, src, dst)
+            if not windowed:
+                return {name: _paged.copy_page(arr, src, dst)
+                        for name, arr in pool.items()}
+            # each kind of layer copies within its own pools
+            kind = {name: 'window' if name in windowed else 'full'
+                    for name in pool}
+            return {name: _paged.copy_page(arr, src[kind[name]],
+                                           dst[kind[name]])
                     for name, arr in pool.items()}
         return fn
 
@@ -942,9 +1017,10 @@ class PagedDecodeProgram(DecodeProgram):
         import jax
         key = self._program_key('prefill:%d' % bucket)
         npages = pages_for(bucket, self.page_size)
+        ids = jax.ShapeDtypeStruct((npages,), 'int32')
         avals = [jax.ShapeDtypeStruct((1, bucket), 'int32'),
                  jax.ShapeDtypeStruct((), 'int32'),
-                 jax.ShapeDtypeStruct((npages,), 'int32')]
+                 self._by_kind(ids, ids)]
         if self._has_extras:
             avals.append(self._extra_avals('prefill'))
         return self._build(key, 'prefill_b%d' % bucket,
@@ -955,8 +1031,11 @@ class PagedDecodeProgram(DecodeProgram):
         key = self._program_key('step')
         avals = [jax.ShapeDtypeStruct((self.slots,), 'int32'),
                  jax.ShapeDtypeStruct((self.slots,), 'int32'),
-                 jax.ShapeDtypeStruct((self.slots, self.max_pages),
-                                      'int32')]
+                 self._by_kind(
+                     jax.ShapeDtypeStruct((self.slots, self.max_pages),
+                                          'int32'),
+                     jax.ShapeDtypeStruct((self.slots, self.window_pages),
+                                          'int32'))]
         if self._has_extras:
             avals.append(self._extra_avals('step'))
         return self._build(key, self._step_name(),
@@ -980,10 +1059,10 @@ class PagedDecodeProgram(DecodeProgram):
     def compile_copy_page(self):
         import jax
         key = self._program_key('copy')
-        return self._build(
-            key, 'page_copy', self._copy_fn(key),
-            jax.ShapeDtypeStruct((), 'int32'),
-            jax.ShapeDtypeStruct((), 'int32'))
+        page = jax.ShapeDtypeStruct((), 'int32')
+        return self._build(key, 'page_copy', self._copy_fn(key),
+                           self._by_kind(page, page),
+                           self._by_kind(page, page))
 
     def warmup(self, buckets=None):
         """Ladder + step + copy_page (+ verify under speculation):
@@ -1000,44 +1079,66 @@ class PagedDecodeProgram(DecodeProgram):
 
     def run_prefill(self, pool, tokens, page_ids, temps=None,
                     top_ps=None, keys=None, masks=None, apool=None,
-                    aidx=None):
+                    aidx=None, wpage_ids=None):
         """Pad ``tokens`` to its bucket and land its K/V in the
         host-allocated ``page_ids`` (list; padded with the trash page
-        to the bucket's page count). Returns (pool', first_token,
-        logits | None)."""
+        to the bucket's page count). ``wpage_ids`` is the same list
+        for the window layers of a model that has them: one id a
+        prompt page, the trash page for each page already behind the
+        window. Returns (pool', first_token, logits | None)."""
         tokens = onp.asarray(tokens, 'int32').reshape(-1)
         n = tokens.shape[0]
         if n < 1:
             raise ValueError('empty prompt')
         bucket = self.policy.bucket_for(n)
         npages = pages_for(bucket, self.page_size)
-        ids = list(page_ids)
-        if len(ids) > npages:
-            raise ValueError('%d page ids for a %d-page bucket'
-                             % (len(ids), npages))
-        ids = ids + [TRASH_PAGE] * (npages - len(ids))
+
+        def padded_ids(ids):
+            ids = list(ids)
+            if len(ids) > npages:
+                raise ValueError('%d page ids for a %d-page bucket'
+                                 % (len(ids), npages))
+            return onp.asarray(ids + [TRASH_PAGE] * (npages - len(ids)),
+                               'int32')
+
         padded = onp.zeros((1, bucket), 'int32')
         padded[0, :n] = tokens
         prog = self.compile_prefill(bucket)
         pool, tok, logits = self._call(
-            prog, pool, padded, onp.int32(n), onp.asarray(ids, 'int32'),
+            prog, pool, padded, onp.int32(n),
+            self._by_kind(padded_ids(page_ids),
+                          padded_ids(wpage_ids or ())),
             *self._extra_args('prefill', temps, top_ps, keys, masks,
                               apool, aidx))
         return pool, int(tok), logits
 
     def run_step(self, pool, tokens, positions, tables, temps=None,
                  top_ps=None, keys=None, masks=None, apool=None,
-                 aidx=None):
-        """Advance every slot one token through its page table."""
+                 aidx=None, wtables=None):
+        """Advance every slot one token through its page table
+        (``wtables``: the window layers' ring tables, for a model that
+        has them). A family's device-side counts come back behind the
+        tokens and are left in ``last_step_stats``."""
         prog = self.compile_step()
-        return self._call(
+        if self.window_pages:
+            wtables = onp.asarray(wtables, 'int32').reshape(
+                self.slots, self.window_pages)
+        pool, toks, logits = self._call(
             prog, pool,
             onp.asarray(tokens, 'int32').reshape(self.slots),
             onp.asarray(positions, 'int32').reshape(self.slots),
-            onp.asarray(tables, 'int32').reshape(self.slots,
-                                                 self.max_pages),
+            self._by_kind(
+                onp.asarray(tables, 'int32').reshape(self.slots,
+                                                     self.max_pages),
+                wtables),
             *self._extra_args('step', temps, top_ps, keys, masks,
                               apool, aidx))
+        if self._n_stats:
+            self.last_step_stats = dict(zip(
+                self.model.step_stats,
+                (int(v) for v in toks[self.slots:])))
+            toks = toks[:self.slots]
+        return pool, toks, logits
 
     def run_verify(self, pool, tokens, positions, tables, temps=None,
                    top_ps=None, keys=None, masks=None, apool=None,
@@ -1058,13 +1159,24 @@ class PagedDecodeProgram(DecodeProgram):
             *self._extra_args('verify', temps, top_ps, keys, masks,
                               apool, aidx))
 
-    def run_copy_page(self, pool, src, dst):
-        """Copy-on-write: duplicate page ``src`` into ``dst``."""
+    def run_copy_page(self, pool, src, dst, wsrc=TRASH_PAGE,
+                      wdst=TRASH_PAGE):
+        """Copy-on-write: duplicate page ``src`` into ``dst`` in the
+        full layers' pools and ``wsrc`` into ``wdst`` in the window
+        layers' (the trash page onto itself where only one kind
+        copies)."""
         prog = self.compile_copy_page()
-        return prog(self._params, pool, onp.int32(src),
-                    onp.int32(dst))
+        return prog(self._params, pool,
+                    self._by_kind(onp.int32(src), onp.int32(wsrc)),
+                    self._by_kind(onp.int32(dst), onp.int32(wdst)))
 
     # -- live migration (seqstate export/import) ----------------------------
+
+    def _no_window(self, what):
+        if self.window_pages:
+            raise FamilyUnsupported(
+                self.model.family, '%s: the seqstate payload carries one '
+                'page list a sequence, not a ring beside it' % what)
 
     def export_pages(self, pool, page_ids):
         """Gather ``page_ids`` from the pool to host rows, keyed by
@@ -1073,6 +1185,7 @@ class PagedDecodeProgram(DecodeProgram):
         host, not the pool); migration is rare, so eager ops — the
         step program's zero-retrace contract is untouched."""
         import jax.numpy as jnp
+        self._no_window('export_pages (live migration)')
         ids = onp.asarray(list(page_ids), 'int32')
         out = {}
         for name, arr in pool.items():
@@ -1089,6 +1202,7 @@ class PagedDecodeProgram(DecodeProgram):
         is exactly the pool's init state (additive masks keep unused
         rows inert). Returns the new pool."""
         import jax.numpy as jnp
+        self._no_window('import_pages (live migration)')
         ids = onp.asarray(list(page_ids), 'int32')
         want = ids.shape[0] * self.page_size
         out = dict(pool)

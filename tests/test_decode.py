@@ -300,6 +300,72 @@ def test_engine_streams_and_retires_on_length():
         eng.close()
 
 
+def test_outbox_holds_the_scheduler_threads_tokens_and_no_other_threads():
+    """A stream's list of tokens and its end are up to date at once; its
+    reader is woken when the outbox is flushed, in order. Another thread
+    (the reaper, close, the CPU fallback) puts directly."""
+    from mxnet_tpu.serving.decode.engine import GenerateStream, _Outbox
+    box, s = _Outbox(), GenerateStream(3)
+    s._outbox = box
+    box.owner = threading.get_ident()
+    s._emit(5)
+    s._emit(6)
+    s._finish('length')
+    assert s.tokens == [5, 6] and s.done() and s.result(0) == [5, 6]
+    assert s._q.empty() and len(box.items) == 3
+    box.flush()
+    assert list(s) == [5, 6] and not box.items and box.flushed
+    other = GenerateStream(3)
+    other._outbox = box
+    th = threading.Thread(target=other._emit, args=(7,))
+    th.start()
+    th.join()
+    assert other._q.get_nowait() == 7 and not box.items
+
+
+def test_engine_hands_tokens_over_once_the_next_program_is_enqueued():
+    """A program that, like ``DecodeProgram._call``, runs its thread's
+    ``while_device_runs.hook`` between enqueueing and reading: the token a
+    call produced is in ``stream.tokens`` when the call returns and reaches
+    the stream's reader inside the NEXT call, while the device would be
+    busy; the last ones when the engine falls idle. The order holds."""
+    from mxnet_tpu.serving.decode.program import while_device_runs
+    gate = threading.Event()
+
+    class Program(_FakeProgram):
+        watch, seen = None, []
+
+        def _enqueued(self):
+            gate.wait(10)
+            s = self.watch
+            before = s._q.qsize()
+            while_device_runs.hook()
+            self.seen.append((len(s.tokens), before, s._q.qsize()))
+
+        def run_prefill(self, cache, tokens, slot):
+            self._enqueued()
+            return super().run_prefill(cache, tokens, slot)
+
+        def run_step(self, cache, tokens, positions):
+            self._enqueued()
+            return super().run_step(cache, tokens, positions)
+
+    prog = Program()
+    eng = DecodeEngine(prog, timeout_s=10.0)
+    try:
+        s = eng.generate([1, 2, 3], max_new_tokens=5)
+        prog.watch = s
+        gate.set()
+        assert s.result(10) == _expected([1, 2, 3], 5)
+        # one prefill and four steps: each call finds the tokens of the
+        # calls before it emitted, the last of them not yet handed over
+        assert prog.seen == [(0, 0, 0)] + [(n, n - 1, n)
+                                           for n in range(1, 5)]
+        assert list(s) == _expected([1, 2, 3], 5)     # idle: all there
+    finally:
+        eng.close()
+
+
 def test_engine_eos_retires_early():
     prompt = [4, 1]
     eos = _expected(prompt, 3)[2]

@@ -142,6 +142,132 @@ def test_prefix_cache_release_leaf_steals_tail_only():
     assert covered == 6
 
 
+class _WalkedRegistry:
+    """The registry's rule written out the slow way: every node in the
+    order it was last touched, an eviction walks it from the front for
+    the first node with no child."""
+
+    def __init__(self, page_size):
+        self.ps, self.nodes = page_size, {}      # key -> [page, children]
+
+    def _keys(self, prompt):
+        key, out = None, []
+        for i in range(0, len(prompt), self.ps):
+            key = (key, tuple(prompt[i:i + self.ps]))
+            out.append(key)
+        return out
+
+    def _touch(self, key):
+        self.nodes[key] = self.nodes.pop(key)
+
+    def register(self, prompt, pages):
+        for key, page in zip(self._keys(prompt), pages):
+            if key not in self.nodes:
+                self.nodes[key] = [page, 0]
+                if key[0] is not None:
+                    self.nodes[key[0]][1] += 1
+            self._touch(key)
+
+    def lookup(self, prompt):
+        covered = 0
+        for key in self._keys(prompt):
+            if key not in self.nodes:
+                break
+            self._touch(key)
+            covered += len(key[1])
+        return covered
+
+    def _drop(self, key):
+        page, _ = self.nodes.pop(key)
+        if key[0] is not None:
+            self.nodes[key[0]][1] -= 1
+        return page
+
+    def release_leaf(self, page):
+        key = next((k for k, v in self.nodes.items() if v[0] == page), None)
+        if key is None or self.nodes[key][1]:
+            return False
+        self._drop(key)
+        return True
+
+    def evict(self, count):
+        out = []
+        for _ in range(count):
+            key = next((k for k, v in self.nodes.items() if not v[1]), None)
+            if key is None:
+                break
+            out.append(self._drop(key))
+        return out
+
+
+@pytest.mark.parametrize('seed', [0, 1, 2, 3])
+def test_prefix_cache_evicts_what_a_walk_over_every_node_would(seed):
+    """Random registrations, lookups, stolen leaves and evictions: the
+    registry, which keeps its leaves in a heap and keys a node by its
+    parent's serial number, gives back the pages, in the order, that a
+    walk over every node in least-recently-used order would."""
+    rng = np.random.RandomState(seed)
+    a = PageAllocator(4096)
+    pc, walked = PrefixCache(2, a), _WalkedRegistry(2)
+    prompts = [[int(t) for t in rng.randint(0, 3, rng.randint(1, 12))]
+               for _ in range(40)]
+    for _ in range(400):
+        op, prompt = rng.randint(0, 10), prompts[rng.randint(0, 40)]
+        if op < 4:
+            ids = a.alloc(pages_for(len(prompt), 2))
+            pc.register(prompt, ids)
+            walked.register(prompt, ids)
+            for p in ids:
+                a.release(p)            # the registry's holds remain
+        elif op < 7:
+            assert pc.lookup(prompt)[1] == walked.lookup(prompt)
+        elif op < 8 and walked.nodes:
+            pages = [v[0] for v in walked.nodes.values()]
+            page = pages[rng.randint(0, len(pages))]
+            assert pc.release_leaf(page) == walked.release_leaf(page)
+        else:
+            want = walked.evict(rng.randint(1, 6))
+            assert pc.evict_lru(a.free_pages + len(want)) == want
+        assert len(pc) == len(walked.nodes)
+        # a leaf touched again and again leaves entries behind: bounded
+        assert len(pc._leaves) <= 4 * len(pc) + 65
+    assert pc.evictions > 50
+    # what is left goes in the walk's order too, to the last node
+    want = walked.evict(len(walked.nodes))
+    assert pc.evict_lru(a.pages) == want and len(pc) == 0
+    assert a.free_pages == a.pages - 1
+
+
+def test_prefix_cache_key_is_one_page_and_its_parents_serial():
+    """A key holds the page's own tokens and its parent's serial number,
+    not the chain behind it (hashing a chain of nested tuples was
+    quadratic in its pages), and a serial number is never used twice: a
+    chain that was evicted and registered again cannot be reached
+    through what an older registration left behind."""
+    a = PageAllocator(64)
+    pc = PrefixCache(2, a)
+    chain = list(range(20))
+    ids = a.alloc(10)
+    pc.register(chain, ids)
+    assert all(k[0] is None or isinstance(k[0], int) for k in pc._nodes)
+    assert all(len(k[1]) == 2 for k in pc._nodes)
+    for p in ids:
+        a.release(p)
+    assert pc.evict_lru(63) == ids[::-1] and len(pc) == 0
+    # the same first page under a new serial: the old second page's
+    # tokens do not follow it
+    other = a.alloc(2)
+    pc.register([0, 1, 7, 7], other)
+    assert pc.lookup(chain) == ([other[0]], 2)
+    # two chains with the same tokens at depth 2 stay apart
+    more = a.alloc(2)
+    pc.register([5, 5, 7, 7], more)
+    assert pc.lookup([5, 5, 7, 7]) == (more, 4)
+    assert pc.lookup([0, 1, 7, 7]) == (other, 4)
+    # and a namespace roots a trie of its own
+    assert pc.lookup([0, 1, 7, 7], namespace='ad1') == ([], 0)
+
+
 def test_prefix_cache_lru_eviction_leaf_first():
     a = PageAllocator(8)                # 7 usable
     pc = PrefixCache(4, a)

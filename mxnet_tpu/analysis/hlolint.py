@@ -27,7 +27,7 @@ reads — one shared instruction iterator,
     read through the page table (a gather must be present) and no
     instruction may materialize an O(pool)-sized ``copy`` of the KV
     pool (``pool_bytes`` sets the threshold) — cache updates stay
-    O(1) dynamic-slice writes on donated pool buffers.
+    O(rows) writes in place on donated pool buffers.
 
 ``check(hlo_text, expect)`` returns :class:`~mxnet_tpu.analysis.Finding`
 records; ``expect`` keys: ``amp`` ('bf16'|'fp16'|'off'), ``dp`` (int),
@@ -200,7 +200,7 @@ def check(hlo_text, expect, program='program'):
                         'HLO-DECODE-PAGED', program,
                         'O(pool)-sized copy materializes the whole KV '
                         'pool (%d+ bytes) — paged cache updates must '
-                        'stay O(1) dynamic-slice writes on the '
+                        'stay O(rows) writes in place on the '
                         'donated pool buffers' % pool_bytes,
                         instr=i.name))
 

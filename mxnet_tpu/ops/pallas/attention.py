@@ -534,26 +534,23 @@ def flash_paged_decode_attention(q, key_pool, value_pool, tables,
     (pages, page_size, U) — the shared pool every sequence's pages
     live in; ``tables`` (slots, max_pages) int32 page tables.
 
-    The per-slot history view is one XLA gather of each slot's table
-    entries (O(slots × max_len) rows — the identical read traffic the
-    slot-cache kernel paid, independent of pool size), then the same
-    single-token online-softmax kernel walks it in the fixed K_BLOCK
-    steps. Gathered rows past a slot's position — including trash-page
-    garbage behind unused table entries — carry exactly 0.0 attention
-    weight, so the paged path combines the same reduction tree over
-    the real keys as the slot path (the decode bit-identity
-    contract). A chip-side follow-up can fold the gather into the
-    kernel via scalar-prefetch BlockSpec index maps (one page id per
-    grid step); the program structure — table in, O(1) row writes,
+    The per-slot history view is ``paged.gather_pages``' (its
+    contract on ``tables`` holds here too): one XLA gather that
+    writes slots x max_len rows of K and of V to HBM whatever the
+    sequences' real lengths, then the same single-token online-softmax
+    kernel reads them back in the fixed K_BLOCK steps. Gathered rows
+    past a slot's position — including trash-page garbage behind
+    unused table entries — carry exactly 0.0 attention weight, so the
+    paged path combines the same reduction tree over the real keys as
+    the slot path (the decode bit-identity contract). A chip-side
+    follow-up can fold the gather into the kernel via scalar-prefetch
+    BlockSpec index maps (one page id per grid step) and read live
+    pages only; the program structure — table in, O(1) row writes,
     no O(pool) copy — is already the paged contract hlolint gates.
     """
-    import jax.numpy as jnp
-    pages, ps, u = key_pool.shape
-    gk = jnp.take(key_pool, tables, axis=0)     # (S, P, ps, U)
-    gv = jnp.take(value_pool, tables, axis=0)
-    s, p = tables.shape
-    keys = gk.reshape(s, p * ps, u)
-    values = gv.reshape(s, p * ps, u)
+    from ...serving.decode.paged import gather_pages
+    keys = gather_pages(key_pool, tables)       # (S, P * ps, U)
+    values = gather_pages(value_pool, tables)
     return flash_decode_attention(q, keys, values, positions, heads,
                                   scale=scale)
 

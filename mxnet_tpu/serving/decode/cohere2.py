@@ -53,8 +53,8 @@ from __future__ import annotations
 import numpy as onp
 
 from .model import _FAMILIES, DecodeModel, FamilyUnsupported
-from .paged import (PagedCacheSpec, ring_key_positions, scatter_pages,
-                    scatter_rows)
+from .paged import (PagedCacheSpec, gather_pages, ring_key_positions,
+                    scatter_pages, scatter_rows)
 
 __all__ = ['Cohere2MoELM', 'init_cohere2_moe_lm']
 
@@ -364,17 +364,6 @@ class Cohere2MoELM(DecodeModel):
                           preferred_element_type='float32').reshape(
                               s, self.heads * self.head_dim)
 
-    @staticmethod
-    def _gather(pool_arr, tables):
-        """The rows a table names: pool_arr (pages, page_size, row),
-        tables (slots, P) -> (slots, P * page_size, row). The engine's
-        tables name pages of the pool only, so nothing fills rows of
-        out-of-range pages (paged.gather_pages' ``jnp.take`` does)."""
-        import jax
-        with jax.named_scope('kv_gather'):
-            g = pool_arr.at[tables].get(mode='promise_in_bounds')
-            return g.reshape((g.shape[0], -1) + g.shape[3:])
-
     def _embed(self, params, tokens):
         import jax
         import jax.numpy as jnp
@@ -523,8 +512,8 @@ class Cohere2MoELM(DecodeModel):
                     pool[vk] = scatter_rows(
                         pool[vk], v.reshape(v.shape[0], -1), at[group],
                         offsets)
-                keys = self._gather(pool[kk], tabs[group])
-                values = self._gather(pool[vk], tabs[group])
+                keys = gather_pages(pool[kk], tabs[group])
+                values = gather_pages(pool[vk], tabs[group])
                 with jax.named_scope('attn'):
                     attn = self._mm(
                         'to,oh->th',

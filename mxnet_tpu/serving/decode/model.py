@@ -32,8 +32,8 @@ from __future__ import annotations
 import numpy as onp
 
 from .cache import CacheSpec, write_position, write_slot
-from .paged import (PagedCacheSpec, gather_pages, write_paged_chunk,
-                    write_paged_rows, write_prefill_pages)
+from .paged import (PagedCacheSpec, gather_pages, scatter_rows,
+                    write_prefill_pages)
 
 __all__ = ['DecodeModel', 'RNNLM', 'TransformerLM', 'from_gluon_rnn_lm',
            'model_from_config', 'init_rnn_lm', 'init_transformer_lm',
@@ -388,20 +388,39 @@ class TransformerLM(DecodeModel):
             return jnp.einsum('...u,vu->...v', h, params['embed']) \
                 + params['out_bias']
 
-    def _attend_rows(self, q, keys, values, bias):
-        """One query row a slot over its own (slots, L, units) keys
-        and values (the slot cache's rows, or the pages a table
-        gathered): q (slots, units) already scaled, bias (slots, 1, L)
-        masking what lies beyond each slot's position."""
+    def _attend_view(self, q, keys, values, bias):
+        """C query rows a slot over its own (slots, L, units) keys and
+        values (the slot cache's rows, or the pages a table gathered):
+        q (slots, C, units) already scaled, bias (slots, C, L) masking
+        what lies beyond each query's position. Returns (slots, C,
+        units).
+
+        No head is split out of the view. A head's query is laid over
+        all ``units`` columns, zero outside the head's own, so both
+        contractions run over the rows as they lie in the pool, and
+        each column of the context keeps its own head's sum. The zeros
+        cost ``heads`` times the multiply-adds of a step that waits
+        for memory; the split (slots, L, heads, D) cost a relayout of
+        both views on a TPU, whose tiles want the minor dimension 128
+        wide and get D: at GPT-1's size 28 ms of a 60 ms step once
+        nothing else rewrote the view (PERF.md section 6, PR 31)."""
         import jax.numpy as jnp
-        qh = self._heads_split(q)                         # (S,H,D)
-        kh = self._heads_split(keys)                      # (S,L,H,D)
-        vh = self._heads_split(values)
-        scores = jnp.einsum('shd,slhd->shl', qh, kh) + bias
+        s, c, u = q.shape
+        h = self.heads
+        own = jnp.arange(u)[None, :] // (u // h) \
+            == jnp.arange(h)[:, None]                     # (H, U)
+        qx = jnp.where(own, q[:, :, None, :], 0.0).reshape(s, c * h, u)
+        scores = jnp.einsum('smu,slu->sml', qx, keys).reshape(
+            s, c, h, -1) + bias[:, :, None, :]
         att = jnp.exp(scores - jnp.max(scores, axis=-1, keepdims=True))
         att = att / jnp.sum(att, axis=-1, keepdims=True)
-        ctx = jnp.einsum('shl,slhd->shd', att, vh)
-        return ctx.reshape(q.shape[0], self.units)
+        cx = jnp.einsum('sml,slu->smu', att.reshape(s, c * h, -1),
+                        values).reshape(s, c, h, u)
+        return jnp.sum(jnp.where(own, cx, 0.0), axis=2)
+
+    def _attend_rows(self, q, keys, values, bias):
+        """One query row a slot: q (slots, units), bias (slots, 1, L)."""
+        return self._attend_view(q[:, None], keys, values, bias)[:, 0]
 
     def _attn_out(self, params, i, x, ctx):
         """Output projection, residual and LayerNorm closing layer
@@ -608,9 +627,9 @@ class TransformerLM(DecodeModel):
                     qkv = self._adapted(x, p('qkv_w'), p('qkv_b'),
                                         ad, 'l%d_qkv' % i)
                     q, k, v = jnp.split(qkv, 3, axis=-1)
-                    pool['l%d_k' % i] = write_paged_rows(
+                    pool['l%d_k' % i] = scatter_rows(
                         pool['l%d_k' % i], k, page_ids, offsets)
-                    pool['l%d_v' % i] = write_paged_rows(
+                    pool['l%d_v' % i] = scatter_rows(
                         pool['l%d_v' % i], v, page_ids, offsets)
                 if flash:
                     # page-table gather + the same single-token kernel
@@ -648,7 +667,7 @@ class TransformerLM(DecodeModel):
         overwritten — docs/DIVERGENCES.md)."""
         import jax
         import jax.numpy as jnp
-        slots, C = tokens.shape
+        C = tokens.shape[1]
         ps = pool[next(iter(pool))].shape[1]
         qpos = positions[:, None] + jnp.arange(C)[None, :]  # (S, C)
         x = self._embed(params, tokens, qpos)               # (S, C, U)
@@ -658,7 +677,7 @@ class TransformerLM(DecodeModel):
         ar = jnp.arange(lp)
         # query c of slot s sees key j iff j <= positions[s] + c
         bias = jnp.where(ar[None, None, :] <= qpos[:, :, None],
-                         0.0, -1e9)[:, None]           # (S, 1, C, Lp)
+                         0.0, -1e9)                       # (S, C, Lp)
         scale = 1.0 / float(onp.sqrt(self.units // self.heads))
         pool = dict(pool)
         for i in range(self.layers):
@@ -668,23 +687,14 @@ class TransformerLM(DecodeModel):
                     qkv = self._adapted(x, p('qkv_w'), p('qkv_b'),
                                         ad, 'l%d_qkv' % i)
                     q, k, v = jnp.split(qkv, 3, axis=-1)
-                    pool['l%d_k' % i] = write_paged_chunk(
+                    pool['l%d_k' % i] = scatter_rows(
                         pool['l%d_k' % i], k, page_ids, offsets)
-                    pool['l%d_v' % i] = write_paged_chunk(
+                    pool['l%d_v' % i] = scatter_rows(
                         pool['l%d_v' % i], v, page_ids, offsets)
                 ck = gather_pages(pool['l%d_k' % i], tables)
                 cv = gather_pages(pool['l%d_v' % i], tables)
                 with jax.named_scope('attn'):
-                    qh = self._heads_split(q * scale)     # (S,C,H,D)
-                    kh = self._heads_split(ck)            # (S,Lp,H,D)
-                    vh = self._heads_split(cv)
-                    scores = jnp.einsum('schd,slhd->shcl',
-                                        qh, kh) + bias
-                    att = jnp.exp(scores - jnp.max(
-                        scores, axis=-1, keepdims=True))
-                    att = att / jnp.sum(att, axis=-1, keepdims=True)
-                    ctx = jnp.einsum('shcl,slhd->schd', att, vh)
-                    ctx = ctx.reshape(slots, C, self.units)
+                    ctx = self._attend_view(q * scale, ck, cv, bias)
                 x = self._attn_out(params, i, x, ctx)
                 x = self._ffn_block(params, i, x, ad)
         return pool, self._head(params, x)              # (S, C, V)

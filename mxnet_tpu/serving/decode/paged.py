@@ -13,9 +13,10 @@ the reservation into fixed-size **pages** of ``page_size`` rows:
     ``(max_pages,)`` vector of pool page indices — carried into the
     one compiled decode-step program as a plain array argument. The
     program's only cache ops are a gather of the table entries (the
-    per-slot K/V view) and O(1) ``lax.dynamic_update_slice`` row
-    writes at ``(table[pos // page_size], pos % page_size)`` — never
-    an O(pool) copy;
+    per-slot K/V view) and one scatter of the step's new rows to
+    ``(table[pos // page_size], pos % page_size)`` — never an O(pool)
+    copy. Both promise their indices in bounds: the contract on
+    tables is in :func:`gather_pages`;
   * **allocation, freeing, refcounting, prefix sharing, and
     copy-on-write decisions all happen host-side** in the engine
     scheduler (:class:`PageAllocator`, :class:`PrefixCache`). The
@@ -53,8 +54,7 @@ import numpy as onp
 
 __all__ = ['PagedCacheSpec', 'PageAllocator', 'PrefixCache',
            'TRASH_PAGE', 'init_pool', 'pool_avals', 'pool_bytes',
-           'gather_pages', 'write_paged_rows', 'write_paged_chunk',
-           'write_prefill_pages', 'copy_page', 'pages_for',
+           'gather_pages', 'write_prefill_pages', 'copy_page', 'pages_for',
            'scatter_rows', 'scatter_pages', 'ring_key_positions',
            'window_table_pages']
 
@@ -203,55 +203,31 @@ def gather_pages(pool_arr, tables):
     (pages, page_size, *row), ``tables`` (slots, max_pages) int32 ->
     (slots, max_pages * page_size, *row).
 
-    One XLA gather of O(slots × max_len) rows — the same read traffic
-    the slot cache's per-step view cost, independent of pool size (the
-    HLO-DECODE-PAGED lint asserts no O(pool) materializing copy
-    appears instead)."""
+    The contract on tables, for every pool operation of a compiled
+    program (this gather, :func:`scatter_rows`, :func:`scatter_pages`):
+    an entry names a page of the pool, and an unused entry holds
+    ``TRASH_PAGE``. The engine makes it true in two places: a
+    sequence's table starts ``TRASH_PAGE``-filled and afterwards takes
+    only pages its ``PageAllocator`` handed out
+    (``engine._admit_paged``), and the step's tables start from zeros,
+    which is the trash page, and copy live sequences' tables in
+    (``engine._paged_step``, ``_spec_step``). So the indices are
+    promised in bounds: nothing compares them with the pool's size,
+    and nothing lays a fill for out-of-range pages over the view
+    (``jnp.take``'s default did: a select over the whole view, 14.6 ms
+    of GPT-1's 52 ms step on a v5e; PERF.md section 6, PR 31).
+
+    One XLA gather that writes slots x max_pages x page_size rows to
+    HBM, whatever the sequences' real lengths, for the attention to
+    read back: the same traffic the slot cache's per-step view cost,
+    independent of pool size (the HLO-DECODE-PAGED lint asserts no
+    O(pool) materializing copy appears instead). Only attention that
+    walks the table itself would read the live rows alone."""
     import jax
-    import jax.numpy as jnp
     with jax.named_scope('kv_gather'):
-        g = jnp.take(pool_arr, tables, axis=0)   # (S, P, ps, *row)
-        s, p, ps = g.shape[:3]
+        g = pool_arr.at[tables].get(mode='promise_in_bounds')
+        s, p, ps = g.shape[:3]                   # (S, P, ps, *row)
         return g.reshape((s, p * ps) + g.shape[3:])
-
-
-def _row_write(pool_arr, row, page_id, offset):
-    import jax.numpy as jnp
-    from jax import lax
-    start = (jnp.asarray(page_id, 'int32'),
-             jnp.asarray(offset, 'int32')) + tuple(
-                 jnp.asarray(0, 'int32')
-                 for _ in range(pool_arr.ndim - 2))
-    return lax.dynamic_update_slice(
-        pool_arr, row[None, None].astype(pool_arr.dtype), start)
-
-
-def write_paged_rows(pool_arr, rows, page_ids, offsets):
-    """The decode-step KV append through the page table: one row per
-    slot at that slot's own ``(page, offset)``.
-
-    ``rows`` (slots, *row); ``page_ids``/``offsets`` (slots,) traced
-    int32. Slots is static, so this unrolls to ``slots`` dynamic
-    update slices — O(slots × row) like the slot cache's
-    ``write_position``, never O(pool). Distinct live slots never
-    share a writable (page, offset); padded/free slots all target the
-    trash page, where last-writer-wins garbage is masked anyway."""
-    for s in range(rows.shape[0]):
-        pool_arr = _row_write(pool_arr, rows[s], page_ids[s],
-                              offsets[s])
-    return pool_arr
-
-
-def write_paged_chunk(pool_arr, rows, page_ids, offsets):
-    """Multi-token append (the speculative verify program): ``rows``
-    (slots, C, *row), ``page_ids``/``offsets`` (slots, C). O(slots ×
-    C × row) dynamic-slice writes."""
-    slots, c = rows.shape[0], rows.shape[1]
-    for s in range(slots):
-        for j in range(c):
-            pool_arr = _row_write(pool_arr, rows[s, j],
-                                  page_ids[s, j], offsets[s, j])
-    return pool_arr
 
 
 def write_prefill_pages(pool_arr, rows, page_ids):
@@ -276,9 +252,13 @@ def write_prefill_pages(pool_arr, rows, page_ids):
 
 
 def scatter_rows(pool_arr, rows, page_ids, offsets):
-    """The decode-step KV append as one scatter: ``rows`` (slots,
-    *row) to ``(page_ids[s], offsets[s])``. Free slots all target the
-    trash page, where whichever write wins is masked anyway."""
+    """The KV append as one scatter, under :func:`gather_pages`'
+    contract: ``rows`` (*batch, *row) to ``(page_ids[b], offsets[b])``,
+    both (*batch,) int32 with ``offsets < page_size``. ``batch`` is
+    (slots,) for the decode step and (slots, C) for the speculative
+    verify's chunk. Live slots never share a (page, offset); free
+    slots all target the trash page, where whichever write wins is
+    masked anyway."""
     return pool_arr.at[page_ids, offsets].set(
         rows.astype(pool_arr.dtype), mode='promise_in_bounds')
 

@@ -847,3 +847,128 @@ def test_degraded_fallback_rebuilds_pool_and_matches_tokens():
             eng.close()
     finally:
         mx.config.unset('MXNET_TPU_FAULT')
+
+
+# ---------------------------------------------------------------------------
+# the compiled programs' pool operations promise their indices
+# ---------------------------------------------------------------------------
+
+def _pool_ops(jaxpr):
+    """(primitive, name stack, result shape, mode) of every equation
+    that could touch a pool, sub-jaxprs included."""
+    import jax
+    out = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name in ('gather', 'scatter', 'select_n',
+                                  'dynamic_update_slice', 'reshape'):
+            out.append((eqn.primitive.name,
+                        str(eqn.source_info.name_stack),
+                        tuple(eqn.outvars[0].aval.shape),
+                        eqn.params.get('mode')))
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            out += _pool_ops(sub)
+    return out
+
+
+def _step_case(caller):
+    """(the traced function's jaxpr, layers) of one caller of the pool
+    operations, at toy sizes."""
+    import jax
+    import jax.numpy as jnp
+    from mxnet_tpu.serving.decode import init_cohere2_moe_lm
+    from mxnet_tpu.serving.decode.paged import (pool_avals,
+                                                window_table_pages)
+    slots, ps = 3, 4
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32)
+
+    if caller == 'Cohere2MoELM.paged_step':
+        model, params = init_cohere2_moe_lm(seed=0)
+        pool = pool_avals(model.paged_spec(ps), 33, 13)
+        tables = {'full': i32(slots, model.max_len // ps),
+                  'window': i32(slots, window_table_pages(
+                      model.window, ps, model.max_len))}
+        args = (i32(slots), i32(slots), tables)
+        fn, layers = model.paged_step, len(model.layer_types)
+    else:
+        model, params = _model(max_len=32)
+        pool = pool_avals(model.paged_spec(ps), 25)
+        tokens = i32(slots, 3) if caller.endswith('verify') \
+            else i32(slots)
+        args = (tokens, i32(slots), i32(slots, 32 // ps))
+        fn = getattr(model, caller.split('.')[1])
+        layers = model.layers
+    return jax.make_jaxpr(fn)(params, pool, *args), pool, layers
+
+
+@pytest.mark.parametrize('caller', ['TransformerLM.paged_step',
+                                    'TransformerLM.paged_verify',
+                                    'Cohere2MoELM.paged_step'])
+def test_pool_operations_promise_their_indices(caller):
+    """Every family reads its K/V view through ``paged.gather_pages``
+    and appends through ``paged.scatter_rows``, both on indices the
+    engine guarantees: no fill is laid over the gathered view (GPT-1's
+    step spent 57 % of its time in one, PERF.md PR 31) and no append is
+    unrolled into a dynamic_update_slice a slot."""
+    from jax.lax import GatherScatterMode
+    jaxpr, pool, layers = _step_case(caller)
+    ops = _pool_ops(jaxpr.jaxpr)
+    pools = {tuple(a.shape) for a in pool.values()}
+    views = [(shape, mode) for prim, scope, shape, mode in ops
+             if prim == 'gather' and 'kv_gather' in scope]
+    assert len(views) == 2 * layers
+    assert all(mode == GatherScatterMode.PROMISE_IN_BOUNDS
+               for _, mode in views)
+    # the view as gathered (slots, pages, page_size, row) and as the
+    # attention takes it (slots, pages * page_size, row)
+    filled = {shape for shape, _ in views} | {
+        (s[0], s[1] * s[2]) + s[3:] for s, _ in views}
+    assert not [op for op in ops
+                if op[0] == 'select_n' and op[2] in filled]
+    appends = [mode for prim, _, shape, mode in ops
+               if prim == 'scatter' and shape in pools]
+    assert len(appends) == 2 * layers
+    assert all(mode == GatherScatterMode.PROMISE_IN_BOUNDS
+               for mode in appends)
+    assert not [op for op in ops
+                if op[0] == 'dynamic_update_slice' and op[2] in pools]
+    if caller.startswith('TransformerLM'):
+        # nor is a head split out of the view (_attend_view)
+        assert not [op for op in ops if op[0] == 'reshape' and any(
+            len(op[2]) == 4 and op[2][:2] == v[:2] for v in filled
+            if len(v) == 3)]
+
+
+@pytest.mark.parametrize('queries', [1, 3])
+def test_attention_over_the_view_equals_attention_head_by_head(queries):
+    """``_attend_view`` splits no head out of the (slots, L, units) view
+    (on a TPU the split is a relayout of the whole view); what it
+    computes is each head's softmax attention over its own columns."""
+    import jax.numpy as jnp
+    model, _ = _model(max_len=32)
+    rs = np.random.RandomState(queries)
+    s, length, u, h = 3, 20, model.units, model.heads
+    q = rs.randn(s, queries, u).astype('float32')
+    keys = rs.randn(s, length, u).astype('float32')
+    values = rs.randn(s, length, u).astype('float32')
+    seen = rs.randint(1, length, (s, queries))
+    bias = np.where(np.arange(length)[None, None] <= seen[:, :, None],
+                    0.0, -1e9).astype('float32')
+    got = np.asarray(model._attend_view(
+        jnp.asarray(q), jnp.asarray(keys), jnp.asarray(values),
+        jnp.asarray(bias)))
+    d = u // h
+    want = np.zeros((s, queries, u))
+    for head in range(h):
+        cols = slice(head * d, (head + 1) * d)
+        scores = np.einsum('scd,sld->scl', q[..., cols].astype('float64'),
+                           keys[..., cols]) + bias
+        att = np.exp(scores - scores.max(-1, keepdims=True))
+        att /= att.sum(-1, keepdims=True)
+        want[..., cols] = np.einsum('scl,sld->scd', att, values[..., cols])
+    np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-6)
+    rows = np.asarray(model._attend_rows(
+        jnp.asarray(q[:, 0]), jnp.asarray(keys), jnp.asarray(values),
+        jnp.asarray(bias[:, :1])))
+    np.testing.assert_allclose(rows, want[:, 0], rtol=2e-5, atol=2e-6)

@@ -335,13 +335,6 @@ KNOBS = {k.name: k for k in [
     # multi-adapter (LoRA) serving + sampled decoding
     # (serving/adapters/, docs/SERVING.md "Multi-adapter serving &
     # sampling")
-    _knob('MXNET_TPU_SERVE_SAMPLE_ARGS', bool, True,
-          'Compile temperature/top-p/PRNG-key sampling as fixed-shape'
-          ' ARRAY arguments of the one decode step: greedy and'
-          ' sampled requests share the same executable (temperature 0'
-          ' stays byte-identical to the greedy-only program). 0'
-          ' freezes the pre-sampling signature — old artifacts load'
-          ' either way.'),
     _knob('MXNET_TPU_SERVE_SAMPLE_MASK', bool, False,
           'Also compile the per-request additive logit-mask argument'
           ' (grammar/JSON constrained decoding hook): a (rows, vocab)'

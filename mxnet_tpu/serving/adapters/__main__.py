@@ -25,10 +25,10 @@ Legs:
                      traffic with the target AND draft trace_counts
                      unchanged: switching adapters is an int32 array
                      arg, never a recompile.
-  4 temp0_identity   the extras-carrying program at temperature 0 is
-                     byte-identical to the legacy program without
-                     sampling args (greedy is the degenerate case,
-                     not a different code path).
+  4 temp0_identity   the program at temperature 0 emits the greedy
+                     continuation of the uncached whole-sequence
+                     forward, token for token (greedy is the
+                     degenerate case, not a different code path).
   5 sampled_spec     same seed, same prompt: speculative decoding and
                      plain decoding emit the identical sampled stream
                      (coupled rejection sampling preserves the target
@@ -178,13 +178,13 @@ def check_zero_retrace(tmp):
     paged = freeze_decode(model, params, slots=4,
                           prefill_buckets=(16, 32), paged=True,
                           page_size=8, pages=96, spec_k=3,
-                          sample_args=True, adapter_rank=4,
+                          adapter_rank=4,
                           adapter_slots=n_adapters + 1)
     from ..decode.model import init_transformer_lm
     dm, dp = init_transformer_lm(VOCAB, units=16, hidden=32, layers=1,
                                  heads=2, max_len=96, seed=9)
     draft = freeze_decode(dm, dp, slots=4, prefill_buckets=(16, 32),
-                          paged=False, sample_args=True)
+                          paged=False)
     with DecodeEngine(paged, draft=draft, adapters=root,
                       name='retrace') as eng:
         # warmup: greedy, sampled and adapter-carrying streams
@@ -217,22 +217,23 @@ def check_temp0_identity(tmp):
     model, params = _model()
     root = os.path.join(tmp, 'temp0')
     _stamp_adapters(root, model, 1)
-    legacy = freeze_decode(model, params, slots=4,
-                           prefill_buckets=(16, 32), paged=False,
-                           sample_args=False)
     extras = freeze_decode(model, params, slots=4,
                            prefill_buckets=(16, 32), paged=False,
-                           sample_args=True, adapter_rank=4,
-                           adapter_slots=4)
-    with DecodeEngine(legacy, name='t0-leg') as e1:
-        ref = list(e1.generate(PROMPT, max_new_tokens=10))
+                           adapter_rank=4, adapter_slots=4)
+    # the greedy continuation by the uncached whole-sequence forward
+    ref = list(PROMPT)
+    for _ in range(10):
+        logits = onp.asarray(model.full_forward(
+            params, onp.asarray([ref], 'int32')))[0]
+        ref.append(int(logits[-1].argmax()))
+    ref = ref[len(PROMPT):]
     with DecodeEngine(extras, adapters=root, name='t0-ext') as e2:
         got = list(e2.generate(PROMPT, max_new_tokens=10))
         base = list(e2.generate(PROMPT, max_new_tokens=10,
                                 adapter='base'))
     if got != ref:
-        return ('temperature-0 extras stream differs from the legacy '
-                'program: %r vs %r' % (got, ref))
+        return ('temperature-0 stream differs from the reference\'s '
+                'greedy continuation: %r vs %r' % (got, ref))
     if base != ref:
         return 'adapter="base" is not bit-identical to no adapter'
     return None
@@ -248,12 +249,12 @@ def check_sampled_spec(tmp):
     paged = freeze_decode(model, params, slots=4,
                           prefill_buckets=(16, 32), paged=True,
                           page_size=8, pages=64, spec_k=3,
-                          sample_args=True, adapter_rank=4,
+                          adapter_rank=4,
                           adapter_slots=4)
     dm, dp = init_transformer_lm(VOCAB, units=16, hidden=32, layers=1,
                                  heads=2, max_len=96, seed=9)
     draft = freeze_decode(dm, dp, slots=4, prefill_buckets=(16, 32),
-                          paged=False, sample_args=True)
+                          paged=False)
     with DecodeEngine(paged, draft=draft, adapters=root,
                       name='spec') as spec_eng, \
             DecodeEngine(paged, adapters=root,
@@ -275,22 +276,27 @@ def check_sampled_spec(tmp):
 
 
 def check_prefix_isolation(tmp):
-    from ..decode.paged import PrefixCache, PageAllocator
+    import threading
+    from ..decode.paged import PagedCacheSpec, PageOwner
     from ..decode.program import freeze_decode
     from ..decode.engine import DecodeEngine
     # unit level: chains registered under one namespace are invisible
     # to every other namespace
-    alloc = PageAllocator(pages=16)
-    cache = PrefixCache(page_size=4, allocator=alloc)
-    cache.register(list(range(12)), alloc.alloc(3), namespace='ad0')
-    ids, covered = cache.lookup(list(range(12)), namespace='ad1')
+    owner = PageOwner(PagedCacheSpec({'k': ((4,), 'float32')}, 4, 64),
+                      {'full': 16}, threading.Lock(), True, {})
+    chain = list(range(12))
+    owner.register(chain, owner.place(owner.open(0), 12),
+                   namespace='ad0')
+    covered, _ = owner.share_prefix(owner.open(1), chain,
+                                    namespace='ad1')
     if covered:
         return ('namespace ad1 saw %d tokens of an ad0 chain'
                 % covered)
-    ids, covered = cache.lookup(list(range(12)), namespace='ad0')
-    if covered != 12:
+    covered, _ = owner.share_prefix(owner.open(2), chain,
+                                    namespace='ad0')
+    if covered != 11:           # all of it but the token to step on
         return 'owning namespace lost its own chain'
-    ids, covered = cache.lookup(list(range(12)))
+    covered, _ = owner.share_prefix(owner.open(3), chain)
     if covered:
         return 'null namespace saw a namespaced chain'
     # engine level: the same prompt under two adapters yields each
@@ -303,7 +309,7 @@ def check_prefix_isolation(tmp):
     prompt = [(3 * i + 1) % VOCAB for i in range(20)]
     paged = freeze_decode(model, params, slots=4,
                           prefill_buckets=(16, 32), paged=True,
-                          page_size=8, pages=64, sample_args=True,
+                          page_size=8, pages=64,
                           adapter_rank=4, adapter_slots=4)
     with DecodeEngine(paged, adapters=root, name='iso-cold') as cold:
         want_base = list(cold.generate(prompt, max_new_tokens=8))

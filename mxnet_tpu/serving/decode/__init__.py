@@ -35,8 +35,8 @@ from .engine import DecodeEngine, DrainTimeout, GenerateStream
 from .model import (DecodeModel, FamilyUnsupported, RNNLM, TransformerLM,
                     from_gluon_rnn_lm, init_rnn_lm, init_transformer_lm,
                     model_from_config)
-from .paged import (PageAllocator, PagedCacheSpec, PrefixCache,
-                    pool_bytes)
+from .paged import (PageAllocator, PagedCacheSpec, PageOwner,
+                    PrefixCache, pool_bytes)
 from .program import (DecodeProgram, PagedDecodeProgram, freeze_decode,
                       load_decode)
 from .seqstate import SEQSTATE_SCHEMA, SeqStateError
@@ -48,6 +48,7 @@ __all__ = [
     'init_cohere2_moe_lm', 'FamilyUnsupported', 'from_gluon_rnn_lm',
     'init_rnn_lm', 'init_transformer_lm', 'model_from_config',
     'DecodeProgram', 'PagedDecodeProgram', 'PageAllocator',
-    'PagedCacheSpec', 'PrefixCache', 'pool_bytes', 'freeze_decode',
+    'PagedCacheSpec', 'PageOwner', 'PrefixCache', 'pool_bytes',
+    'freeze_decode',
     'load_decode', 'SEQSTATE_SCHEMA', 'SeqStateError',
 ]

@@ -28,14 +28,17 @@ sequence DEGRADED on the CPU fallback (same math, same tokens) rather
 than erroring mid-stream.
 
 **Paged scheduling** (program.paged — docs/SERVING.md "Paged KV
-cache, prefix sharing, speculative decoding"): the engine owns the
-page pool's host state — a :class:`~.paged.PageAllocator` free list
-with refcounts, lazy per-token page allocation when a sequence's
-position crosses a page boundary, a :class:`~.paged.PrefixCache`
-that lands hash-matching prompts on shared read-only pages (no
-prefill program runs; the suffix streams through the regular decode
-step), copy-on-write before any write into a shared page, and
-LRU eviction of unreferenced cached prefixes under pool pressure.
+cache, prefix sharing, speculative decoding"): the page pool's host
+state has one owner, a :class:`~.paged.PageOwner` — free lists with
+refcounts, lazy per-token page allocation when a sequence's position
+crosses a page boundary, prefix registries that land matching
+prompts on shared read-only pages (no prefill program runs; the
+suffix streams through the regular decode step), copy-on-write
+before any write into a shared page, LRU eviction of unreferenced
+cached prefixes under pool pressure, and whatever else differs
+between the kinds of layer a model has. The engine asks it (open,
+share, place, make writable, advance, drop) and runs the device
+calls; it knows no kind of layer.
 Pool exhaustion is TYPED — admission and mid-stream allocation
 failures finish the stream with :class:`BackpressureError`, never a
 stall — and the compiled programs never see any of it (page churn
@@ -55,6 +58,7 @@ discipline as batcher.py.
 """
 from __future__ import annotations
 
+import functools
 import logging
 import queue as _queue
 import threading
@@ -64,8 +68,7 @@ import numpy as onp
 
 from ...observability.spans import span as _span
 from ..batcher import BackpressureError, BatcherClosed, RequestTimeout
-from .paged import (TRASH_PAGE, PageAllocator, PrefixCache, pages_for,
-                    pool_bytes)
+from .paged import PageOwner, pages_for
 from .sampling import key_for
 from .seqstate import SeqStateError, build_payload, decode_payload
 
@@ -243,9 +246,9 @@ class _Seq:
 
     __slots__ = ('stream', 'prompt', 'max_new', 'eos_id', 'slot',
                  'pos', 'last_token', 'enqueued_at', 'deadline_at',
-                 'first_token_at', 'table', 'pages', 'prefill_only',
+                 'first_token_at', 'pages', 'prefill_only',
                  'trace', 'adapter_id', 'adapter_idx', 'temperature',
-                 'top_p', 'seed', 'wtable', 'wpages', 'wtop')
+                 'top_p', 'seed')
 
     def __init__(self, stream, prompt, max_new, eos_id, enqueued_at,
                  deadline_at, prefill_only=False, adapter_id=None,
@@ -260,18 +263,9 @@ class _Seq:
         self.enqueued_at = enqueued_at
         self.deadline_at = deadline_at
         self.first_token_at = None
-        # paged scheduling: the per-sequence page table (np int32,
-        # max_pages entries, trash-page filled) + the pool pages this
-        # sequence holds allocator refs on
-        self.table = None
-        self.pages = []
-        # a model with sliding-window layers: their ring table
-        # (window_pages columns, logical page p in column p %
-        # window_pages), the window pool's pages this sequence holds,
-        # and the highest logical page the ring has been given
-        self.wtable = None
-        self.wpages = []
-        self.wtop = -1
+        # paged scheduling: the sequence's record with the page owner
+        # (paged.SeqPages: its tables and the pages it holds)
+        self.pages = None
         # disaggregated serving: export the seqstate at the prefill
         # boundary instead of entering the step loop
         self.prefill_only = prefill_only
@@ -390,33 +384,16 @@ class DecodeEngine:
         # boundaries (the only thread that owns the device cache):
         # (op, arg, result_box, done_event)
         self._migrations = []
-        # paged scheduling state (host side of the page pool)
+        # paged scheduling: the one owner of the pools' host state
         self.paged = bool(getattr(program, 'paged', False))
-        self._allocator = None
-        self._prefix = None
-        # two kinds of layer in one manager: the sliding-window
-        # layers' pools have an allocator and a prefix registry of
-        # their own (page ids index other pools); 0 columns = the
-        # model has one kind and none of this exists
-        self._wcols = int(getattr(program, 'window_pages', 0) or 0) \
-            if self.paged else 0
-        self._wallocator = None
-        self._wprefix = None
+        self._pages = None
         if self.paged:
-            self._allocator = PageAllocator(program.pages)
-            if self._wcols:
-                self._wallocator = PageAllocator(
-                    program.window_pool_pages)
-                self._counts['window_pages_released'] = 0
             if prefix_cache is None:
                 prefix_cache = bool(
                     _knob('MXNET_TPU_SERVE_PREFIX_CACHE', True))
-            if prefix_cache:
-                self._prefix = PrefixCache(program.page_size,
-                                           self._allocator)
-                if self._wcols:
-                    self._wprefix = PrefixCache(program.page_size,
-                                                self._wallocator)
+            self._pages = PageOwner(
+                program.page_spec, program.pool_pages, self._lock,
+                prefix_cache, self._counts, _record_event)
         # device-side counts the step program brings back behind its
         # tokens (program.last_step_stats), booked beside the host's
         self._step_stats = tuple(getattr(
@@ -487,11 +464,6 @@ class DecodeEngine:
             raise ValueError(
                 'adapters given but the program was frozen without an '
                 'adapter_spec (freeze with adapter_rank > 0)')
-        self.sample_args = bool(getattr(program, 'sample_args',
-                                        False))
-        # whether the compiled programs carry the extras argument at
-        # all (per-slot array build is skipped entirely when not)
-        self._extras_on = self.sample_args or aspec is not None
         self._outbox = _Outbox()
         self._worker = threading.Thread(
             target=self._run, daemon=True,
@@ -567,7 +539,7 @@ class DecodeEngine:
         prompt = [int(t) for t in onp.asarray(tokens).reshape(-1)]
         if not prompt:
             raise ValueError('empty prompt')
-        if prefill_only and self._wcols:
+        if prefill_only and self.paged:
             self.program._no_window('prefill_only admission (its '
                                     'seqstate export)')
         if len(prompt) > self.program.max_prompt_len():
@@ -584,11 +556,6 @@ class DecodeEngine:
             raise ValueError('temperature must be >= 0')
         if not 0 < top_p <= 1:
             raise ValueError('top_p must be in (0, 1]')
-        if temperature > 0 and not self.sample_args:
-            raise ValueError(
-                'sampled decoding requested (temperature=%g) but the '
-                'program was frozen without sampling args (freeze '
-                'with sample_args=True)' % temperature)
         from ..adapters import AdapterRegistry as _AR
         if adapter not in _AR.BASE_IDS and self._adapters is None:
             raise ValueError(
@@ -761,10 +728,7 @@ class DecodeEngine:
                 # back 64 spans for the one open over a gap, and one
                 # prefill's device call leaves some forty behind it
                 with _span('eng.tick.admit'):
-                    if self.paged:
-                        self._admit_paged(seq, slot)
-                    else:
-                        self._admit(seq, slot)
+                    self._admit(seq, slot)
                 budget -= 1
             if self._active:
                 self._step()
@@ -778,8 +742,8 @@ class DecodeEngine:
                     with self._lock:
                         inst.active_slots.set(len(self._active))
                         inst.queue_depth.set(len(self._pending))
-                        if self._allocator is not None:
-                            pool = self._allocator.stats()
+                        if self._pages is not None:
+                            pool = self._pages.pool_stats()
                             inst.pages_total.set(pool['pages_total'])
                             inst.pages_free.set(pool['pages_free'])
                             inst.page_occupancy.set(pool['occupancy_pct'])
@@ -807,7 +771,8 @@ class DecodeEngine:
                 # drop the sequence's page holds; pages whose prefix
                 # registration still holds a ref stay resident for
                 # future hits (evicted LRU under pool pressure)
-                self._drop_pages(seq)
+                if seq.pages is not None:
+                    self._pages.drop(seq.pages)
         # adapter pool unpin outside the lock (the pool has its own)
         self._release_adapter(seq)
         _record_event('decode_retire', slot=slot, reason=reason,
@@ -830,61 +795,16 @@ class DecodeEngine:
 
     def _rebuild_cache(self):
         """Fresh device cache after a failed call (donated buffers are
-        unusable): the pool's host state — free list, refcounts,
+        unusable): the pools' host state — free lists, refcounts,
         prefix registrations — describes garbage now, so it resets
-        with it. Callers retire (and release) in-flight slots FIRST.
-        """
+        with it (under the lock: stats()/cache_accounting() readers
+        must never observe a half-reset pool). Callers retire (and
+        release) in-flight slots FIRST."""
         self._cache = self.program.new_cache()
-        if self._allocator is not None:
-            # under the lock: stats()/cache_accounting() readers must
-            # never observe a half-reset pool (free list rebuilt,
-            # refcounts/registry still stale)
-            with self._lock:
-                self._allocator.reset()
-                if self._prefix is not None:
-                    self._prefix.clear()
-                if self._wallocator is not None:
-                    self._wallocator.reset()
-                if self._wprefix is not None:
-                    self._wprefix.clear()
+        if self._pages is not None:
+            self._pages.reset()
         if self._draft is not None:
             self._draft_cache = self._draft.new_cache()
-
-    def _drop_pages(self, seq):
-        """Give back every page hold of ``seq``, of both kinds of
-        layer (caller holds the lock)."""
-        if self._allocator is not None and seq.pages:
-            for p in seq.pages:
-                self._allocator.release(p)
-            seq.pages = []
-        if seq.wpages:
-            for p in seq.wpages:
-                self._wallocator.release(p)
-            seq.wpages = []
-
-    def _release_seq_pages(self, seq):
-        with self._lock:
-            self._drop_pages(seq)
-
-    def _alloc_pages(self, n, slot, window=False):
-        """``n`` fresh pages (of the window layers' pool with
-        ``window``), evicting LRU cached prefixes under pool pressure;
-        None on exhaustion (the caller fails TYPED)."""
-        allocator = self._wallocator if window else self._allocator
-        prefix = self._wprefix if window else self._prefix
-        with self._lock:
-            ids = allocator.alloc(n)
-            evicted = []
-            if ids is None and prefix is not None:
-                evicted = prefix.evict_lru(n)
-                ids = allocator.alloc(n)
-            if evicted:
-                self._counts['page_evictions'] += len(evicted)
-        for p in evicted:
-            _record_event('page_evict', page=p, slot=slot)
-        if ids is not None and slot is not None:
-            _record_event('page_alloc', pages=len(ids), slot=slot)
-        return ids
 
     def _fail_pool_exhausted(self, seq, slot, where):
         """Pool exhaustion is typed backpressure, never a stall: the
@@ -893,7 +813,7 @@ class DecodeEngine:
         with self._lock:
             self._counts['pool_exhausted'] += 1
             depth = len(self._pending)
-            free = self._allocator.free_pages
+            free = self._pages.pool_stats()['pages_free']
         inst = _serving_instruments()
         if inst is not None:
             inst.rejected.labels(reason='pool_exhausted').inc()
@@ -903,100 +823,11 @@ class DecodeEngine:
         seq.stream._finish('error', BackpressureError(
             depth, self.max_queue))
 
-    def _ensure_writable(self, seq, first_pos, last_pos):
-        """Make every page this tick will write — positions
-        ``first_pos..last_pos`` of ``seq`` — privately writable:
-        lazily allocate pages at boundary crossings, copy-on-write
-        pages shared with other sequences or the prefix registry.
-        Returns False on pool exhaustion (after LRU eviction); device
-        errors from the COW copy propagate to the caller's
-        degrade/abort handling."""
-        ps = self.program.page_size
-        for pi in range(int(first_pos) // ps, int(last_pos) // ps + 1):
-            if not self._writable_page(seq, pi, False):
-                return False
-            # a window layer's table is a ring: _release_window has
-            # emptied the column of a page this position opens
-            if self._wcols and not self._writable_page(
-                    seq, pi % self._wcols, True):
-                return False
-        return True
-
-    def _writable_page(self, seq, col, window):
-        """One table column of ``seq``, of the full layers' table or
-        of the window layers' ring: fill it if it is empty, make it
-        private if it is shared (:meth:`_ensure_writable`)."""
-        table = seq.wtable if window else seq.table
-        pages = seq.wpages if window else seq.pages
-        allocator = self._wallocator if window else self._allocator
-        prefix = self._wprefix if window else self._prefix
-        page = int(table[col])
-        if page == TRASH_PAGE:
-            ids = self._alloc_pages(1, seq.slot, window)
-            if ids is None:
-                return False
-            table[col] = ids[0]
-            with self._lock:
-                pages.append(ids[0])
-            return True
-        with self._lock:
-            shared = allocator.refcount(page) > 1
-            if shared and prefix is not None \
-                    and allocator.refcount(page) == 2:
-                # only co-holder is the prefix registry: steal the
-                # registration back instead of copying — the
-                # write is private, no extra page burned (real
-                # sharers keep the full copy-on-write below)
-                if prefix.release_leaf(page):
-                    shared = allocator.refcount(page) > 1
-        if not shared:
-            return True
-        # copy-on-write: the first divergent write into a shared
-        # page lands in this sequence's private copy
-        ids = self._alloc_pages(1, seq.slot, window)
-        if ids is None:
-            return False
-        if window:
-            self._cache = self._device(
-                self.program.run_copy_page, self._cache, TRASH_PAGE,
-                TRASH_PAGE, wsrc=page, wdst=ids[0])
-        else:
-            self._cache = self._device(self.program.run_copy_page,
-                                       self._cache, page, ids[0])
-        with self._lock:
-            allocator.release(page)
-            pages.remove(page)
-            pages.append(ids[0])
-            self._counts['cow_copies'] += 1
-        table[col] = ids[0]
-        return True
-
-    def _release_window(self, active):
-        """A sequence whose next position opens a logical page its
-        window ring has not held yet gives back the page that column
-        held: by then it lies wholly behind the window (the ring is
-        ``ceil(window / page) + 1`` columns). The registry's own hold,
-        if the page was a shared prefix, keeps it for later hits."""
-        ps, cols = self.program.page_size, self._wcols
-        released = 0
-        for seq in active.values():
-            top = int(seq.pos) // ps
-            if top <= seq.wtop or seq.stream.done() \
-                    or seq.stream._cancelled:
-                continue
-            for page_no in range(seq.wtop + 1, top + 1):
-                col = page_no % cols
-                page = int(seq.wtable[col])
-                if page != TRASH_PAGE:
-                    with self._lock:
-                        self._wallocator.release(page)
-                        seq.wpages.remove(page)
-                    seq.wtable[col] = TRASH_PAGE
-                    released += 1
-            seq.wtop = top
-        if released:
-            with self._lock:
-                self._counts['window_pages_released'] += released
+    def _copy_page(self, src, dst):
+        """The page owner's copy-on-write, as a device call under the
+        breaker and the watchdog like every other."""
+        self._cache = self._device(self.program.run_copy_page,
+                                   self._cache, src, dst)
 
     # -- device calls under breaker + watchdog -----------------------------
 
@@ -1152,13 +983,11 @@ class DecodeEngine:
             return False
 
     def _prefill_extras(self, seq):
-        """Sampling/adapter kwargs for one ``run_prefill`` — {} when
-        the program compiled without the extras argument (the kwargs
-        would be ignored, but skip even building them)."""
-        if not self._extras_on:
-            return {}
+        """Sampling/adapter kwargs for one ``run_prefill``; what is
+        left out takes the program's neutral value (greedy, the base
+        adapter)."""
         kw = {}
-        if self.sample_args and seq.temperature > 0:
+        if seq.temperature > 0:
             # the prefill's emitted token is the logits row at
             # absolute position len(prompt) - 1
             kw['temps'] = onp.asarray([seq.temperature], 'float32')
@@ -1169,34 +998,35 @@ class DecodeEngine:
             kw['aidx'] = seq.adapter_idx
         return kw
 
-    def _step_extras(self, active, spec_c=0):
-        """Per-slot sampling/adapter arrays for one step call (or one
-        verify call: ``spec_c`` keys per slot at absolute positions
-        ``pos .. pos + spec_c - 1``, exactly the keys the plain path
-        would burn at those positions). {} when the program compiled
-        without the extras argument."""
-        if not self._extras_on:
+    def _sampling_extras(self, active, spec_c=0, off=0):
+        """Per-slot sampling arrays for one step call at absolute
+        positions ``pos + off`` (or one verify call: ``spec_c`` keys
+        per slot at ``pos .. pos + spec_c - 1``, exactly the keys the
+        plain path would burn at those positions). {} with no live
+        slot that samples: the program fills in the same neutral
+        values (greedy rows)."""
+        if not _samples(active):
             return {}
-        kw = {}
-        if self.sample_args:
-            temps = onp.zeros(self.slots, 'float32')
-            top_ps = onp.ones(self.slots, 'float32')
-            shape = (self.slots, spec_c, 2) if spec_c \
-                else (self.slots, 2)
-            keys = onp.zeros(shape, 'uint32')
-            for slot, seq in active.items():
-                if seq.temperature <= 0:
-                    continue
-                temps[slot] = seq.temperature
-                top_ps[slot] = seq.top_p
-                if spec_c:
-                    for c in range(spec_c):
-                        keys[slot, c] = key_for(seq.seed, seq.pos + c)
-                else:
-                    keys[slot] = key_for(seq.seed, seq.pos)
-            kw['temps'] = temps
-            kw['top_ps'] = top_ps
-            kw['keys'] = keys
+        temps = onp.zeros(self.slots, 'float32')
+        top_ps = onp.ones(self.slots, 'float32')
+        shape = (self.slots, spec_c, 2) if spec_c else (self.slots, 2)
+        keys = onp.zeros(shape, 'uint32')
+        for slot, seq in active.items():
+            if seq.temperature <= 0:
+                continue
+            temps[slot] = seq.temperature
+            top_ps[slot] = seq.top_p
+            if spec_c:
+                for c in range(spec_c):
+                    keys[slot, c] = key_for(seq.seed, seq.pos + c)
+            else:
+                keys[slot] = key_for(seq.seed, seq.pos + off)
+        return {'temps': temps, 'top_ps': top_ps, 'keys': keys}
+
+    def _step_extras(self, active, spec_c=0):
+        """Per-slot sampling/adapter arrays for one step or verify
+        call of the target program."""
+        kw = self._sampling_extras(active, spec_c)
         if self._adapters is not None:
             aidx = onp.zeros(self.slots, 'int32')
             for slot, seq in active.items():
@@ -1205,27 +1035,11 @@ class DecodeEngine:
             kw['aidx'] = aidx
         return kw
 
-    def _draft_step_extras(self, active, off):
-        """Coupled (shared-noise) draft proposals: the draft samples
-        its proposal for absolute position ``pos + off`` with the SAME
-        key the verify pass burns there, so under agreement the draft
-        proposes exactly the token the target would sample — the
-        greedy longest-prefix acceptance walk then preserves the
-        1 + k*r win for sampled traffic without biasing the output
-        (every emitted token is the target's own draw either way)."""
-        temps = onp.zeros(self.slots, 'float32')
-        top_ps = onp.ones(self.slots, 'float32')
-        keys = onp.zeros((self.slots, 2), 'uint32')
-        for slot, seq in active.items():
-            if seq.temperature <= 0:
-                continue
-            temps[slot] = seq.temperature
-            top_ps[slot] = seq.top_p
-            keys[slot] = key_for(seq.seed, seq.pos + off)
-        return {'temps': temps, 'top_ps': top_ps, 'keys': keys}
-
     def _admit(self, seq, slot):
-        """Prefill one pending request into ``slot`` (join)."""
+        """Join: prefill one pending request into ``slot``. The two
+        kinds of program (slot cache, paged) differ in the body only:
+        what a failed device call does to the request is written
+        here, once."""
         if seq.stream.done() or seq.stream._cancelled:
             if not seq.stream.done():
                 seq.stream._finish('cancelled')
@@ -1244,202 +1058,107 @@ class DecodeEngine:
             return
         try:
             if self._cache is None:
-                self._cache = self.program.new_cache()
-            self._cache, tok, _logits = self._device(
-                self.program.run_prefill, self._cache,
-                onp.asarray(seq.prompt, 'int32'), slot,
-                **self._prefill_extras(seq))
+                self._rebuild_cache()
+            emit = self._prefill_paged(seq, slot) if self.paged \
+                else self._prefill_slot(seq, slot)
         except _DegradedPath:
-            self._release_adapter(seq)
-            with self._lock:
-                self._free.append(slot)
+            self._give_back(seq, slot)
             self._spawn_fallback([seq])
-            return
         except _AbortPath as ab:
             # worker crash / preemption at prefill: fail THIS request
             # with the typed error (client retries), free the slot
-            self._release_adapter(seq)
-            with self._lock:
-                self._free.append(slot)
+            self._give_back(seq, slot)
             seq.stream._finish('error', ab.exc)
-            return
         except Exception as exc:
             # bug-shaped (non-transient) failure: fail THIS request
             # loudly with the typed error, but never leak its slot or
             # leave its stream blocking forever
-            self._release_adapter(seq)
-            with self._lock:
-                self._free.append(slot)
+            self._give_back(seq, slot)
             seq.stream._finish('error', exc)
-            logging.exception('decode %s: prefill failed with a '
-                              'non-transient error', self.name)
-            return
-        with _span('eng.tick.emit'):
-            self._emit_prefilled(seq, slot, tok)
+            logging.exception('decode %s: %s failed with a '
+                              'non-transient error', self.name,
+                              'paged prefill' if self.paged
+                              else 'prefill')
+        else:
+            if emit is not None:
+                with _span('eng.tick.emit'):
+                    emit()
 
-    def _admit_paged(self, seq, slot):
+    def _give_back(self, seq, slot):
+        """An admission that came to nothing: unpin the adapter row,
+        release the pages, free the slot."""
+        self._release_adapter(seq)
+        with self._lock:
+            if seq.pages is not None:
+                self._pages.drop(seq.pages)
+            self._free.append(slot)
+
+    def _prefill_slot(self, seq, slot):
+        """One bucketed prefill into ``slot`` of the slot cache.
+        Returns what emits its first token."""
+        self._cache, tok, _logits = self._device(
+            self.program.run_prefill, self._cache,
+            onp.asarray(seq.prompt, 'int32'), slot,
+            **self._prefill_extras(seq))
+        return functools.partial(self._emit_prefilled, seq, slot, tok)
+
+    def _prefill_paged(self, seq, slot):
         """Paged join: a prefix-cache hit references the shared pages
         and streams the remaining prompt through the decode step (no
         prefill program runs — the prefix was prefilled ONCE); a miss
-        allocates pages and runs one bucketed prefill into them."""
-        if seq.stream.done() or seq.stream._cancelled:
-            if not seq.stream.done():
-                seq.stream._finish('cancelled')
-            with self._lock:
-                self._free.append(slot)
-            return
-        tr = seq.trace
-        if tr is not None:
-            w0 = time.time()
-            # the eng.tick span (profiler's clock) that admitted it
-            tr['tick'] = self._counts['steps']
-            self._trace_span(seq, 'eng.queue_wait', tr['enq'], w0,
-                             tick=tr['tick'])
-            tr['last'] = w0
-        if not self._admit_adapter(seq, slot):
-            return
+        allocates pages and runs one bucketed prefill into them.
+        Returns what emits the first token, None where nothing is to
+        emit yet (a hit) or ever (pool exhausted: failed typed)."""
         prompt = seq.prompt
         n = len(prompt)
-        seq.table = onp.full(self.program.max_pages, TRASH_PAGE,
-                             'int32')
-        ps = self.program.page_size
-        if self._wcols:
-            seq.wtable = onp.full(self._wcols, TRASH_PAGE, 'int32')
-            seq.wtop = -1
-        shared, wshared, covered = [], [], 0
-        if self._prefix is not None:
-            # namespaced by adapter id: an adapter's KV rows for the
-            # same tokens differ from the base's — a warm hit must
-            # never splice across variants
-            with self._lock:
-                shared, covered = self._prefix.lookup(
-                    prompt, namespace=seq.adapter_id)
-                if self._wprefix is not None:
-                    # a hit reaches as far as BOTH kinds of layer
-                    # still hold the prefix (the window layers
-                    # register only prompts their ring holds whole,
-                    # and evict on their own)
-                    wshared, wcovered = self._wprefix.lookup(
-                        prompt, namespace=seq.adapter_id)
-                    covered = min(covered, wcovered)
-                    shared = shared[:pages_for(covered, ps)]
-                    wshared = wshared[:pages_for(covered, ps)]
-            # always leave >= 1 suffix token to step on: its logits
-            # are the first generated token
-            covered = min(covered, n - 1)
-        try:
-            if self._cache is None:
-                self._rebuild_cache()
-            if covered > 0:
-                with self._lock:
-                    for p in shared:
-                        self._allocator.ref(p)
-                    seq.pages = list(shared)
-                    for p in wshared:
-                        self._wallocator.ref(p)
-                    seq.wpages = list(wshared)
-                    self._counts['prefix_hits'] += 1
-                    self._counts['prefix_tokens_saved'] += covered
-                seq.table[:len(shared)] = shared
-                if self._wcols:
-                    # a registered prefix fits the ring: column = page
-                    seq.wtable[:len(wshared)] = wshared
-                    seq.wtop = len(wshared) - 1
-                seq.slot = slot
-                seq.pos = covered
-                seq.last_token = int(prompt[covered])
-                if self._draft is not None:
-                    # the draft has no prefix cache: prefill it whole
-                    # (cheap — that is what makes it a draft)
-                    self._draft_cache, _dt, _dl = self._device(
-                        self._draft.run_prefill, self._draft_cache,
-                        onp.asarray(prompt, 'int32'), slot)
-                inst = _serving_instruments()
-                if inst is not None:
-                    inst.prefix_hits.inc()
-                    inst.prefix_tokens_saved.inc(covered)
-                _record_event('prefix_hit', slot=slot, prompt_len=n,
-                              tokens_shared=covered,
-                              pages_shared=len(shared))
-                _record_event('decode_admit', slot=slot, prompt_len=n,
-                              prefix_tokens=covered)
-                with self._lock:
-                    self._active[slot] = seq
-                if seq.prefill_only:
-                    # hand off the extending state (pos=covered, no
-                    # token emitted yet): the importer streams the
-                    # un-shared suffix through ITS decode step
-                    self._export_at_boundary(seq, slot)
-                return
-            npages = pages_for(n, ps)
-            ids = self._alloc_pages(npages, slot)
-            wids, extras = None, self._prefill_extras(seq)
-            if ids is not None and self._wcols:
-                # the window layers keep the prompt's last pages only:
-                # what lies behind the ring is never written
-                behind = max(0, npages - self._wcols)
-                wids = self._alloc_pages(npages - behind, slot, True)
-            if ids is None or (self._wcols and wids is None):
-                with self._lock:
-                    seq.pages = list(ids or ())
-                self._release_seq_pages(seq)
-                self._fail_pool_exhausted(seq, slot, where='admit')
-                self._release_adapter(seq)
-                with self._lock:
-                    self._free.append(slot)
-                return
-            with self._lock:
-                seq.pages = list(ids)
-            seq.table[:len(ids)] = ids
-            if self._wcols:
-                with self._lock:
-                    seq.wpages = list(wids)
-                for j, page in enumerate(wids):
-                    seq.wtable[(behind + j) % self._wcols] = page
-                seq.wtop = npages - 1
-                extras['wpage_ids'] = [TRASH_PAGE] * behind + wids
-            self._cache, tok, _logits = self._device(
-                self.program.run_prefill, self._cache,
-                onp.asarray(prompt, 'int32'), ids, **extras)
+        seq.pages = self._pages.open(slot)
+        # namespaced by adapter id: an adapter's KV rows for the same
+        # tokens differ from the base's — a warm hit must never splice
+        # across variants
+        covered, shared = self._pages.share_prefix(
+            seq.pages, prompt, namespace=seq.adapter_id)
+        if covered > 0:
+            seq.slot = slot
+            seq.pos = covered
+            seq.last_token = int(prompt[covered])
             if self._draft is not None:
+                # the draft has no prefix cache: prefill it whole
+                # (cheap — that is what makes it a draft)
                 self._draft_cache, _dt, _dl = self._device(
                     self._draft.run_prefill, self._draft_cache,
                     onp.asarray(prompt, 'int32'), slot)
-            if self._prefix is not None:
-                with _span('eng.tick.prefix_register'), self._lock:
-                    self._prefix.register(prompt, ids,
-                                          namespace=seq.adapter_id)
-                    if self._wprefix is not None:
-                        # registers nothing where the prompt outran
-                        # the ring: its first page is the trash page
-                        self._wprefix.register(
-                            prompt, extras['wpage_ids'],
-                            namespace=seq.adapter_id)
-        except _DegradedPath:
-            self._release_adapter(seq)
-            self._release_seq_pages(seq)
+            inst = _serving_instruments()
+            if inst is not None:
+                inst.prefix_hits.inc()
+                inst.prefix_tokens_saved.inc(covered)
+            _record_event('prefix_hit', slot=slot, prompt_len=n,
+                          tokens_shared=covered, pages_shared=shared)
+            _record_event('decode_admit', slot=slot, prompt_len=n,
+                          prefix_tokens=covered)
             with self._lock:
-                self._free.append(slot)
-            self._spawn_fallback([seq])
-            return
-        except _AbortPath as ab:
-            self._release_adapter(seq)
-            self._release_seq_pages(seq)
-            with self._lock:
-                self._free.append(slot)
-            seq.stream._finish('error', ab.exc)
-            return
-        except Exception as exc:
-            self._release_adapter(seq)
-            self._release_seq_pages(seq)
-            with self._lock:
-                self._free.append(slot)
-            seq.stream._finish('error', exc)
-            logging.exception('decode %s: paged prefill failed with a '
-                              'non-transient error', self.name)
-            return
-        with _span('eng.tick.emit'):
-            self._emit_prefilled(seq, slot, tok, prefix_tokens=0)
+                self._active[slot] = seq
+            if seq.prefill_only:
+                # hand off the extending state (pos=covered, no
+                # token emitted yet): the importer streams the
+                # un-shared suffix through ITS decode step
+                self._export_at_boundary(seq, slot)
+            return None
+        ids = self._pages.place(seq.pages, n)
+        if ids is None:
+            self._fail_pool_exhausted(seq, slot, where='admit')
+            self._give_back(seq, slot)
+            return None
+        self._cache, tok, _logits = self._device(
+            self.program.run_prefill, self._cache,
+            onp.asarray(prompt, 'int32'), ids,
+            **self._prefill_extras(seq))
+        if self._draft is not None:
+            self._draft_cache, _dt, _dl = self._device(
+                self._draft.run_prefill, self._draft_cache,
+                onp.asarray(prompt, 'int32'), slot)
+        self._pages.register(prompt, ids, namespace=seq.adapter_id)
+        return functools.partial(self._emit_prefilled, seq, slot, tok,
+                                 prefix_tokens=0)
 
     def _emit_prefilled(self, seq, slot, tok, **event):
         """A prefill has landed in ``slot``: book it, make the sequence
@@ -1497,61 +1216,66 @@ class DecodeEngine:
     def _step(self):
         """Advance every live slot one token (the single fixed-shape
         decode program); paged engines dispatch the page-table step,
-        or the speculative draft+verify tick when eligible."""
+        or the speculative draft+verify tick when eligible. The three
+        differ in the body only: what a failed device call does to
+        the sequences in flight is written here, once."""
         with self._lock:
             active = dict(self._active)
         if not active:
             return
-        if self.paged:
-            spec_ok = (self._draft is not None and self.spec_k
-                       and all(not s.extending
-                               and s.pos + self.spec_k
-                               < self.program.max_len
-                               for s in active.values()))
-            if spec_ok:
-                self._spec_step(active)
-            else:
-                self._paged_step(active)
-            return
+        if not self.paged:
+            what, run = 'step', self._slot_step
+        elif (self._draft is not None and self.spec_k
+              and all(not s.extending
+                      and s.pos + self.spec_k < self.program.max_len
+                      for s in active.values())):
+            what, run = 'speculative step', self._spec_step
+        else:
+            what, run = 'paged step', self._paged_step
         try:
-            with _span('eng.tick.build_inputs'):
-                tokens = onp.zeros(self.slots, 'int32')
-                positions = onp.zeros(self.slots, 'int32')
-                for slot, seq in active.items():
-                    tokens[slot] = seq.last_token
-                    positions[slot] = seq.pos
-                extras = self._step_extras(active)
-            t0 = self._clock()
-            self._cache, toks, _logits = self._device(
-                self.program.run_step, self._cache, tokens, positions,
-                **extras)
+            emit = run(active)
         except _DegradedPath:
             self._degrade_inflight(active)
-            return
         except _AbortPath as ab:
             # worker crash / preemption mid-stream: every in-flight
             # sequence terminates with the typed error (an NDJSON
             # stream gets it as its final line), slots retire, and
             # the cache rebuilds for the engine's recovery
-            for slot, seq in active.items():
-                seq.stream._finish('error', ab.exc)
-                self._retire(slot, seq, 'aborted')
-            self._rebuild_cache()
-            return
+            self._fail_inflight(active, ab.exc, 'aborted')
         except Exception as exc:
             # bug-shaped failure: a deterministic error would recur
             # every tick — fail the in-flight streams with the typed
             # error, retire their slots, rebuild the (possibly
             # donated-away) cache, and keep the engine serviceable
-            logging.exception('decode %s: step failed with a '
-                              'non-transient error', self.name)
+            logging.exception('decode %s: %s failed with a '
+                              'non-transient error', self.name, what)
+            self._fail_inflight(active, exc, 'error')
+        else:
+            if emit is not None:
+                with _span('eng.tick.emit'):
+                    emit()
+
+    def _fail_inflight(self, active, exc, reason):
+        for slot, seq in active.items():
+            seq.stream._finish('error', exc)
+            self._retire(slot, seq, reason)
+        self._rebuild_cache()
+
+    def _slot_step(self, active):
+        """One decode step over the slot cache. Returns what emits
+        its tokens."""
+        with _span('eng.tick.build_inputs'):
+            tokens = onp.zeros(self.slots, 'int32')
+            positions = onp.zeros(self.slots, 'int32')
             for slot, seq in active.items():
-                seq.stream._finish('error', exc)
-                self._retire(slot, seq, 'error')
-            self._rebuild_cache()
-            return
-        with _span('eng.tick.emit'):
-            self._emit_step(active, toks, self._clock() - t0)
+                tokens[slot] = seq.last_token
+                positions[slot] = seq.pos
+            extras = self._step_extras(active)
+        t0 = self._clock()
+        self._cache, toks, _logits = self._device(
+            self.program.run_step, self._cache, tokens, positions,
+            **extras)
+        return lambda: self._emit_step(active, toks, self._clock() - t0)
 
     def _emit_step(self, active, toks, dt):
         """Book the step and hand each live slot its token."""
@@ -1612,14 +1336,15 @@ class DecodeEngine:
         allocation at boundary crossings + copy-on-write of shared
         pages. Pool exhaustion fails THAT stream typed and drops it
         from this tick; device errors propagate to the caller."""
-        if self._wcols:
-            with _span('eng.tick.release_window'):
-                self._release_window(active)
+        self._pages.advance(
+            (seq.pages, seq.pos) for seq in active.values()
+            if not (seq.stream.done() or seq.stream._cancelled))
         for slot, seq in list(active.items()):
             if seq.stream.done() or seq.stream._cancelled:
                 continue
-            if not self._ensure_writable(seq, seq.pos,
-                                         seq.pos + lookahead):
+            if not self._pages.make_writable(
+                    seq.pages, seq.pos, seq.pos + lookahead,
+                    self._copy_page):
                 self._fail_pool_exhausted(seq, slot, where='step')
                 self._retire(slot, seq, 'error')
                 del active[slot]
@@ -1629,59 +1354,36 @@ class DecodeEngine:
         """One decode step through the page tables. Extension slots
         (prefix hits still consuming their prompt suffix) feed prompt
         tokens and emit nothing until the last prompt token's logits
-        produce their first generated token."""
+        produce their first generated token. Returns what emits the
+        step's tokens, None where no sequence is left to step."""
         t0 = self._clock()
-        try:
-            with _span('eng.tick.page_faults'):
-                active = self._page_faults(active)
-            if not active:
-                return
-            with _span('eng.tick.build_inputs'):
-                tokens = onp.zeros(self.slots, 'int32')
-                positions = onp.zeros(self.slots, 'int32')
-                tables = onp.zeros((self.slots, self.program.max_pages),
-                                   'int32')
-                for slot, seq in active.items():
-                    tokens[slot] = seq.last_token
-                    positions[slot] = seq.pos
-                    tables[slot] = seq.table
-                extras = self._step_extras(active)
-                if self._wcols:
-                    wtables = onp.zeros((self.slots, self._wcols),
-                                        'int32')
-                    for slot, seq in active.items():
-                        wtables[slot] = seq.wtable
-                    extras['wtables'] = wtables
-            self._cache, toks, _logits = self._device(
-                self.program.run_step, self._cache, tokens, positions,
-                tables, **extras)
-            if self._draft is not None:
-                # keep the draft's KV history in lockstep on
-                # non-speculative ticks (extension / near-max_len):
-                # a hole at these positions would starve every later
-                # speculative round's proposals
-                self._draft_cache, _dt, _dl = self._device(
-                    self._draft.run_step, self._draft_cache, tokens,
-                    positions)
-        except _DegradedPath:
-            self._degrade_inflight(active)
-            return
-        except _AbortPath as ab:
+        with _span('eng.tick.page_faults'):
+            active = self._page_faults(active)
+        if not active:
+            return None
+        with _span('eng.tick.build_inputs'):
+            tokens = onp.zeros(self.slots, 'int32')
+            positions = onp.zeros(self.slots, 'int32')
             for slot, seq in active.items():
-                seq.stream._finish('error', ab.exc)
-                self._retire(slot, seq, 'aborted')
-            self._rebuild_cache()
-            return
-        except Exception as exc:
-            logging.exception('decode %s: paged step failed with a '
-                              'non-transient error', self.name)
-            for slot, seq in active.items():
-                seq.stream._finish('error', exc)
-                self._retire(slot, seq, 'error')
-            self._rebuild_cache()
-            return
-        with _span('eng.tick.emit'):
-            self._emit_paged_step(active, toks, self._clock() - t0)
+                tokens[slot] = seq.last_token
+                positions[slot] = seq.pos
+            tables = self._pages.tables(
+                self.slots, ((slot, seq.pages)
+                             for slot, seq in active.items()))
+            extras = self._step_extras(active)
+        self._cache, toks, _logits = self._device(
+            self.program.run_step, self._cache, tokens, positions,
+            tables, **extras)
+        if self._draft is not None:
+            # keep the draft's KV history in lockstep on
+            # non-speculative ticks (extension / near-max_len):
+            # a hole at these positions would starve every later
+            # speculative round's proposals
+            self._draft_cache, _dt, _dl = self._device(
+                self._draft.run_step, self._draft_cache, tokens,
+                positions)
+        return lambda: self._emit_paged_step(active, toks,
+                                             self._clock() - t0)
 
     def _emit_paged_step(self, active, toks, dt):
         """Advance positions, stream each slot's token, book the step."""
@@ -1736,72 +1438,55 @@ class DecodeEngine:
         greedy-matching prefix is accepted plus the target's own
         correction token — 1..k+1 tokens per sequence per tick for
         one target pass. Rejected K/V rows need no rollback: they sit
-        masked behind each slot's position until overwritten."""
+        masked behind each slot's position until overwritten. Returns
+        what emits the round's tokens, None where no sequence is left
+        to step."""
         k = self.spec_k
         C = k + 1
         t0 = self._clock()
-        try:
-            with _span('eng.tick.page_faults'):
-                active = self._page_faults(active, lookahead=k)
-            if not active:
-                return
-            with _span('eng.tick.build_inputs'):
-                inputs = onp.zeros((self.slots, C), 'int32')
-                positions = onp.zeros(self.slots, 'int32')
-                tables = onp.zeros((self.slots, self.program.max_pages),
-                                   'int32')
-                for slot, seq in active.items():
-                    inputs[slot, 0] = seq.last_token
-                    positions[slot] = seq.pos
-                    tables[slot] = seq.table
-            # coupled proposals only when BOTH programs compiled with
-            # sampling args — a greedy draft under sampled verify
-            # stays correct (every emitted token is a target draw),
-            # it just accepts less
-            couple = (self.sample_args
-                      and getattr(self._draft, 'sample_args', False))
-            cur = inputs[:, 0].copy()
-            for c in range(1, C):
-                dkw = self._draft_step_extras(active, c - 1) \
-                    if couple else {}
-                self._draft_cache, dtoks, _dl = self._device(
-                    self._draft.run_step, self._draft_cache, cur,
-                    positions + (c - 1), **dkw)
-                cur = onp.asarray(dtoks, 'int32').copy()
-                inputs[:, c] = cur
-            # feed the LAST proposal too (its output is discarded):
-            # a fully-accepted round advances pos past pos+k, so this
-            # is the only chance to write that draft KV row — skipping
-            # it leaves a permanent zero-row hole every later proposal
-            # attends (for shorter acceptances the row is masked and
-            # overwritten later, harmless)
-            self._draft_cache, _dt, _dl = self._device(
+        with _span('eng.tick.page_faults'):
+            active = self._page_faults(active, lookahead=k)
+        if not active:
+            return None
+        with _span('eng.tick.build_inputs'):
+            inputs = onp.zeros((self.slots, C), 'int32')
+            positions = onp.zeros(self.slots, 'int32')
+            for slot, seq in active.items():
+                inputs[slot, 0] = seq.last_token
+                positions[slot] = seq.pos
+            tables = self._pages.tables(
+                self.slots, ((slot, seq.pages)
+                             for slot, seq in active.items()))
+        cur = inputs[:, 0].copy()
+        for c in range(1, C):
+            # coupled (shared-noise) proposals: the draft samples its
+            # proposal for absolute position pos + c - 1 with the SAME
+            # key the verify pass burns there, so under agreement it
+            # proposes exactly the token the target would sample — the
+            # greedy longest-prefix acceptance walk then preserves the
+            # 1 + k*r win for sampled traffic without biasing the
+            # output (every emitted token is the target's own draw)
+            self._draft_cache, dtoks, _dl = self._device(
                 self._draft.run_step, self._draft_cache, cur,
-                positions + k)
-            self._cache, vtoks, _logits = self._device(
-                self.program.run_verify, self._cache, inputs,
-                positions, tables,
-                **self._step_extras(active, spec_c=C))
-        except _DegradedPath:
-            self._degrade_inflight(active)
-            return
-        except _AbortPath as ab:
-            for slot, seq in active.items():
-                seq.stream._finish('error', ab.exc)
-                self._retire(slot, seq, 'aborted')
-            self._rebuild_cache()
-            return
-        except Exception as exc:
-            logging.exception('decode %s: speculative step failed '
-                              'with a non-transient error', self.name)
-            for slot, seq in active.items():
-                seq.stream._finish('error', exc)
-                self._retire(slot, seq, 'error')
-            self._rebuild_cache()
-            return
-        with _span('eng.tick.emit'):
-            self._emit_spec_step(active, inputs, vtoks,
-                                 self._clock() - t0)
+                positions + (c - 1),
+                **self._sampling_extras(active, off=c - 1))
+            cur = onp.asarray(dtoks, 'int32').copy()
+            inputs[:, c] = cur
+        # feed the LAST proposal too (its output is discarded):
+        # a fully-accepted round advances pos past pos+k, so this
+        # is the only chance to write that draft KV row — skipping
+        # it leaves a permanent zero-row hole every later proposal
+        # attends (for shorter acceptances the row is masked and
+        # overwritten later, harmless)
+        self._draft_cache, _dt, _dl = self._device(
+            self._draft.run_step, self._draft_cache, cur,
+            positions + k)
+        self._cache, vtoks, _logits = self._device(
+            self.program.run_verify, self._cache, inputs,
+            positions, tables,
+            **self._step_extras(active, spec_c=C))
+        return lambda: self._emit_spec_step(active, inputs, vtoks,
+                                            self._clock() - t0)
 
     def _emit_spec_step(self, active, inputs, vtoks, dt):
         """Walk each slot's verified chunk, stream what was accepted,
@@ -2014,8 +1699,8 @@ class DecodeEngine:
         if self.paged:
             ps = self.program.page_size
             npages = pages_for(seq.pos, ps)
-            ids = [int(seq.table[i]) for i in range(npages)]
-            entries = self.program.export_pages(self._cache, ids)
+            entries = self.program.export_pages(
+                self._cache, self._pages.first_pages(seq.pages, seq.pos))
             entries = {k: v[:seq.pos] for k, v in entries.items()}
             payload = build_payload(
                 'paged', seq.prompt, list(stream.tokens), seq.pos,
@@ -2070,21 +1755,16 @@ class DecodeEngine:
         incompatible payloads, :class:`BackpressureError` when no
         slot/pages are available, :class:`BatcherClosed` after
         :meth:`close`."""
-        if self._wcols:
+        if self.paged:
             self.program._no_window('import_sequence')
         state = decode_payload(payload)
         state['trace'] = trace
-        # a pinned adapter / sampled stream must land in an engine
-        # that can CONTINUE it exactly — never silently under the base
-        # weights or greedy argmax
+        # a pinned adapter must land in an engine that can CONTINUE
+        # it exactly — never silently under the base weights
         if state['adapter_id'] is not None and self._adapters is None:
             raise SeqStateError(
                 'payload pins adapter %r but this engine serves no '
                 'adapter pool' % (state['adapter_id'],))
-        if state['sampling'] is not None and not self.sample_args:
-            raise SeqStateError(
-                'payload carries sampling state but this engine '
-                'compiled without sampling args')
         if state['kind'] == 'cold':
             # never prefilled at the source: ordinary admission
             samp = state['sampling'] or {}
@@ -2126,7 +1806,7 @@ class DecodeEngine:
                 raise BackpressureError(len(self._pending),
                                         self.max_queue)
             slot = self._free.pop(0)
-        ids = []
+        rec = None
         npages = 0
         aidx = 0
         try:
@@ -2142,16 +1822,13 @@ class DecodeEngine:
                         'cannot re-pin adapter %r at import: %s'
                         % (state['adapter_id'], exc))
             if self._cache is None:
-                if self.paged:
-                    self._rebuild_cache()
-                else:
-                    self._cache = self.program.new_cache()
+                self._rebuild_cache()
             if self.paged:
                 ps = self.program.page_size
                 npages = pages_for(pos, ps)
-                ids = self._alloc_pages(npages, slot)
+                rec = self._pages.open(slot)
+                ids = self._pages.place(rec, pos)
                 if ids is None:
-                    ids = []
                     with self._lock:
                         self._counts['pool_exhausted'] += 1
                         depth = len(self._pending)
@@ -2182,9 +1859,8 @@ class DecodeEngine:
                         % (exc,))
         except BaseException:
             with self._lock:
-                if self._allocator is not None:
-                    for p in ids:
-                        self._allocator.release(p)
+                if rec is not None:
+                    self._pages.drop(rec)
                 self._free.append(slot)
             if aidx:
                 self._adapters.release(aidx)
@@ -2210,17 +1886,13 @@ class DecodeEngine:
         if emitted:
             seq.first_token_at = now
         if self.paged:
-            seq.table = onp.full(self.program.max_pages, TRASH_PAGE,
-                                 'int32')
-            seq.table[:npages] = ids
-            seq.pages = list(ids)
-            if self._prefix is not None and pos >= len(prompt):
+            seq.pages = rec
+            if pos >= len(prompt):
                 # re-register the prompt so future shared-prefix
                 # admissions hit (one ref per newly registered page,
                 # exactly the admit-path contract)
-                with self._lock:
-                    self._prefix.register(prompt, ids,
-                                          namespace=seq.adapter_id)
+                self._pages.register(prompt, ids,
+                                     namespace=seq.adapter_id)
             if self._draft is not None:
                 # re-sync the draft from the fed context; a failure
                 # only lowers speculative acceptance (greedy verify
@@ -2377,14 +2049,12 @@ class DecodeEngine:
         per_seq = getattr(prog, 'per_sequence_bytes', None)
         if callable(per_seq):
             out['per_sequence_bytes_max'] = int(per_seq())
-        if self.paged and self._allocator is not None:
+        if self.paged:
             with self._lock:
-                pool = self._allocator.stats()
+                pool = self._pages.pool_stats()
                 live = len(self._active)
-                live_pages = sum(len(s.pages)
-                                 for s in self._active.values())
-                live_wpages = sum(len(s.wpages)
-                                  for s in self._active.values())
+                held = self._pages.held_bytes(
+                    s.pages for s in self._active.values())
             out['pool'] = pool
             page_bytes = getattr(prog, 'page_bytes', None)
             if callable(page_bytes):
@@ -2393,12 +2063,7 @@ class DecodeEngine:
                 # amortized: what the CURRENT live population actually
                 # holds, per sequence (falls back to one page when
                 # idle — the floor a new sequence costs)
-                amort = (live_pages * pb // live) if live else pb
-                if self._wcols and live:
-                    # a page of the window layers and a page of the
-                    # full layers hold different bytes
-                    amort = pool_bytes(prog._pspec, live_pages,
-                                       live_wpages) // live
+                amort = held // live if live else pb
                 out['per_sequence_bytes_amortized'] = int(amort)
                 if amort:
                     out['max_concurrent_sequences_per_gb'] = \
@@ -2426,21 +2091,9 @@ class DecodeEngine:
                 'closed': self._closed,
                 'paged': self.paged,
             }
-            if self._allocator is not None:
-                out['pages'] = self._allocator.stats()
-                if self._prefix is not None:
-                    out['pages']['prefix_entries'] = len(self._prefix)
-            if self._wallocator is not None:
-                out['pages_window'] = self._wallocator.stats()
-                if self._wprefix is not None:
-                    out['pages_window']['prefix_entries'] = \
-                        len(self._wprefix)
-                # pages in use by kind of layer, sequences' holds and
-                # the prefix registry's alike (gauges, not sums)
-                out['counts']['pages_live.full'] = \
-                    self._allocator.used_pages
-                out['counts']['pages_live.window'] = \
-                    self._wallocator.used_pages
+            if self._pages is not None:
+                out.update(self._pages.stats())
+                out['counts'].update(self._pages.live_gauges())
             if self._draft is not None:
                 proposed = self._counts['spec_proposed']
                 out['spec'] = {
@@ -2492,10 +2145,8 @@ class DecodeEngine:
                     leftovers.append(seq)
                     del self._active[slot]
                     self._free.append(slot)
-                    if self._allocator is not None and seq.pages:
-                        for p in seq.pages:
-                            self._allocator.release(p)
-                        seq.pages = []
+                    if seq.pages is not None:
+                        self._pages.drop(seq.pages)
                 self._counts['drain_timeouts'] += len(leftovers)
             # migration requests the worker will never service now
             orphans = list(self._migrations)

@@ -18,10 +18,12 @@ the reservation into fixed-size **pages** of ``page_size`` rows:
     copy. Both promise their indices in bounds: the contract on
     tables is in :func:`gather_pages`;
   * **allocation, freeing, refcounting, prefix sharing, and
-    copy-on-write decisions all happen host-side** in the engine
-    scheduler (:class:`PageAllocator`, :class:`PrefixCache`). The
-    compiled program never sees the free list — page churn costs zero
-    retraces.
+    copy-on-write decisions all happen host-side**, in one place:
+    :class:`PageOwner` holds, for every kind of layer the spec has, a
+    :class:`PageAllocator`, a :class:`PrefixCache` and every live
+    sequence's tables and holds; the engine's scheduler asks it and
+    knows no kind. The compiled program never sees the free list —
+    page churn costs zero retraces.
 
 Page 0 is the reserved **trash page**: unused table entries point at
 it, and padded prefill writes land in it harmlessly. Reads of trash
@@ -52,8 +54,10 @@ import heapq
 
 import numpy as onp
 
-__all__ = ['PagedCacheSpec', 'PageAllocator', 'PrefixCache',
-           'TRASH_PAGE', 'init_pool', 'pool_avals', 'pool_bytes',
+from ...observability.spans import span as _span
+
+__all__ = ['PagedCacheSpec', 'PageAllocator', 'PrefixCache', 'PageOwner',
+           'SeqPages', 'TRASH_PAGE', 'init_pool', 'pool_avals', 'pool_bytes',
            'gather_pages', 'write_prefill_pages', 'copy_page', 'pages_for',
            'scatter_rows', 'scatter_pages', 'ring_key_positions',
            'window_table_pages']
@@ -120,6 +124,16 @@ class PagedCacheSpec:
 
     def items(self):
         return self.entries.items()
+
+    def kinds(self):
+        """The kinds of layer this cache has, as ``(name, table
+        columns, ring)``: ``full`` first, whose table has a column for
+        every page of ``max_len``; ``window`` where there are window
+        entries, a ring of ``window_pages`` columns."""
+        out = [('full', self.max_pages, False)]
+        if self.window_entries:
+            out.append(('window', self.window_pages, True))
+        return out
 
     def pages_of(self, name, pages, window_pool):
         """Pool size of one entry: ``window_pool`` for a window
@@ -206,12 +220,12 @@ def gather_pages(pool_arr, tables):
     The contract on tables, for every pool operation of a compiled
     program (this gather, :func:`scatter_rows`, :func:`scatter_pages`):
     an entry names a page of the pool, and an unused entry holds
-    ``TRASH_PAGE``. The engine makes it true in two places: a
+    ``TRASH_PAGE``. :class:`PageOwner` makes it true in two places: a
     sequence's table starts ``TRASH_PAGE``-filled and afterwards takes
-    only pages its ``PageAllocator`` handed out
-    (``engine._admit_paged``), and the step's tables start from zeros,
-    which is the trash page, and copy live sequences' tables in
-    (``engine._paged_step``, ``_spec_step``). So the indices are
+    only pages its kind's ``PageAllocator`` handed out
+    (:meth:`PageOwner.open` and what follows it), and the step's
+    tables start from zeros, which is the trash page, and copy live
+    sequences' tables in (:meth:`PageOwner.tables`). So the indices are
     promised in bounds: nothing compares them with the pool's size,
     and nothing lays a fill for out-of-range pages over the view
     (``jnp.take``'s default did: a select over the whole view, 14.6 ms
@@ -582,3 +596,376 @@ class PrefixCache:
                 freed.append(node.page)
             self.evictions += 1
         return freed
+
+
+# ---------------------------------------------------------------------------
+# one owner of pages (host side), keyed by kind of layer
+# ---------------------------------------------------------------------------
+
+
+class _Kind:
+    """What one kind of layer has on the host: its pools' allocator,
+    its prefix registry (None: the owner was built without one) and
+    its table geometry."""
+
+    __slots__ = ('name', 'columns', 'ring', 'allocator', 'prefix')
+
+    def __init__(self, name, columns, ring, allocator, prefix):
+        self.name = name
+        self.columns = int(columns)
+        # a ring keeps logical page p in column p % columns and gives
+        # back the page that falls behind; a table that is no ring has
+        # a column for every page a sequence can reach
+        self.ring = bool(ring)
+        self.allocator = allocator
+        self.prefix = prefix
+
+
+class SeqPages:
+    """One live sequence's pages, by kind of layer: its table
+    (``TRASH_PAGE`` where it holds nothing), the pages it holds a
+    reference on and, for a ring, the highest logical page it has
+    opened. Made by :meth:`PageOwner.open`; only the owner writes it."""
+
+    __slots__ = ('slot', 'tables', 'held', 'top')
+
+    def __init__(self, slot, kinds):
+        self.slot = slot
+        self.tables = {k.name: onp.full(k.columns, TRASH_PAGE, 'int32')
+                       for k in kinds}
+        self.held = {k.name: [] for k in kinds}
+        self.top = {k.name: -1 for k in kinds if k.ring}
+
+
+class PageOwner:
+    """Who holds which page when: the one host-side owner of a paged
+    cache's pages, for every kind of layer ``spec`` has.
+
+    The scheduler (engine.py) keeps one of these and one
+    :class:`SeqPages` a sequence, and knows no kind of layer: every
+    method loops over the kinds. A third kind is an entry in
+    :meth:`PagedCacheSpec.kinds` and its geometry here.
+
+    Page ids and tables go out in the form the compiled programs take
+    them (``PagedDecodeProgram.run_prefill`` / ``run_step`` /
+    ``run_verify`` / ``run_copy_page``): bare where the cache has one
+    kind of layer, ``{kind: ...}`` where it has more.
+
+    ``lock`` is the scheduler's: every change of an allocator, a
+    registry or a record's holds is made under it, so that a reader of
+    the figures (:meth:`stats`, :meth:`pool_stats`, under the same
+    lock) never sees a pool half reset. ``counts`` is the scheduler's
+    counter dict: ``prefix_hits``, ``prefix_tokens_saved``,
+    ``page_evictions``, ``cow_copies`` and, with a ring,
+    ``window_pages_released`` are booked here and nowhere else.
+    ``event(kind, **fields)`` takes the flight recorder's
+    ``page_alloc`` and ``page_evict``. Worker thread only, but for the
+    readers and :meth:`drop`."""
+
+    def __init__(self, spec, pool_pages, lock, prefix_cache, counts,
+                 event=None):
+        self.page_size = spec.page_size
+        self._spec = spec
+        self._lock = lock
+        self._counts = counts
+        self._event = event or (lambda kind, **fields: None)
+        self._kinds = []
+        for name, columns, ring in spec.kinds():
+            allocator = PageAllocator(pool_pages[name])
+            self._kinds.append(_Kind(
+                name, columns, ring, allocator,
+                PrefixCache(spec.page_size, allocator)
+                if prefix_cache else None))
+        self._rings = [k for k in self._kinds if k.ring]
+        self._registers = bool(prefix_cache)
+        for name in ('prefix_hits', 'prefix_tokens_saved',
+                     'page_evictions', 'cow_copies'):
+            counts.setdefault(name, 0)
+        if self._rings:
+            counts.setdefault('window_pages_released', 0)
+
+    def _out(self, by_kind):
+        """``by_kind`` as the compiled programs take it."""
+        if len(self._kinds) == 1:
+            return by_kind[self._kinds[0].name]
+        return by_kind
+
+    # -- a sequence's life -------------------------------------------------
+
+    def open(self, slot):
+        """The record of a sequence that joins ``slot``: tables full of
+        the trash page, nothing held."""
+        return SeqPages(slot, self._kinds)
+
+    def share_prefix(self, rec, prompt, namespace=None):
+        """Look ``prompt`` up in every kind's registry under
+        ``namespace`` (the scheduler passes the adapter id: an
+        adapter's K/V rows for the same tokens differ from the base's)
+        and take what is found into ``rec``. A hit reaches as far as
+        EVERY kind still holds the prefix (a ring registers only
+        prompts it holds whole, and each registry evicts on its own)
+        and always leaves one token to step on: its logits are the
+        first generated token. Returns ``(tokens covered, pages
+        shared)``, ``(0, 0)`` on a miss."""
+        if not self._registers:
+            return 0, 0
+        ps = self.page_size
+        with self._lock:
+            hits, covered = {}, len(prompt)
+            for k in self._kinds:
+                hits[k.name], found = k.prefix.lookup(
+                    prompt, namespace=namespace)
+                covered = min(covered, found)
+            npages = pages_for(covered, ps)
+            covered = min(covered, len(prompt) - 1)
+            if covered <= 0:
+                return 0, 0
+            for k in self._kinds:
+                ids = hits[k.name][:npages]
+                for page in ids:
+                    k.allocator.ref(page)
+                rec.held[k.name] = list(ids)
+                # a registered prefix fits a ring: column = page
+                rec.tables[k.name][:len(ids)] = ids
+                if k.ring:
+                    rec.top[k.name] = len(ids) - 1
+            self._counts['prefix_hits'] += 1
+            self._counts['prefix_tokens_saved'] += covered
+        return covered, npages
+
+    def place(self, rec, n_tokens):
+        """Pages for positions ``[0, n_tokens)`` of ``rec`` in every
+        kind's pools: a prefill's landing, or an imported sequence's. A
+        ring keeps the last ``columns`` pages only: what lies behind
+        it is never written. Registered prefixes are evicted, least
+        recently used first, under pool pressure. Returns the page ids
+        of each logical page (the trash page for those behind a ring),
+        or None on exhaustion with nothing left held."""
+        npages = pages_for(n_tokens, self.page_size)
+        out = {}
+        for k in self._kinds:
+            behind = max(0, npages - k.columns) if k.ring else 0
+            ids = self._alloc(k, npages - behind, rec.slot)
+            if ids is None:
+                with self._lock:
+                    self.drop(rec)
+                return None
+            with self._lock:
+                rec.held[k.name] = list(ids)
+            table = rec.tables[k.name]
+            if k.ring:
+                for j, page in enumerate(ids):
+                    table[(behind + j) % k.columns] = page
+                rec.top[k.name] = npages - 1
+            else:
+                table[:npages] = ids
+            out[k.name] = [TRASH_PAGE] * behind + ids
+        return self._out(out)
+
+    def register(self, prompt, ids, namespace=None):
+        """Record a prompt's pages, ``ids`` as :meth:`place` returned
+        them, for later sharers. A ring the prompt outran registers
+        nothing: its first page is the trash page."""
+        if not self._registers:
+            return
+        if len(self._kinds) == 1:
+            ids = {self._kinds[0].name: ids}
+        with _span('eng.tick.prefix_register'), self._lock:
+            for k in self._kinds:
+                k.prefix.register(prompt, ids[k.name],
+                                  namespace=namespace)
+
+    def make_writable(self, rec, first_pos, last_pos, copy):
+        """Make every page this tick writes, positions
+        ``first_pos..last_pos`` of ``rec``, privately writable in every
+        kind: allocate at a page boundary, take a registration back
+        whose only other holder is the registry, else copy on write.
+        ``copy(src, dst)`` runs the device's page copy (ids as the
+        program takes them: the trash page onto itself for the kinds
+        that do not copy); what it raises goes to the caller. False on
+        pool exhaustion (after eviction)."""
+        ps = self.page_size
+        for page_no in range(int(first_pos) // ps,
+                             int(last_pos) // ps + 1):
+            for k in self._kinds:
+                # :meth:`advance` has emptied the column of a page that
+                # this position opens in a ring
+                col = page_no % k.columns if k.ring else page_no
+                if not self._writable(rec, k, col, copy):
+                    return False
+        return True
+
+    def _writable(self, rec, k, col, copy):
+        table, held = rec.tables[k.name], rec.held[k.name]
+        page = int(table[col])
+        if page == TRASH_PAGE:
+            ids = self._alloc(k, 1, rec.slot)
+            if ids is None:
+                return False
+            table[col] = ids[0]
+            with self._lock:
+                held.append(ids[0])
+            return True
+        with self._lock:
+            shared = k.allocator.refcount(page) > 1
+            if shared and k.prefix is not None \
+                    and k.allocator.refcount(page) == 2:
+                # the only co-holder is the prefix registry: steal the
+                # registration back instead of copying — the write is
+                # private, no extra page burned (real sharers keep the
+                # full copy-on-write below)
+                if k.prefix.release_leaf(page):
+                    shared = k.allocator.refcount(page) > 1
+        if not shared:
+            return True
+        # copy-on-write: the first divergent write into a shared page
+        # lands in this sequence's private copy
+        ids = self._alloc(k, 1, rec.slot)
+        if ids is None:
+            return False
+        copy(self._out({o.name: page if o is k else TRASH_PAGE
+                        for o in self._kinds}),
+             self._out({o.name: ids[0] if o is k else TRASH_PAGE
+                        for o in self._kinds}))
+        with self._lock:
+            k.allocator.release(page)
+            held.remove(page)
+            held.append(ids[0])
+            self._counts['cow_copies'] += 1
+        table[col] = ids[0]
+        return True
+
+    def advance(self, live):
+        """Before the step writes: ``live`` is ``(record, next
+        position)`` of every sequence that steps. A sequence whose
+        position opens a logical page its ring has not held yet gives
+        back the page that column held: by then it lies wholly behind
+        the window (the ring is ``ceil(window / page) + 1`` columns).
+        The registry's own hold, if the page was a shared prefix, keeps
+        it for later hits."""
+        if not self._rings:
+            return
+        ps = self.page_size
+        with _span('eng.tick.release_window'):
+            released = 0
+            for rec, pos in live:
+                top = int(pos) // ps
+                for k in self._rings:
+                    if top <= rec.top[k.name]:
+                        continue
+                    table, held = rec.tables[k.name], rec.held[k.name]
+                    for page_no in range(rec.top[k.name] + 1, top + 1):
+                        col = page_no % k.columns
+                        page = int(table[col])
+                        if page != TRASH_PAGE:
+                            with self._lock:
+                                k.allocator.release(page)
+                                held.remove(page)
+                            table[col] = TRASH_PAGE
+                            released += 1
+                    rec.top[k.name] = top
+            if released:
+                with self._lock:
+                    self._counts['window_pages_released'] += released
+
+    def drop(self, rec):
+        """Give back every hold of ``rec`` (caller holds the lock).
+        Pages whose registration still holds a reference stay for
+        later hits, until eviction."""
+        for k in self._kinds:
+            held = rec.held[k.name]
+            for page in held:
+                k.allocator.release(page)
+            rec.held[k.name] = []
+
+    def reset(self):
+        """The device pools were rebuilt: free lists, reference counts
+        and registrations of every kind describe garbage now. Callers
+        retire (and :meth:`drop`) the sequences in flight first."""
+        with self._lock:
+            for k in self._kinds:
+                k.allocator.reset()
+                if k.prefix is not None:
+                    k.prefix.clear()
+
+    def _alloc(self, k, n, slot):
+        """``n`` fresh pages of kind ``k``, evicting its least recently
+        used registered prefixes under pool pressure; None on
+        exhaustion (the caller fails typed)."""
+        with self._lock:
+            ids = k.allocator.alloc(n)
+            evicted = []
+            if ids is None and k.prefix is not None:
+                evicted = k.prefix.evict_lru(n)
+                ids = k.allocator.alloc(n)
+            if evicted:
+                self._counts['page_evictions'] += len(evicted)
+        for page in evicted:
+            self._event('page_evict', page=page, slot=slot)
+        if ids is not None and slot is not None:
+            self._event('page_alloc', pages=len(ids), slot=slot)
+        return ids
+
+    # -- what the programs are handed --------------------------------------
+
+    def tables(self, slots, placed):
+        """The tables of one step: ``placed`` is ``(slot, record)`` of
+        every sequence that steps; rows of the other slots hold the
+        trash page. Built anew every tick, from zeros: keeping one
+        table across ticks and sending the device only the rows a page
+        fault changed belongs here (this owner sees every change of a
+        row), and is a measured change of its own (ROADMAP S1)."""
+        out = {k.name: onp.zeros((slots, k.columns), 'int32')
+               for k in self._kinds}
+        for slot, rec in placed:
+            for name, table in out.items():
+                table[slot] = rec.tables[name]
+        return self._out(out)
+
+    def first_pages(self, rec, n_tokens):
+        """The pages that hold positions ``[0, n_tokens)`` of ``rec``
+        (a live migration's export; a ring has given its first pages
+        back, and ``export_pages`` refuses a cache that has one before
+        it reads these)."""
+        npages = pages_for(n_tokens, self.page_size)
+        return self._out({k.name: [int(p) for p in
+                                   rec.tables[k.name][:npages]]
+                          for k in self._kinds})
+
+    # -- figures (caller holds the lock) -----------------------------------
+
+    def pool_stats(self):
+        """The full layers' pool: what the page gauges, the cache
+        accounting's ``pool`` and a ``serve_reject`` report."""
+        return self._kinds[0].allocator.stats()
+
+    def stats(self):
+        """``stats()``'s blocks: ``pages`` for the full layers' pool,
+        ``pages_<kind>`` for every other kind's."""
+        out = {}
+        for k in self._kinds:
+            block = k.allocator.stats()
+            if k.prefix is not None:
+                block['prefix_entries'] = len(k.prefix)
+            out['pages' if k is self._kinds[0]
+                else 'pages_%s' % k.name] = block
+        return out
+
+    def live_gauges(self):
+        """Pages in use by kind of layer, sequences' holds and the
+        registry's alike (gauges, not sums); nothing where there is
+        one kind."""
+        if len(self._kinds) == 1:
+            return {}
+        return {'pages_live.%s' % k.name: k.allocator.used_pages
+                for k in self._kinds}
+
+    def held_bytes(self, recs):
+        """Device bytes behind the holds of ``recs``: a page of the
+        window layers and a page of the full layers hold different
+        bytes."""
+        held = dict.fromkeys((k.name for k in self._kinds), 0)
+        for rec in recs:
+            for name, pages in rec.held.items():
+                held[name] += len(pages)
+        return pool_bytes(self._spec, held['full'], held.get('window', 0))

@@ -94,27 +94,23 @@ def _instrument_compile(key, seconds):
 class DecodeProgram:
     """AOT prefill/step programs + slot cache for one decode model.
 
-    ``sample_args`` (default on, ``MXNET_TPU_SERVE_SAMPLE_ARGS``)
-    compiles temperature/top-p/PRNG-key sampling into every token-
-    emitting program as fixed-shape array arguments (an ``extras``
-    dict pytree appended to the signature); ``temps == 0`` rows take
-    the greedy branch byte-for-byte, so the default token streams are
-    unchanged. ``logit_mask`` additionally compiles a per-slot
-    additive ``(slots, vocab)`` grammar/JSON mask argument at the
-    same point (``MXNET_TPU_SERVE_SAMPLE_MASK``; off by default — it
-    is vocab-sized per-step traffic). ``adapter_spec`` (an
-    :class:`~..adapters.AdapterSpec`) sizes a low-rank adapter pool
+    Every token-emitting program has one signature: its last operand
+    is ``extras``, a dict pytree of fixed-shape arrays. Temperature,
+    top-p and a PRNG key a row are always in it (``temps == 0`` rows
+    are greedy, byte for byte the argmax). ``logit_mask`` additionally
+    compiles a per-slot additive ``(slots, vocab)`` grammar/JSON mask
+    argument at the same point (``MXNET_TPU_SERVE_SAMPLE_MASK``; off
+    by default — it is vocab-sized per-step traffic). ``adapter_spec``
+    (an :class:`~..adapters.AdapterSpec`) sizes a low-rank adapter pool
     argument plus per-slot int32 indices so one program serves every
     resident fine-tune — switching adapters is an array-value change,
-    never a retrace. All three are recorded in the manifest; loading
-    an artifact reconstructs the exact signature it was compiled
-    with, so pre-sampling artifacts keep deserializing their
-    executables.
+    never a retrace. Both are recorded in the manifest; loading an
+    artifact reconstructs the signature it was compiled with.
     """
 
     def __init__(self, model, params, slots=None, prefill_buckets=None,
                  name=None, donate=None, emit_logits=True,
-                 sample_args=None, logit_mask=None, adapter_spec=None):
+                 logit_mask=None, adapter_spec=None):
         import jax
         import jax.numpy as jnp
         if not isinstance(model, DecodeModel):
@@ -156,15 +152,9 @@ class DecodeProgram:
             donate = jax.default_backend() != 'cpu'
         self._donate = bool(donate)
         self.emit_logits = bool(emit_logits)
-        self.sample_args = bool(
-            sample_args if sample_args is not None
-            else _knob('MXNET_TPU_SERVE_SAMPLE_ARGS', True))
         self.logit_mask = bool(
             logit_mask if logit_mask is not None
             else _knob('MXNET_TPU_SERVE_SAMPLE_MASK', False))
-        if self.logit_mask and not self.sample_args:
-            raise ValueError('logit_mask requires sample_args (the '
-                             'mask applies at the sampling point)')
         self.adapter_spec = adapter_spec
         self._zero_apool_cached = None
         self._compiled = {}          # key -> jax Compiled
@@ -216,31 +206,25 @@ class DecodeProgram:
     def _cache_avals(self):
         return cache_avals(self._spec, self.slots)
 
-    # -- sampling / adapter extras (one dict pytree appended to the
-    # program signature when either feature is compiled in) -----------------
-
-    @property
-    def _has_extras(self):
-        return self.sample_args or self.adapter_spec is not None
+    # -- sampling / adapter extras (one dict pytree, the last operand
+    # of every token-emitting program) --------------------------------------
 
     def _extra_avals(self, kind):
         """Aval pytree of the ``extras`` argument for one program
-        kind ('prefill' | 'step' | 'verify'). Empty features are
-        absent keys, so a sampling-only program carries no adapter
-        arrays and vice versa."""
+        kind ('prefill' | 'step' | 'verify'). A feature that was not
+        compiled in is an absent key: no mask without ``logit_mask``,
+        no adapter arrays without an ``adapter_spec``."""
         import jax
         extras = {}
         S, V = self.slots, self.model.vocab
-        if self.sample_args:
-            rows = 1 if kind == 'prefill' else S
-            extras['temps'] = jax.ShapeDtypeStruct((rows,), 'float32')
-            extras['top_ps'] = jax.ShapeDtypeStruct((rows,), 'float32')
-            kshape = (S, self.spec_k + 1, 2) if kind == 'verify' \
-                else (rows, 2)
-            extras['keys'] = jax.ShapeDtypeStruct(kshape, 'uint32')
-            if self.logit_mask:
-                extras['masks'] = jax.ShapeDtypeStruct((rows, V),
-                                                       'float32')
+        rows = 1 if kind == 'prefill' else S
+        extras['temps'] = jax.ShapeDtypeStruct((rows,), 'float32')
+        extras['top_ps'] = jax.ShapeDtypeStruct((rows,), 'float32')
+        kshape = (S, self.spec_k + 1, 2) if kind == 'verify' \
+            else (rows, 2)
+        extras['keys'] = jax.ShapeDtypeStruct(kshape, 'uint32')
+        if self.logit_mask:
+            extras['masks'] = jax.ShapeDtypeStruct((rows, V), 'float32')
         if self.adapter_spec is not None:
             extras['apool'] = self.adapter_spec.avals()
             extras['aidx'] = jax.ShapeDtypeStruct(
@@ -262,31 +246,25 @@ class DecodeProgram:
     def _extra_args(self, kind, temps=None, top_ps=None, keys=None,
                     masks=None, apool=None, aidx=None):
         """Concrete ``extras`` for one call; None fields take the
-        neutral value (greedy, no mask, base adapter). Returns () when
-        the program compiled without extras — the pre-sampling
-        signature."""
-        if not self._has_extras:
-            return ()
+        neutral value (greedy, no mask, base adapter)."""
         extras = {}
         S, V = self.slots, self.model.vocab
-        if self.sample_args:
-            rows = 1 if kind == 'prefill' else S
-            extras['temps'] = (
-                onp.zeros((rows,), 'float32') if temps is None
-                else onp.asarray(temps, 'float32').reshape(rows))
-            extras['top_ps'] = (
-                onp.ones((rows,), 'float32') if top_ps is None
-                else onp.asarray(top_ps, 'float32').reshape(rows))
-            kshape = (S, self.spec_k + 1, 2) if kind == 'verify' \
-                else (rows, 2)
-            extras['keys'] = (
-                onp.zeros(kshape, 'uint32') if keys is None
-                else onp.asarray(keys, 'uint32').reshape(kshape))
-            if self.logit_mask:
-                extras['masks'] = (
-                    onp.zeros((rows, V), 'float32') if masks is None
-                    else onp.asarray(masks, 'float32').reshape(rows,
-                                                               V))
+        rows = 1 if kind == 'prefill' else S
+        extras['temps'] = (
+            onp.zeros((rows,), 'float32') if temps is None
+            else onp.asarray(temps, 'float32').reshape(rows))
+        extras['top_ps'] = (
+            onp.ones((rows,), 'float32') if top_ps is None
+            else onp.asarray(top_ps, 'float32').reshape(rows))
+        kshape = (S, self.spec_k + 1, 2) if kind == 'verify' \
+            else (rows, 2)
+        extras['keys'] = (
+            onp.zeros(kshape, 'uint32') if keys is None
+            else onp.asarray(keys, 'uint32').reshape(kshape))
+        if self.logit_mask:
+            extras['masks'] = (
+                onp.zeros((rows, V), 'float32') if masks is None
+                else onp.asarray(masks, 'float32').reshape(rows, V))
         if self.adapter_spec is not None:
             extras['apool'] = apool if apool is not None \
                 else self._zero_apool()
@@ -296,14 +274,14 @@ class DecodeProgram:
                 extras['aidx'] = (
                     onp.zeros((S,), 'int32') if aidx is None
                     else onp.asarray(aidx, 'int32').reshape(S))
-        return (extras,)
+        return extras
 
     @staticmethod
     def _gather_ad(extras):
         """Per-call adapter view for the model: pool rows selected by
         the (scalar or per-slot) indices — a 2-D (r, in)/(out, r)
         pair at prefill, per-slot 3-D stacks at step/verify."""
-        if extras is None or 'apool' not in extras:
+        if 'apool' not in extras:
             return None
         aidx = extras['aidx']
         return {k: (a[aidx], b[aidx])
@@ -314,21 +292,10 @@ class DecodeProgram:
     spec_k = 0
 
     def _prefill_fn(self, key):
-        import jax.numpy as jnp
         from .sampling import sample_tokens
         counts = self.trace_counts
         model, emit = self.model, self.emit_logits
-        sample, gather = self.sample_args, self._gather_ad
-
-        if not self._has_extras:
-            def fn(params, cache, tokens, length, slot):
-                counts[key] = counts.get(key, 0) + 1
-                cache, logits = model.prefill(params, cache, tokens,
-                                              length, slot)
-                tok = jnp.argmax(logits, axis=-1).astype('int32')
-                return (cache, tok, logits) if emit else (cache, tok)
-            return fn
-
+        gather = self._gather_ad
         # the adapter operand exists only when an adapter_spec was
         # compiled in (never for families without lora_targets, e.g.
         # RNNLM, whose prefill/step take no ad argument)
@@ -343,31 +310,17 @@ class DecodeProgram:
             else:
                 cache, logits = model.prefill(params, cache, tokens,
                                               length, slot)
-            if sample:
-                tok = sample_tokens(logits[None], extras['temps'],
-                                    extras['top_ps'], extras['keys'],
-                                    extras.get('masks'))[0]
-            else:
-                tok = jnp.argmax(logits, axis=-1).astype('int32')
+            tok = sample_tokens(logits[None], extras['temps'],
+                                extras['top_ps'], extras['keys'],
+                                extras.get('masks'))[0]
             return (cache, tok, logits) if emit else (cache, tok)
         return fn
 
     def _step_fn(self, key):
-        import jax.numpy as jnp
         from .sampling import sample_tokens
         counts = self.trace_counts
         model, emit = self.model, self.emit_logits
-        sample, gather = self.sample_args, self._gather_ad
-
-        if not self._has_extras:
-            def fn(params, cache, tokens, positions):
-                counts[key] = counts.get(key, 0) + 1
-                cache, logits = model.step(params, cache, tokens,
-                                           positions)
-                tok = jnp.argmax(logits, axis=-1).astype('int32')
-                return (cache, tok, logits) if emit else (cache, tok)
-            return fn
-
+        gather = self._gather_ad
         ad_on = self.adapter_spec is not None
 
         def fn(params, cache, tokens, positions, extras):
@@ -378,12 +331,9 @@ class DecodeProgram:
             else:
                 cache, logits = model.step(params, cache, tokens,
                                            positions)
-            if sample:
-                tok = sample_tokens(logits, extras['temps'],
-                                    extras['top_ps'], extras['keys'],
-                                    extras.get('masks'))
-            else:
-                tok = jnp.argmax(logits, axis=-1).astype('int32')
+            tok = sample_tokens(logits, extras['temps'],
+                                extras['top_ps'], extras['keys'],
+                                extras.get('masks'))
             return (cache, tok, logits) if emit else (cache, tok)
         return fn
 
@@ -444,23 +394,20 @@ class DecodeProgram:
     def compile_prefill(self, bucket):
         import jax
         key = self._program_key('prefill:%d' % bucket)
-        avals = [jax.ShapeDtypeStruct((1, bucket), 'int32'),
-                 jax.ShapeDtypeStruct((), 'int32'),
-                 jax.ShapeDtypeStruct((), 'int32')]
-        if self._has_extras:
-            avals.append(self._extra_avals('prefill'))
         return self._build(key, 'prefill_b%d' % bucket,
-                           self._prefill_fn(key), *avals)
+                           self._prefill_fn(key),
+                           jax.ShapeDtypeStruct((1, bucket), 'int32'),
+                           jax.ShapeDtypeStruct((), 'int32'),
+                           jax.ShapeDtypeStruct((), 'int32'),
+                           self._extra_avals('prefill'))
 
     def compile_step(self):
         import jax
         key = self._program_key('step')
-        avals = [jax.ShapeDtypeStruct((self.slots,), 'int32'),
-                 jax.ShapeDtypeStruct((self.slots,), 'int32')]
-        if self._has_extras:
-            avals.append(self._extra_avals('step'))
         return self._build(key, self._step_name(), self._step_fn(key),
-                           *avals)
+                           jax.ShapeDtypeStruct((self.slots,), 'int32'),
+                           jax.ShapeDtypeStruct((self.slots,), 'int32'),
+                           self._extra_avals('step'))
 
     def warmup(self, buckets=None):
         """Compile the whole ladder + the step program (server start,
@@ -515,8 +462,8 @@ class DecodeProgram:
         prog = self.compile_prefill(bucket)
         cache, tok, logits = self._call(
             prog, cache, padded, onp.int32(n), onp.int32(slot),
-            *self._extra_args('prefill', temps, top_ps, keys, masks,
-                              apool, aidx))
+            self._extra_args('prefill', temps, top_ps, keys, masks,
+                             apool, aidx))
         return cache, int(tok), logits
 
     def run_step(self, cache, tokens, positions, temps=None,
@@ -529,8 +476,8 @@ class DecodeProgram:
             prog, cache,
             onp.asarray(tokens, 'int32').reshape(self.slots),
             onp.asarray(positions, 'int32').reshape(self.slots),
-            *self._extra_args('step', temps, top_ps, keys, masks,
-                              apool, aidx))
+            self._extra_args('step', temps, top_ps, keys, masks,
+                             apool, aidx))
 
     def max_prompt_len(self):
         return self.policy.max_batch
@@ -673,11 +620,9 @@ class DecodeProgram:
             'prefill_buckets': list(self.policy.buckets),
             'emit_logits': self.emit_logits,
             'donate': self._donate,
-            # the extras signature the programs were compiled with —
+            # what the extras operand holds beside the sampling law:
             # load() must reconstruct it exactly or the serialized
-            # executables stop matching (absent keys = pre-sampling
-            # artifact = no extras argument at all)
-            'sample_args': self.sample_args,
+            # executables stop matching
             'logit_mask': self.logit_mask,
             'adapter': (None if self.adapter_spec is None
                         else self.adapter_spec.to_manifest()),
@@ -704,9 +649,10 @@ class DecodeProgram:
     @classmethod
     def load(cls, path):
         """Reload a decode artifact; executables deserialize when jax
-        version + platform match, else the key re-jits on first use
-        and lands in ``retraced_buckets``. Dispatches on the manifest:
-        paged artifacts reload as :class:`PagedDecodeProgram`."""
+        version, platform and program signature match, else the key
+        re-jits on first use and lands in ``retraced_buckets``.
+        Dispatches on the manifest: paged artifacts reload as
+        :class:`PagedDecodeProgram`."""
         import jax
         with open(os.path.join(path, 'MANIFEST.json')) as f:
             manifest = json.load(f)
@@ -740,12 +686,18 @@ class DecodeProgram:
                       name=manifest.get('name'),
                       donate=manifest.get('donate'),
                       emit_logits=manifest.get('emit_logits', True),
-                      sample_args=manifest.get('sample_args', False),
                       logit_mask=manifest.get('logit_mask', False),
                       adapter_spec=aspec,
                       **kwargs)
+        # an artifact from before every program took ``extras``: its
+        # manifest says ``sample_args: false`` or, older still, has no
+        # key of the signature at all. Its executables were compiled
+        # for a signature that is gone, so they take the road of a
+        # jax-version mismatch: not loaded, re-jitted on first use
         env_ok = (manifest.get('jax_version') == jax.__version__
-                  and manifest.get('platform') == jax.default_backend())
+                  and manifest.get('platform') == jax.default_backend()
+                  and manifest.get('sample_args',
+                                   'logit_mask' in manifest))
         for key, fname in (manifest.get('programs') or {}).items():
             if not env_ok:
                 prog.retraced_buckets.append(key)
@@ -781,9 +733,12 @@ class PagedDecodeProgram(DecodeProgram):
         slot advance in one call, logits at every position.
 
     Total executables: ``len(ladder) + 2`` (+1 with speculation).
-    Page allocation/free/refcounting/prefix-sharing live in the
-    ENGINE scheduler (:mod:`.paged`); this class only compiles and
-    runs fixed shapes — page churn costs zero retraces.
+    Page allocation/free/refcounting/prefix-sharing live on the host,
+    in the scheduler's :class:`~.paged.PageOwner`; this class only
+    compiles and runs fixed shapes — page churn costs zero retraces.
+    Page ids and tables arrive as the owner hands them out: bare where
+    the model has one kind of layer, ``{'full': ..., 'window': ...}``
+    where it has two (:meth:`_by_kind`).
     """
 
     paged = True
@@ -791,7 +746,7 @@ class PagedDecodeProgram(DecodeProgram):
     def __init__(self, model, params, slots=None, prefill_buckets=None,
                  name=None, donate=None, emit_logits=True,
                  page_size=None, pages=None, spec_k=None,
-                 sample_args=None, logit_mask=None, adapter_spec=None):
+                 logit_mask=None, adapter_spec=None):
         if not getattr(model, 'supports_paging', False):
             raise TypeError(
                 'family %r does not support a paged cache (an RNN '
@@ -800,19 +755,18 @@ class PagedDecodeProgram(DecodeProgram):
         super().__init__(model, params, slots=slots,
                          prefill_buckets=prefill_buckets, name=name,
                          donate=donate, emit_logits=emit_logits,
-                         sample_args=sample_args,
                          logit_mask=logit_mask,
                          adapter_spec=adapter_spec)
         self.page_size = int(
             page_size if page_size is not None
             else _knob('MXNET_TPU_SERVE_PAGE_SIZE', 16))
-        self._pspec = model.paged_spec(self.page_size)
-        self.max_pages = self._pspec.max_pages
+        self.page_spec = model.paged_spec(self.page_size)
+        self.max_pages = self.page_spec.max_pages
         # two kinds of layer (paged.PagedCacheSpec): a sliding-window
         # layer's table is a ring of ``window_pages`` columns and its
         # pools hold every slot's ring plus the trash page; 0 where
         # the model has one kind
-        self.window_pages = self._pspec.window_pages
+        self.window_pages = self.page_spec.window_pages
         self.window_pool_pages = self.slots * self.window_pages + 1 \
             if self.window_pages else 0
         self._n_stats = len(getattr(model, 'step_stats', ()))
@@ -841,11 +795,11 @@ class PagedDecodeProgram(DecodeProgram):
     # slots × max_len worst case the slot cache reserved) ------------------
 
     def cache_bytes(self):
-        return pool_bytes(self._pspec, self.pages, self.window_pool_pages)
+        return pool_bytes(self.page_spec, self.pages, self.window_pool_pages)
 
     def page_bytes(self):
         """Bytes one page holds across every cache entry."""
-        return pool_bytes(self._pspec, 1, 1)
+        return pool_bytes(self.page_spec, 1, 1)
 
     def per_sequence_bytes(self, seq_len=None):
         """Amortized cache bytes for a sequence of ``seq_len`` tokens
@@ -855,15 +809,15 @@ class PagedDecodeProgram(DecodeProgram):
         """
         n = self.model.max_len if seq_len is None else int(seq_len)
         held = pages_for(n, self.page_size)
-        return pool_bytes(self._pspec, held,
+        return pool_bytes(self.page_spec, held,
                           min(held, self.window_pages))
 
     def new_cache(self):
         """Fresh zeroed page pool."""
-        return init_pool(self._pspec, self.pages, self.window_pool_pages)
+        return init_pool(self.page_spec, self.pages, self.window_pool_pages)
 
     def _cache_avals(self):
-        return pool_avals(self._pspec, self.pages,
+        return pool_avals(self.page_spec, self.pages,
                           self.window_pool_pages)
 
     def _manifest_extra(self):
@@ -876,43 +830,45 @@ class PagedDecodeProgram(DecodeProgram):
                        window_pool_pages=self.window_pool_pages)
         return out
 
+    @property
+    def pool_pages(self):
+        """Pool size by kind of layer (what a ``PageOwner`` is built
+        over)."""
+        out = {'full': self.pages}
+        if self.window_pages:
+            out['window'] = self.window_pool_pages
+        return out
+
     def _by_kind(self, full, window):
-        """Page ids, tables or avals of them as the programs take
-        them: the full layers' alone where the model has one kind of
-        layer, ``{'full': ..., 'window': ...}`` where it has two."""
+        """Avals of page ids or tables as the programs take them: the
+        full layers' alone where the model has one kind of layer,
+        ``{'full': ..., 'window': ...}`` where it has two."""
         return {'full': full, 'window': window} if self.window_pages \
             else full
+
+    def _each_kind(self, arg, fn):
+        """``fn`` over page ids or tables in that form, as a
+        ``PageOwner`` hands them out."""
+        if self.window_pages:
+            return {'full': fn(arg['full']), 'window': fn(arg['window'])}
+        return fn(arg)
 
     # -- program construction ----------------------------------------------
 
     def _paged_prefill_fn(self, key):
-        import jax.numpy as jnp
         from .sampling import sample_tokens
         counts = self.trace_counts
         model, emit = self.model, self.emit_logits
-        sample, gather = self.sample_args, self._gather_ad
-
-        if not self._has_extras:
-            def fn(params, pool, tokens, length, page_ids):
-                counts[key] = counts.get(key, 0) + 1
-                pool, logits = model.paged_prefill(params, pool,
-                                                   tokens, length,
-                                                   page_ids)
-                tok = jnp.argmax(logits, axis=-1).astype('int32')
-                return (pool, tok, logits) if emit else (pool, tok)
-            return fn
+        gather = self._gather_ad
 
         def fn(params, pool, tokens, length, page_ids, extras):
             counts[key] = counts.get(key, 0) + 1
             pool, logits = model.paged_prefill(params, pool, tokens,
                                                length, page_ids,
                                                gather(extras))
-            if sample:
-                tok = sample_tokens(logits[None], extras['temps'],
-                                    extras['top_ps'], extras['keys'],
-                                    extras.get('masks'))[0]
-            else:
-                tok = jnp.argmax(logits, axis=-1).astype('int32')
+            tok = sample_tokens(logits[None], extras['temps'],
+                                extras['top_ps'], extras['keys'],
+                                extras.get('masks'))[0]
             return (pool, tok, logits) if emit else (pool, tok)
         return fn
 
@@ -921,34 +877,19 @@ class PagedDecodeProgram(DecodeProgram):
         from .sampling import sample_tokens
         counts = self.trace_counts
         model, emit = self.model, self.emit_logits
-        sample, gather = self.sample_args, self._gather_ad
-
-        def with_stats(tok, stats):
-            # a family's device-side counts (model.step_stats) ride
-            # behind the tokens: one array, one read a tick
-            return jnp.concatenate([tok, stats[0]]) if stats else tok
-
-        if not self._has_extras:
-            def fn(params, pool, tokens, positions, tables):
-                counts[key] = counts.get(key, 0) + 1
-                pool, logits, *stats = model.paged_step(
-                    params, pool, tokens, positions, tables)
-                tok = jnp.argmax(logits, axis=-1).astype('int32')
-                tok = with_stats(tok, stats)
-                return (pool, tok, logits) if emit else (pool, tok)
-            return fn
+        gather = self._gather_ad
 
         def fn(params, pool, tokens, positions, tables, extras):
             counts[key] = counts.get(key, 0) + 1
             pool, logits, *stats = model.paged_step(
                 params, pool, tokens, positions, tables, gather(extras))
-            if sample:
-                tok = sample_tokens(logits, extras['temps'],
-                                    extras['top_ps'], extras['keys'],
-                                    extras.get('masks'))
-            else:
-                tok = jnp.argmax(logits, axis=-1).astype('int32')
-            tok = with_stats(tok, stats)
+            tok = sample_tokens(logits, extras['temps'],
+                                extras['top_ps'], extras['keys'],
+                                extras.get('masks'))
+            if stats:
+                # a family's device-side counts (model.step_stats) ride
+                # behind the tokens: one array, one read a tick
+                tok = jnp.concatenate([tok, stats[0]])
             return (pool, tok, logits) if emit else (pool, tok)
         return fn
 
@@ -957,47 +898,34 @@ class PagedDecodeProgram(DecodeProgram):
         from .sampling import sample_tokens
         counts = self.trace_counts
         model, emit = self.model, self.emit_logits
-        sample, gather = self.sample_args, self._gather_ad
-
-        if not self._has_extras:
-            def fn(params, pool, tokens, positions, tables):
-                counts[key] = counts.get(key, 0) + 1
-                pool, logits = model.paged_verify(params, pool,
-                                                  tokens, positions,
-                                                  tables)
-                tok = jnp.argmax(logits, axis=-1).astype('int32')
-                return (pool, tok, logits) if emit else (pool, tok)
-            return fn
+        gather = self._gather_ad
 
         def fn(params, pool, tokens, positions, tables, extras):
             counts[key] = counts.get(key, 0) + 1
             pool, logits = model.paged_verify(params, pool, tokens,
                                               positions, tables,
                                               gather(extras))
-            if sample:
-                # one sampler row per (slot, chunk-position): the row
-                # at (s, c) uses the SAME key the plain path would at
-                # that absolute position, so verify-emitted tokens are
-                # bit-identical to unspeculated sampling
-                S, C, V = logits.shape
-                masks = extras.get('masks')
-                if masks is not None:
-                    masks = jnp.repeat(masks, C, axis=0)
-                tok = sample_tokens(
-                    logits.reshape(S * C, V),
-                    jnp.repeat(extras['temps'], C),
-                    jnp.repeat(extras['top_ps'], C),
-                    extras['keys'].reshape(S * C, 2),
-                    masks).reshape(S, C)
-            else:
-                tok = jnp.argmax(logits, axis=-1).astype('int32')
+            # one sampler row per (slot, chunk-position): the row at
+            # (s, c) uses the SAME key the plain path would at that
+            # absolute position, so verify-emitted tokens are
+            # bit-identical to unspeculated sampling
+            S, C, V = logits.shape
+            masks = extras.get('masks')
+            if masks is not None:
+                masks = jnp.repeat(masks, C, axis=0)
+            tok = sample_tokens(
+                logits.reshape(S * C, V),
+                jnp.repeat(extras['temps'], C),
+                jnp.repeat(extras['top_ps'], C),
+                extras['keys'].reshape(S * C, 2),
+                masks).reshape(S, C)
             return (pool, tok, logits) if emit else (pool, tok)
         return fn
 
     def _copy_fn(self, key):
         counts = self.trace_counts
 
-        windowed = self._pspec.window_entries
+        windowed = self.page_spec.window_entries
 
         def fn(params, pool, src, dst):
             counts[key] = counts.get(key, 0) + 1
@@ -1018,43 +946,38 @@ class PagedDecodeProgram(DecodeProgram):
         key = self._program_key('prefill:%d' % bucket)
         npages = pages_for(bucket, self.page_size)
         ids = jax.ShapeDtypeStruct((npages,), 'int32')
-        avals = [jax.ShapeDtypeStruct((1, bucket), 'int32'),
-                 jax.ShapeDtypeStruct((), 'int32'),
-                 self._by_kind(ids, ids)]
-        if self._has_extras:
-            avals.append(self._extra_avals('prefill'))
         return self._build(key, 'prefill_b%d' % bucket,
-                           self._paged_prefill_fn(key), *avals)
+                           self._paged_prefill_fn(key),
+                           jax.ShapeDtypeStruct((1, bucket), 'int32'),
+                           jax.ShapeDtypeStruct((), 'int32'),
+                           self._by_kind(ids, ids),
+                           self._extra_avals('prefill'))
 
     def compile_step(self):
         import jax
         key = self._program_key('step')
-        avals = [jax.ShapeDtypeStruct((self.slots,), 'int32'),
-                 jax.ShapeDtypeStruct((self.slots,), 'int32'),
-                 self._by_kind(
-                     jax.ShapeDtypeStruct((self.slots, self.max_pages),
-                                          'int32'),
-                     jax.ShapeDtypeStruct((self.slots, self.window_pages),
-                                          'int32'))]
-        if self._has_extras:
-            avals.append(self._extra_avals('step'))
-        return self._build(key, self._step_name(),
-                           self._paged_step_fn(key), *avals)
+        return self._build(
+            key, self._step_name(), self._paged_step_fn(key),
+            jax.ShapeDtypeStruct((self.slots,), 'int32'),
+            jax.ShapeDtypeStruct((self.slots,), 'int32'),
+            self._by_kind(
+                jax.ShapeDtypeStruct((self.slots, self.max_pages),
+                                     'int32'),
+                jax.ShapeDtypeStruct((self.slots, self.window_pages),
+                                     'int32')),
+            self._extra_avals('step'))
 
     def compile_verify(self):
         import jax
         if not self.spec_k:
             raise ValueError('verify program needs spec_k > 0')
         key = self._program_key('verify:%d' % (self.spec_k + 1))
-        avals = [jax.ShapeDtypeStruct((self.slots, self.spec_k + 1),
-                                      'int32'),
-                 jax.ShapeDtypeStruct((self.slots,), 'int32'),
-                 jax.ShapeDtypeStruct((self.slots, self.max_pages),
-                                      'int32')]
-        if self._has_extras:
-            avals.append(self._extra_avals('verify'))
-        return self._build(key, 'verify_k%d' % self.spec_k,
-                           self._verify_fn(key), *avals)
+        return self._build(
+            key, 'verify_k%d' % self.spec_k, self._verify_fn(key),
+            jax.ShapeDtypeStruct((self.slots, self.spec_k + 1), 'int32'),
+            jax.ShapeDtypeStruct((self.slots,), 'int32'),
+            jax.ShapeDtypeStruct((self.slots, self.max_pages), 'int32'),
+            self._extra_avals('verify'))
 
     def compile_copy_page(self):
         import jax
@@ -1079,13 +1002,13 @@ class PagedDecodeProgram(DecodeProgram):
 
     def run_prefill(self, pool, tokens, page_ids, temps=None,
                     top_ps=None, keys=None, masks=None, apool=None,
-                    aidx=None, wpage_ids=None):
+                    aidx=None):
         """Pad ``tokens`` to its bucket and land its K/V in the
-        host-allocated ``page_ids`` (list; padded with the trash page
-        to the bucket's page count). ``wpage_ids`` is the same list
-        for the window layers of a model that has them: one id a
-        prompt page, the trash page for each page already behind the
-        window. Returns (pool', first_token, logits | None)."""
+        host-allocated ``page_ids``: a list, one id a prompt page
+        (padded here with the trash page to the bucket's page count),
+        or such a list for each kind of layer, where a window layer's
+        holds the trash page for each page already behind the window.
+        Returns (pool', first_token, logits | None)."""
         tokens = onp.asarray(tokens, 'int32').reshape(-1)
         n = tokens.shape[0]
         if n < 1:
@@ -1106,33 +1029,31 @@ class PagedDecodeProgram(DecodeProgram):
         prog = self.compile_prefill(bucket)
         pool, tok, logits = self._call(
             prog, pool, padded, onp.int32(n),
-            self._by_kind(padded_ids(page_ids),
-                          padded_ids(wpage_ids or ())),
-            *self._extra_args('prefill', temps, top_ps, keys, masks,
-                              apool, aidx))
+            self._each_kind(page_ids, padded_ids),
+            self._extra_args('prefill', temps, top_ps, keys, masks,
+                             apool, aidx))
         return pool, int(tok), logits
+
+    def _tables_arg(self, tables):
+        return self._each_kind(
+            tables,
+            lambda t: onp.asarray(t, 'int32').reshape(self.slots, -1))
 
     def run_step(self, pool, tokens, positions, tables, temps=None,
                  top_ps=None, keys=None, masks=None, apool=None,
-                 aidx=None, wtables=None):
+                 aidx=None):
         """Advance every slot one token through its page table
-        (``wtables``: the window layers' ring tables, for a model that
-        has them). A family's device-side counts come back behind the
+        (``tables``: one (slots, columns) array, or one for each kind
+        of layer). A family's device-side counts come back behind the
         tokens and are left in ``last_step_stats``."""
         prog = self.compile_step()
-        if self.window_pages:
-            wtables = onp.asarray(wtables, 'int32').reshape(
-                self.slots, self.window_pages)
         pool, toks, logits = self._call(
             prog, pool,
             onp.asarray(tokens, 'int32').reshape(self.slots),
             onp.asarray(positions, 'int32').reshape(self.slots),
-            self._by_kind(
-                onp.asarray(tables, 'int32').reshape(self.slots,
-                                                     self.max_pages),
-                wtables),
-            *self._extra_args('step', temps, top_ps, keys, masks,
-                              apool, aidx))
+            self._tables_arg(tables),
+            self._extra_args('step', temps, top_ps, keys, masks,
+                             apool, aidx))
         if self._n_stats:
             self.last_step_stats = dict(zip(
                 self.model.step_stats,
@@ -1154,21 +1075,18 @@ class PagedDecodeProgram(DecodeProgram):
             onp.asarray(tokens, 'int32').reshape(self.slots,
                                                  self.spec_k + 1),
             onp.asarray(positions, 'int32').reshape(self.slots),
-            onp.asarray(tables, 'int32').reshape(self.slots,
-                                                 self.max_pages),
-            *self._extra_args('verify', temps, top_ps, keys, masks,
-                              apool, aidx))
+            self._tables_arg(tables),
+            self._extra_args('verify', temps, top_ps, keys, masks,
+                             apool, aidx))
 
-    def run_copy_page(self, pool, src, dst, wsrc=TRASH_PAGE,
-                      wdst=TRASH_PAGE):
-        """Copy-on-write: duplicate page ``src`` into ``dst`` in the
-        full layers' pools and ``wsrc`` into ``wdst`` in the window
-        layers' (the trash page onto itself where only one kind
-        copies)."""
+    def run_copy_page(self, pool, src, dst):
+        """Copy-on-write: duplicate page ``src`` into ``dst``, or, of
+        a model with two kinds of layer, ``src[kind]`` into
+        ``dst[kind]`` within each kind's pools (the trash page onto
+        itself where only one kind copies)."""
         prog = self.compile_copy_page()
-        return prog(self._params, pool,
-                    self._by_kind(onp.int32(src), onp.int32(wsrc)),
-                    self._by_kind(onp.int32(dst), onp.int32(wdst)))
+        return prog(self._params, pool, self._each_kind(src, onp.int32),
+                    self._each_kind(dst, onp.int32))
 
     # -- live migration (seqstate export/import) ----------------------------
 
@@ -1226,9 +1144,8 @@ class PagedDecodeProgram(DecodeProgram):
 def freeze_decode(obj, params=None, slots=None, prefill_buckets=None,
                   max_len=None, name=None, donate=None,
                   emit_logits=True, paged=None, page_size=None,
-                  pages=None, spec_k=None, sample_args=None,
-                  logit_mask=None, adapter_rank=None,
-                  adapter_slots=None):
+                  pages=None, spec_k=None, logit_mask=None,
+                  adapter_rank=None, adapter_slots=None):
     """Freeze a generation model into a :class:`DecodeProgram`.
 
     ``obj`` — one of:
@@ -1255,8 +1172,8 @@ def freeze_decode(obj, params=None, slots=None, prefill_buckets=None,
     ``adapter_rank`` > 0 (``MXNET_TPU_SERVE_ADAPTER_RANK``) compiles a
     low-rank adapter pool of ``adapter_slots`` resident variants
     (``MXNET_TPU_SERVE_ADAPTER_SLOTS``) into every program — LoRA
-    families only. ``sample_args`` / ``logit_mask`` select the
-    sampling signature (see :class:`DecodeProgram`).
+    families only. ``logit_mask`` adds the mask operand (see
+    :class:`DecodeProgram`).
     """
     if max_len is None:
         max_len = int(_knob('MXNET_TPU_SERVE_MAX_SEQ_LEN', 256))
@@ -1308,12 +1225,11 @@ def freeze_decode(obj, params=None, slots=None, prefill_buckets=None,
             model, params, slots=slots,
             prefill_buckets=prefill_buckets, name=name, donate=donate,
             emit_logits=emit_logits, page_size=page_size, pages=pages,
-            spec_k=spec_k, sample_args=sample_args,
-            logit_mask=logit_mask, adapter_spec=adapter_spec)
+            spec_k=spec_k, logit_mask=logit_mask,
+            adapter_spec=adapter_spec)
     return DecodeProgram(model, params, slots=slots,
                          prefill_buckets=prefill_buckets, name=name,
                          donate=donate, emit_logits=emit_logits,
-                         sample_args=sample_args,
                          logit_mask=logit_mask,
                          adapter_spec=adapter_spec)
 

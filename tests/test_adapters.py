@@ -53,16 +53,25 @@ def slot_extras(model_params):
     model, params = model_params
     return freeze_decode(model, params, slots=4,
                          prefill_buckets=(16,), paged=False,
-                         sample_args=True, adapter_rank=RANK,
+                         adapter_rank=RANK,
                          adapter_slots=4)
 
 
 @pytest.fixture(scope='module')
 def slot_legacy(model_params):
+    """The greedy continuation by the uncached whole-sequence forward:
+    what a program compiled without the sampling operand emitted (there
+    is no such program any more)."""
     model, params = model_params
-    return freeze_decode(model, params, slots=4,
-                         prefill_buckets=(16,), paged=False,
-                         sample_args=False)
+
+    def continuation(prompt, n):
+        seq = list(prompt)
+        for _ in range(n):
+            logits = np.asarray(model.full_forward(
+                params, np.asarray([seq], 'int32')))[0]
+            seq.append(int(logits[-1].argmax()))
+        return seq[len(prompt):]
+    return continuation
 
 
 @pytest.fixture(scope='module')
@@ -71,7 +80,7 @@ def paged_prog(model_params):
     return freeze_decode(model, params, slots=4,
                          prefill_buckets=(16,), paged=True,
                          page_size=8, pages=64, spec_k=3,
-                         sample_args=True, adapter_rank=RANK,
+                         adapter_rank=RANK,
                          adapter_slots=4)
 
 
@@ -81,7 +90,7 @@ def draft_prog():
                                  layers=1, heads=2, max_len=96,
                                  seed=9)
     return freeze_decode(dm, dp, slots=4, prefill_buckets=(16,),
-                         paged=False, sample_args=True)
+                         paged=False)
 
 
 # ---------------------------------------------------------------------------
@@ -193,8 +202,7 @@ def test_registry_resolves_ids_and_rejects_unknown(model_params,
 def test_temp0_and_base_byte_identical_to_legacy(slot_extras,
                                                  slot_legacy,
                                                  adapter_dir):
-    with DecodeEngine(slot_legacy, name='t0-leg') as e1:
-        ref = list(e1.generate(PROMPT, max_new_tokens=10))
+    ref = slot_legacy(PROMPT, 10)
     with DecodeEngine(slot_extras, adapters=adapter_dir,
                       name='t0-ext') as e2:
         assert list(e2.generate(PROMPT, max_new_tokens=10)) == ref
@@ -259,7 +267,7 @@ def test_pool_exhaustion_at_admission_and_row_reuse(model_params,
     model, params = model_params
     tiny = freeze_decode(model, params, slots=4,
                          prefill_buckets=(16,), paged=True,
-                         page_size=8, pages=64, sample_args=True,
+                         page_size=8, pages=64,
                          adapter_rank=RANK, adapter_slots=2)
     with DecodeEngine(tiny, adapters=adapter_dir, name='tiny') as eng:
         h1 = eng.generate([1, 2, 3], max_new_tokens=40,
@@ -281,15 +289,13 @@ def test_pool_exhaustion_at_admission_and_row_reuse(model_params,
 
 def test_rnn_lm_samples_without_adapter_operand():
     """Regression: families without lora_targets (RNNLM) must still
-    freeze with the default sample_args=True — the extras closure
-    only passes the adapter operand when an adapter_spec compiled
+    freeze: every program samples, and the extras closure only passes the adapter operand when an adapter_spec compiled
     in (RNNLM.prefill/step take no such argument)."""
     from mxnet_tpu.serving.decode import init_rnn_lm
     model, params = init_rnn_lm(vocab=VOCAB, embed=16, hidden=24,
                                 layers=1, max_len=64, seed=3)
     prog = freeze_decode(model, params, slots=2,
-                         prefill_buckets=(16,), paged=False,
-                         sample_args=True)
+                         prefill_buckets=(16,), paged=False)
     with DecodeEngine(prog, name='rnn-sample') as eng:
         greedy = list(eng.generate(PROMPT, max_new_tokens=6))
         a = list(eng.generate(PROMPT, max_new_tokens=6,
@@ -643,8 +649,7 @@ def test_import_without_adapter_support_rejected_typed(model_params,
     model, params = model_params
     plainprog = freeze_decode(model, params, slots=4,
                               prefill_buckets=(16,), paged=True,
-                              page_size=8, pages=64,
-                              sample_args=False)
+                              page_size=8, pages=64)
     src = DecodeEngine(paged_prog, adapters=adapter_dir, name='xsrc')
     dst = DecodeEngine(plainprog, name='xdst')
     try:

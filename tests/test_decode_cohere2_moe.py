@@ -301,7 +301,8 @@ def test_prefill_then_decode_through_a_wrapping_ring_equals_the_reference(
     for lp, page in enumerate(wids):
         if page:
             wtable[lp % ring] = page
-    pool, tok, logits = prog.run_prefill(pool, prompt, full, wpage_ids=wids)
+    pool, tok, logits = prog.run_prefill(pool, prompt,
+                                         {'full': full, 'window': wids})
     seq = list(prompt)
     want = _ref_logits(cfg, w, seq)[-1]
     assert np.abs(logits - want).max() < TOL and tok == int(want.argmax())
@@ -319,8 +320,8 @@ def test_prefill_then_decode_through_a_wrapping_ring_equals_the_reference(
         wtables = np.zeros((4, ring), 'int32')
         tokens[2], positions[2] = tok, pos
         tables[2], wtables[2] = table, wtable
-        pool, toks, logits = prog.run_step(pool, tokens, positions, tables,
-                                           wtables=wtables)
+        pool, toks, logits = prog.run_step(
+            pool, tokens, positions, {'full': tables, 'window': wtables})
         want = _ref_logits(cfg, w, seq)[-1]
         assert np.abs(logits[2] - want).max() < TOL, pos
         tok = int(toks[2])
@@ -510,6 +511,59 @@ def test_one_kind_programs_are_what_they_were():
              .split(',')[0].split(' ')[0] for k in prog._compiled}
     assert names['step'] == 'jit_fn_step'
     assert names['prefill:4'] == 'jit_prefill_b4'
+
+
+_COUNTS = ['adapter_rejects', 'cow_copies', 'drain_timeouts',
+           'fallback_tokens', 'handoff_pages', 'migrated_in', 'migrated_out',
+           'page_evictions', 'pool_exhausted', 'prefill_exports', 'prefills',
+           'prefix_hits', 'prefix_tokens_saved', 'rejected', 'requests',
+           'retired', 'sampled_steps', 'sampled_tokens', 'spec_accepted',
+           'spec_proposed', 'spec_rounds', 'steps', 'timeouts', 'tokens']
+_POOL = ['occupancy_pct', 'pages_free', 'pages_total', 'pages_used',
+         'prefix_entries']
+_ACCOUNTING = ['cache_bytes', 'max_concurrent_sequences_per_gb',
+               'page_bytes', 'paged', 'per_sequence_bytes_amortized',
+               'per_sequence_bytes_max', 'pool']
+
+
+@pytest.mark.parametrize('family', ['transformer', 'cohere2_moe'])
+def test_what_the_benchmark_and_status_read_keeps_its_names(family, toy):
+    """The names that readers outside the package match on, written out
+    from the output of the commit before the page owner (PR 32): the
+    counters, the pool blocks and the cache accounting of ``stats()``,
+    and the XLA modules' names (``benchmark/`` tells the step, the
+    prefills and the page copy apart by them)."""
+    if family == 'transformer':
+        model, params = decode.init_transformer_lm(
+            vocab=23, units=16, hidden=24, layers=2, heads=4, max_len=48)
+        prog = PagedDecodeProgram(model, params, slots=4,
+                                  prefill_buckets=(4, 8, 24), page_size=8)
+        counts, window = _COUNTS, None
+    else:
+        prog = toy[2]
+        counts = sorted(_COUNTS + list(Cohere2MoELM.step_stats) + [
+            'pages_live.full', 'pages_live.window',
+            'window_pages_released'])
+        window = _POOL
+    prog.warmup()
+    eng = DecodeEngine(prog, max_new_tokens=4)
+    try:
+        eng.generate([1, 2, 3, 4, 5], max_new_tokens=4).result(timeout=60)
+        stats = eng.stats()
+        assert sorted(stats['counts']) == counts
+        assert sorted(stats['pages']) == _POOL
+        assert (sorted(stats['pages_window']) if window
+                else stats.get('pages_window')) == window
+        assert sorted(eng.cache_accounting()) == _ACCOUNTING
+        assert sorted(stats['cache']['pool']) == _POOL[:-1]
+    finally:
+        eng.close()
+    names = {k: prog._compiled[k].as_text().split('HloModule ')[1]
+             .split(',')[0].split(' ')[0] for k in prog._compiled}
+    assert names.pop('step') == 'jit_fn_step'
+    assert names.pop('copy') == 'jit_page_copy'
+    assert names and all(name == 'jit_prefill_b%s' % key.split(':')[1]
+                         for key, name in names.items())
 
 
 # ---------------------------------------------------------------------------

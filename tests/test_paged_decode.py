@@ -11,6 +11,7 @@ import json
 import os
 import subprocess
 import sys
+import threading
 import time
 
 import numpy as np
@@ -23,7 +24,8 @@ from mxnet_tpu.serving.decode import (DecodeEngine, DecodeProgram,
                                       PrefixCache, init_rnn_lm,
                                       init_transformer_lm, load_decode)
 from mxnet_tpu.serving.decode.paged import (TRASH_PAGE, PagedCacheSpec,
-                                            pages_for, pool_bytes)
+                                            PageOwner, pages_for,
+                                            pool_bytes)
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -291,6 +293,236 @@ def test_prefix_cache_lru_eviction_leaf_first():
 
 
 # ---------------------------------------------------------------------------
+# the page owner alone: no device, no engine
+# ---------------------------------------------------------------------------
+
+KINDS = pytest.mark.parametrize('two', [False, True],
+                                ids=['one-kind', 'two-kinds'])
+
+
+class _Owned:
+    """A ``PageOwner`` over pages of 4 rows and tables of 16 columns,
+    with or without window layers (window 8: a ring of 3 columns), its
+    counters, its flight events and the copies it asked for."""
+
+    def __init__(self, two, full=17, window=7, prefix=True):
+        entries, kw = {'k': ((4,), 'float32')}, {}
+        pools = {'full': full}
+        if two:
+            entries['wk'] = ((2,), 'float32')
+            kw = dict(window=8, window_entries=('wk',))
+            pools['window'] = window
+        self.two = two
+        self.spec = PagedCacheSpec(entries, 4, 64, **kw)
+        self.counts, self.events, self.copies = {}, [], []
+        self.owner = PageOwner(
+            self.spec, pools, threading.Lock(), prefix, self.counts,
+            lambda kind, **f: self.events.append((kind, f)))
+
+    def copy(self, src, dst):
+        self.copies.append((src, dst))
+
+    def kinds(self, value):
+        """``value`` by kind as the owner hands it out."""
+        return value if self.two else value['full']
+
+    def admit(self, slot, prompt, register=True):
+        """A miss: open, place, register. Returns (record, ids)."""
+        rec = self.owner.open(slot)
+        assert self.owner.share_prefix(rec, prompt) == (0, 0)
+        ids = self.owner.place(rec, len(prompt))
+        if ids is not None and register:
+            self.owner.register(prompt, ids)
+        return rec, ids
+
+    def used(self):
+        return {k: v['pages_used'] for k, v in self.owner.stats().items()}
+
+
+def test_owner_ring_keeps_a_long_prompts_last_pages_and_registers_nothing():
+    o = _Owned(True)
+    prompt = list(range(19))                 # 5 pages, the ring holds 3
+    rec, ids = o.admit(0, prompt)
+    assert len(ids['full']) == 5 and TRASH_PAGE not in ids['full']
+    assert ids['window'][:2] == [TRASH_PAGE, TRASH_PAGE]
+    kept = ids['window'][2:]
+    assert len(kept) == 3 and TRASH_PAGE not in kept
+    assert sorted(rec.held['window']) == sorted(kept)
+    # logical page p sits in column p % 3
+    assert [int(rec.tables['window'][p % 3]) for p in (2, 3, 4)] == kept
+    assert list(rec.tables['full'][:5]) == ids['full']
+    assert rec.top == {'window': 4}
+    stats = o.owner.stats()
+    assert stats['pages']['prefix_entries'] == 5
+    assert stats['pages_window']['prefix_entries'] == 0
+    # so a second sequence with the same prompt shares nothing
+    again = o.owner.open(1)
+    assert o.owner.share_prefix(again, prompt) == (0, 0)
+    assert o.counts['prefix_hits'] == 0
+    # a prompt the ring holds whole is registered by both kinds
+    short = [7] * 10
+    o.admit(2, short)
+    assert o.owner.stats()['pages_window']['prefix_entries'] == 3
+    hit = o.owner.open(3)
+    assert o.owner.share_prefix(hit, short) == (9, 3)
+    assert hit.top == {'window': 2}
+
+
+def test_owner_hit_is_cut_to_what_every_kind_still_holds():
+    o = _Owned(True, window=8)               # 7 ring pages in the pool
+    a = [1] * 12                             # 3 pages in both kinds
+    rec, _ = o.admit(0, a)
+    with o.owner._lock:
+        o.owner.drop(rec)                    # the registries hold them
+    # two more sequences, of 3 and of 2 pages: the second finds one
+    # window page free and evicts the window registry's least recently
+    # used leaf, a's third page
+    for slot, n in ((1, 12), (2, 8)):
+        o.admit(slot, [10 + slot] * n, register=False)
+    assert o.counts['page_evictions'] == 1
+    assert [k for k, _ in o.events].count('page_evict') == 1
+    stats = o.owner.stats()
+    assert stats['pages']['prefix_entries'] == 3
+    assert stats['pages_window']['prefix_entries'] == 2
+    hit = o.owner.open(3)
+    assert o.owner.share_prefix(hit, a) == (8, 2)
+    assert len(hit.held['full']) == 2 and len(hit.held['window']) == 2
+    assert o.counts['prefix_tokens_saved'] == 8
+
+
+@KINDS
+def test_owner_hit_leaves_one_token_to_step_on(two):
+    o = _Owned(two)
+    prompt = [3] * 12
+    o.admit(0, prompt)
+    hit = o.owner.open(1)
+    assert o.owner.share_prefix(hit, prompt) == (11, 3)
+    assert o.counts['prefix_hits'] == 1
+    assert o.counts['prefix_tokens_saved'] == 11
+    # another namespace (the engine passes the adapter id) sees nothing
+    other = o.owner.open(2)
+    assert o.owner.share_prefix(other, prompt, namespace='ad0') == (0, 0)
+
+
+@KINDS
+def test_owner_steals_a_registration_back_and_copies_on_write(two):
+    o = _Owned(two)
+    prompt = [5] * 6                         # a full page and a tail
+    first, ids = o.admit(0, prompt)
+    entries = 2
+    assert o.owner.stats()['pages']['prefix_entries'] == entries
+    # a second sequence shares both pages: three holders of the tail
+    second = o.owner.open(1)
+    assert o.owner.share_prefix(second, prompt) == (5, 2)
+    tail = o.kinds({k: int(t[1]) for k, t in first.tables.items()})
+    assert o.owner.make_writable(second, 5, 5, o.copy)
+    new = o.kinds({k: int(t[1]) for k, t in second.tables.items()})
+    if two:
+        # one copy a kind, the trash page onto itself in the other's
+        assert o.copies == [
+            ({'full': tail['full'], 'window': TRASH_PAGE},
+             {'full': new['full'], 'window': TRASH_PAGE}),
+            ({'full': TRASH_PAGE, 'window': tail['window']},
+             {'full': TRASH_PAGE, 'window': new['window']})]
+        assert new['full'] != tail['full']
+        assert new['window'] != tail['window']
+    else:
+        assert o.copies == [(tail, new)] and new != tail
+    assert o.counts['cow_copies'] == len(o.copies)
+    # now the registry is the tail's only co-holder: the first
+    # sequence's write takes the registration back, and nothing copies
+    del o.copies[:]
+    assert o.owner.make_writable(first, 6, 6, o.copy)
+    assert o.copies == []
+    assert o.kinds({k: int(t[1]) for k, t in first.tables.items()}) \
+        == tail
+    for block in o.owner.stats().values():
+        assert block['prefix_entries'] == entries - 1
+    # a write at a page boundary allocates, in every kind
+    used = o.used()
+    assert o.owner.make_writable(first, 8, 8, o.copy)
+    assert o.used() == {k: v + 1 for k, v in used.items()}
+    assert o.copies == []
+
+
+@KINDS
+def test_owner_exhaustion_after_eviction_leaves_no_hold(two):
+    # one kind: 4 pages; two: 8 of the full layers', 3 of the window's
+    o = _Owned(two, full=9 if two else 5, window=4)
+    rec, _ = o.admit(0, [1] * 8)             # 2 pages a kind, registered
+    with o.owner._lock:
+        o.owner.drop(rec)
+    # 3 pages: registered pages are evicted where the pool is short
+    big, ids = o.admit(1, [2] * 12, register=False)
+    assert ids is not None
+    assert o.counts['page_evictions'] == (2 if two else 1)
+    assert o.used() == ({'pages': 5, 'pages_window': 3} if two
+                        else {'pages': 4})
+    # 2 more: the window layers' pool (one kind: the only pool) cannot
+    # give them even with every registration evicted
+    late, ids = o.admit(2, [3] * 8, register=False)
+    assert ids is None
+    assert all(not held for held in late.held.values())
+    # what the full layers' pool had given to it went back
+    assert o.used() == ({'pages': 5, 'pages_window': 3} if two
+                        else {'pages': 3})
+    allocs = [f['slot'] for k, f in o.events if k == 'page_alloc']
+    assert allocs[-1] == (2 if two else 1)
+
+
+@KINDS
+def test_owner_advance_gives_back_the_pages_behind_the_window(two):
+    o = _Owned(two)
+    rec, _ = o.admit(0, [4] * 10, register=False)       # pages 0-2
+    before = o.used()
+    o.owner.advance([(rec, 11)])             # still on page 2
+    assert o.used() == before
+    if not two:
+        o.owner.advance([(rec, 30)])
+        assert o.used() == before and 'window_pages_released' not in o.counts
+        return
+    was = [int(p) for p in rec.tables['window']]
+    o.owner.advance([(rec, 12)])             # opens page 3: column 0
+    assert int(rec.tables['window'][0]) == TRASH_PAGE
+    assert was[0] not in rec.held['window'] and len(rec.held['window']) == 2
+    assert o.counts['window_pages_released'] == 1
+    assert o.owner.make_writable(rec, 12, 12, o.copy)
+    assert int(rec.tables['window'][0]) != TRASH_PAGE
+    assert int(rec.tables['full'][3]) != TRASH_PAGE
+    # a jump over two page boundaries (a speculative round's lookahead)
+    o.owner.advance([(rec, 20)])             # opens pages 4 and 5
+    assert [int(p) for p in rec.tables['window'][1:]] == [TRASH_PAGE] * 2
+    assert o.counts['window_pages_released'] == 3
+    assert rec.top == {'window': 5}
+    assert o.used() == {'pages': 4, 'pages_window': 1}
+    assert len(rec.held['full']) == 4        # a full layer keeps every page
+
+
+@KINDS
+def test_owner_reset_empties_every_kind(two):
+    o = _Owned(two, window=13)
+    for slot in range(3):
+        o.admit(slot, [slot] * 10)
+    assert all(v['pages_used'] and v['prefix_entries']
+               for v in o.owner.stats().values())
+    o.owner.reset()
+    for block in o.owner.stats().values():
+        assert block['pages_used'] == 0 and block['prefix_entries'] == 0
+        assert block['pages_free'] == block['pages_total']
+    assert o.owner.live_gauges() == (
+        {'pages_live.full': 0, 'pages_live.window': 0} if two else {})
+    # and it serves again
+    rec, ids = o.admit(0, [9] * 10)
+    assert ids is not None
+    tables = o.owner.tables(4, [(2, rec)])
+    for kind, table in (tables.items() if two else [('full', tables)]):
+        assert table.shape == (4, 3 if kind == 'window' else 16)
+        assert list(table[2]) == list(rec.tables[kind])
+        assert not table[[0, 1, 3]].any()
+    assert o.owner.held_bytes([rec]) == pool_bytes(o.spec, 3, 3 if two else 0)
+
+
+# ---------------------------------------------------------------------------
 # paged == slot == uncached reference, across page sizes + slot churn
 # ---------------------------------------------------------------------------
 
@@ -383,6 +615,43 @@ print(json.dumps({"tokens": a, "again": b,
     assert doc['trace_counts'] == {}        # zero retraces
     assert doc['retraced'] == []
     assert doc['prefix_hits'] >= 1
+
+
+@pytest.mark.parametrize('manifest_says', ['false', 'nothing'])
+def test_artifact_from_before_the_one_signature_loads_and_retraces(
+        tmp_path, manifest_says):
+    """An artifact whose programs were compiled without the ``extras``
+    operand (``"sample_args": false``, or a manifest older than the
+    key): the executables are not loaded, their keys are listed in
+    ``retraced_buckets``, and the program generates what the reference
+    does."""
+    model, params = _model()
+    prog = PagedDecodeProgram(model, params, slots=2,
+                              prefill_buckets=(4, 8), page_size=8,
+                              spec_k=0).warmup()
+    art = str(tmp_path / 'old.frozen')
+    prog.save(art)
+    path = os.path.join(art, 'MANIFEST.json')
+    manifest = json.load(open(path))
+    assert 'sample_args' not in manifest and manifest['programs']
+    if manifest_says == 'false':
+        manifest['sample_args'] = False
+    else:
+        del manifest['logit_mask'], manifest['adapter']
+    with open(path, 'w') as f:
+        json.dump(manifest, f)
+    old = load_decode(art)
+    assert isinstance(old, PagedDecodeProgram) and not old._loaded
+    assert sorted(old.retraced_buckets) == sorted(manifest['programs'])
+    prompt = [5, 3, 1, 7, 2, 9]
+    outs, _ = _run_engine(old, [(prompt, 6)])
+    assert outs[0] == _greedy_reference(model, params, prompt, 6)
+    assert set(old.trace_counts) <= set(manifest['programs'])
+    # as it was saved, the same artifact loads its executables
+    prog.save(art)
+    new = load_decode(art)
+    assert sorted(new._loaded) == sorted(manifest['programs'])
+    assert new.retraced_buckets == []
 
 
 def test_load_decode_dispatches_slot_artifacts_unchanged(tmp_path):

@@ -144,7 +144,10 @@ class GenerateStream:
         # carries it to the gateway for the decode-class handoff
         self.seqstate = None
         self.degraded = False
-        self._q = _queue.Queue()
+        # the C queue: a token costs its reader one wake-up, with no
+        # lock and condition in Python on either side (128 readers and
+        # the scheduler share one interpreter)
+        self._q = _queue.SimpleQueue()
         self._done = threading.Event()
         self._exc = None
         self._cancelled = False

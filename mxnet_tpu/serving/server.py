@@ -628,10 +628,13 @@ class ServingHTTPServer:
                     handler.send_error(404)
 
             def _chunk(handler, obj):
-                data = (json.dumps(obj, sort_keys=True)
-                        + '\n').encode()
-                handler.wfile.write(b'%x\r\n' % len(data))
-                handler.wfile.write(data + b'\r\n')
+                handler._chunk_line((json.dumps(obj, sort_keys=True)
+                                     + '\n').encode())
+
+            def _chunk_line(handler, data):
+                # one write a chunk: one system call, one segment, one
+                # wake-up of whoever reads the stream
+                handler.wfile.write(b'%x\r\n%s\r\n' % (len(data), data))
                 handler.wfile.flush()
 
             def _generate(handler, req):
@@ -724,8 +727,12 @@ class ServingHTTPServer:
                 handler.end_headers()
                 try:
                     for i, tok in enumerate(stream):
-                        handler._chunk({'token': tok,
-                                        'index': start_index + i})
+                        # byte for byte what _chunk writes for
+                        # {'token': tok, 'index': ...}, without an
+                        # encoder built for every token of every stream
+                        handler._chunk_line(
+                            b'{"index": %d, "token": %d}\n'
+                            % (start_index + i, tok))
                     done = {'done': True,
                             'tokens': stream.tokens,
                             'finish_reason': stream.finish_reason,

@@ -821,6 +821,57 @@ def test_http_generate_stream_fault_typed_error_line_and_recovery(
         eng.close()
 
 
+@pytest.mark.parametrize('start_index, max_new',
+                         [(0, 5), (7, 1), (123456, 3)])
+def test_http_generate_stream_writes_each_chunk_once(monkeypatch,
+                                                     start_index, max_new):
+    """A streamed token costs its handler one write (one system call,
+    one segment, one wake-up of the reader): the chunk's size line, its
+    NDJSON line and the closing CRLF go out together, and the bytes on
+    the wire are the chunked encoding they were, whatever the width of
+    the index (a resumed stream's starts past 0)."""
+    import http.client
+    import socketserver
+    from mxnet_tpu.serving.server import ServingHTTPServer
+    writes = []
+    write = socketserver._SocketWriter.write
+
+    def recording(self, b):
+        writes.append(bytes(b))
+        return write(self, b)
+
+    monkeypatch.setattr(socketserver._SocketWriter, 'write', recording)
+    eng = DecodeEngine(_FakeProgram(slots=2), timeout_s=10.0)
+    try:
+        with ServingHTTPServer(_EngineSession(eng), 0) as srv:
+            conn = http.client.HTTPConnection('127.0.0.1', srv.port,
+                                              timeout=20)
+            conn.request('POST', '/generate', body=json.dumps(
+                {'tokens': [1, 2, 3], 'max_new_tokens': max_new,
+                 'start_index': start_index, 'stream': True}).encode(),
+                headers={'Content-Type': 'application/json',
+                         'Connection': 'close'})
+            resp = conn.getresponse()
+            lines = [json.loads(ln) for ln in
+                     resp.read().decode().strip().split('\n')]
+            conn.close()
+    finally:
+        eng.close()
+    want = _expected([1, 2, 3], max_new)
+    assert [ln['token'] for ln in lines[:-1]] == want
+    assert [ln['index'] for ln in lines[:-1]] \
+        == list(range(start_index, start_index + max_new))
+    assert lines[-1]['done'] and lines[-1]['tokens'] == want
+    chunks = [w for w in writes if b'"token"' in w or b'"done"' in w]
+    assert len(chunks) == max_new + 1
+    for chunk in chunks:
+        size, rest = chunk.split(b'\r\n', 1)
+        assert rest.endswith(b'\n\r\n') and int(size, 16) == len(rest) - 2
+        # a token's line is what json.dumps writes for it, keys sorted
+        assert rest[:-2].decode() == json.dumps(json.loads(rest),
+                                                sort_keys=True) + '\n'
+
+
 def test_engine_degraded_fallback_runs_off_worker_thread():
     """A breaker trip must not serialize the (slow) CPU fallback into
     the scheduler loop: while a degraded completion is still running,

@@ -22,6 +22,9 @@ one math path shared by prefill, step, and the uncached reference so
 cached decode is bit-identical to the whole-sequence forward),
 ``cohere2`` (the ``cohere2_moe`` family: parallel attention + expert
 block, sliding-window and full layers in one paged cache manager),
+``granite`` (the ``granitemoehybrid`` family: Mamba-2 layers whose
+recurrent state sits in slot entries beside an attention layer's
+pages), ``blocks`` (the held-expert layer and the attention both share),
 ``program`` (AOT compile + frozen.v1 persistence + CPU fallback),
 ``engine`` (continuous batching, admission control, breaker/watchdog
 at site ``serving.decode``).
@@ -32,6 +35,7 @@ from .cache import CacheSpec, cache_bytes, init_cache, write_position, \
     write_slot
 from .cohere2 import Cohere2MoELM, init_cohere2_moe_lm
 from .engine import DecodeEngine, DrainTimeout, GenerateStream
+from .granite import GraniteHybridLM, init_granite_hybrid_lm
 from .model import (DecodeModel, FamilyUnsupported, RNNLM, TransformerLM,
                     from_gluon_rnn_lm, init_rnn_lm, init_transformer_lm,
                     model_from_config)
@@ -45,7 +49,8 @@ __all__ = [
     'CacheSpec', 'cache_bytes', 'init_cache', 'write_position',
     'write_slot', 'DecodeEngine', 'DrainTimeout', 'GenerateStream',
     'DecodeModel', 'RNNLM', 'TransformerLM', 'Cohere2MoELM',
-    'init_cohere2_moe_lm', 'FamilyUnsupported', 'from_gluon_rnn_lm',
+    'init_cohere2_moe_lm', 'GraniteHybridLM', 'init_granite_hybrid_lm',
+    'FamilyUnsupported', 'from_gluon_rnn_lm',
     'init_rnn_lm', 'init_transformer_lm', 'model_from_config',
     'DecodeProgram', 'PagedDecodeProgram', 'PageAllocator',
     'PagedCacheSpec', 'PageOwner', 'PrefixCache', 'pool_bytes',

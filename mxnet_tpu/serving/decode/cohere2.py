@@ -26,17 +26,11 @@ One block, input ``x`` (hidden wide), layer ``l``::
 are held or not, and adds only what its own experts give. Nothing stands
 in for the absent chips.
 
-**The expert layer.** Static shapes, no capacity, nothing dropped. A
-prefill sorts its assignments by expert and takes a block of rows of
-every held expert to a pass (``_expert_block``: what an even router
-sends one expert and four standard deviations of it), one batched
-product a pass, in a loop of as many passes as the fullest expert
-needs: a pass reads each held expert's weights once, and the work
-follows the fullest expert's count, not experts x tokens. The decode
-step multiplies every slot through every held expert and weights the
-unselected ones by zero: at a handful of tokens an expert the step
-reads each held expert's weights once either way, and the dense product
-needs no sort, no gather and no loop.
+**The expert layer** and the attention over a whole sequence and
+over gathered rows are ``blocks.py``'s, shared with the other family
+that holds a share of its experts: this module routes (sigmoid
+scores, weights normalised over the selected) and hands the layer
+its gate weights.
 
 **Precision.** Parameters and cache in ``dtype`` (bfloat16 as served),
 matrix products with ``dtype`` operands and float32 accumulation;
@@ -52,6 +46,7 @@ from __future__ import annotations
 
 import numpy as onp
 
+from . import blocks
 from .model import _FAMILIES, DecodeModel, FamilyUnsupported
 from .paged import (PagedCacheSpec, gather_pages, ring_key_positions,
                     scatter_pages, scatter_rows)
@@ -115,17 +110,9 @@ class Cohere2MoELM(DecodeModel):
                              % (self.heads, self.kv_heads))
         if self.head_dim % 2:
             raise ValueError('interleaved RoPE needs an even head_dim')
-        if not self.held or len(set(self.held)) != len(self.held) \
-                or not all(0 <= e < self.experts for e in self.held):
-            raise ValueError('held_experts must be distinct ids below '
-                             '%d, got %r' % (self.experts, self.held))
-        if self.top_k > self.experts:
-            raise ValueError('top_k %d > experts %d'
-                             % (self.top_k, self.experts))
-        # expert id -> index among the held ones, -1 where absent
-        local = onp.full(self.experts, -1, 'int32')
-        local[self.held] = onp.arange(len(self.held), dtype='int32')
-        self._local_of = local
+        self._experts = blocks.HeldExperts(
+            self.experts, self.held, self.top_k, self.hidden, self.dtype)
+        self._local_of = self._experts.local_of
 
     # -- what this family does not implement --------------------------------
 
@@ -166,9 +153,7 @@ class Cohere2MoELM(DecodeModel):
 
     def _mm(self, spec, a, b):
         """Matrix product with ``dtype`` operands, float32 result."""
-        import jax.numpy as jnp
-        return jnp.einsum(spec, a.astype(self.dtype), b,
-                          preferred_element_type='float32')
+        return blocks.mm(spec, a, b, self.dtype)
 
     def _rope(self, x, positions):
         """Interleaved rotary embedding over all of head_dim: pair
@@ -202,12 +187,6 @@ class Cohere2MoELM(DecodeModel):
         return q.astype(self.dtype), k.astype(self.dtype), \
             v.astype(self.dtype)
 
-    @staticmethod
-    def _softmax(scores):
-        import jax.numpy as jnp
-        e = jnp.exp(scores - jnp.max(scores, axis=-1, keepdims=True))
-        return e / jnp.sum(e, axis=-1, keepdims=True)
-
     def _route(self, p, n):
         """Scores over all experts (float32, highest precision), the
         ``top_k`` largest and their weights normalised over all of
@@ -220,149 +199,41 @@ class Cohere2MoELM(DecodeModel):
         top_s, top_i = jax.lax.top_k(jax.nn.sigmoid(logits), self.top_k)
         return top_s / jnp.sum(top_s, axis=-1, keepdims=True), top_i
 
-    def _ffn(self, spec_in, spec_out, n, w1, w3, w2):
-        import jax
-        h = jax.nn.silu(self._mm(spec_in, n, w1)) \
-            * self._mm(spec_in, n, w3)
-        return self._mm(spec_out, h, w2)
-
     def _shared(self, p, n):
         import jax
         with jax.named_scope('shared'):
-            return self._ffn('th,jhf->jtf', 'jtf,jfh->th', n, p('s1'),
-                             p('s3'), p('s2')) * (1.0 / self.shared)
+            return blocks.gated_ffn(
+                'th,jhf->jtf', 'jtf,jfh->th', n, p('s1'), p('s3'),
+                p('s2'), self.dtype) * (1.0 / self.shared)
 
     def _moe_dense(self, p, n, live):
-        """The decode step's expert layer: every row through every held
-        expert, weighted by its routing weight or by zero. ``live``
-        (T,) marks the rows that are sequences. Returns (routed (T, H),
-        per-expert counts over live rows (held,))."""
+        """The decode step's expert layer (``blocks.HeldExperts.dense``)
+        under this family's router."""
         import jax
-        import jax.numpy as jnp
         with jax.named_scope('router'):
             w, top_i = self._route(p, n)
-            hit = top_i[:, :, None] == jnp.asarray(self.held)[None, None]
-            wh = jnp.sum(jnp.where(hit, w[:, :, None], 0.0), axis=1)
-            counts = jnp.sum(hit & live[:, None, None], axis=(0, 1))
-        with jax.named_scope('experts'):
-            y = self._ffn('th,ehf->etf', 'etf,efh->eth', n, p('w1'),
-                          p('w3'), p('w2'))
-            return jnp.einsum('eth,te->th', y, wh), \
-                counts.astype('int32')
+        return self._experts.dense(n, w, top_i, live, p('w1'), p('w3'),
+                                   p('w2'))
 
     def _expert_block(self, s):
-        """Rows a held expert computes in one pass of a prefill of
-        ``s`` tokens: what a router that spreads its choices evenly
-        sends it (``s * top_k / experts``) and four standard deviations
-        of that count, in whole tiles of 16 rows. A router that sends
-        one expert more than this costs more passes, never a token."""
-        mean = s * self.top_k / self.experts
-        return int(-(-(mean + 4.0 * mean ** 0.5) // 16) * 16)
+        return self._experts.block(s)
 
     def _moe_grouped(self, p, n, length):
-        """A prefill's expert layer: the assignments that landed on a
-        held expert, sorted by expert, ``_expert_block`` rows of every
-        held expert to a pass, in as many passes as the fullest expert
-        needs: one batched product a pass reads each held expert's
-        weights once. Rows at or past ``length`` are padding and are
-        routed nowhere. Returns (routed (S, H), counts (held,))."""
+        """A prefill's expert layer (``blocks.HeldExperts.grouped``)
+        under this family's router."""
         import jax
-        import jax.numpy as jnp
-        from jax import lax
-        s, k, eh = n.shape[0], self.top_k, len(self.held)
-        m, cb = s * k, self._expert_block(s)
         with jax.named_scope('router'):
             w, top_i = self._route(p, n)
-            local = jnp.asarray(self._local_of)[top_i]       # (S, K)
-            real = (jnp.arange(s) < length)[:, None]
-            local = jnp.where(real, local, -1).reshape(m)
-            here = local >= 0
-            order = jnp.argsort(jnp.where(here, local, eh), stable=True)
-            counts = jnp.sum(local[:, None] == jnp.arange(eh)[None],
-                             axis=0).astype('int32')
-            starts = jnp.cumsum(counts) - counts
-            token_at = (order // k).astype('int32')
-            # each (token, choice)'s place among its expert's rows
-            rank = jnp.zeros(m, 'int32').at[order].set(
-                jnp.arange(m, dtype='int32')) \
-                - starts[jnp.maximum(local, 0)]
-        with jax.named_scope('experts'):
-            nb = n.astype(self.dtype)
-            lane = jnp.arange(cb, dtype='int32')[None]
-
-            def one_pass(j, acc):
-                at = jnp.minimum(starts[:, None] + j * cb + lane, m - 1)
-                y = self._ffn('ech,ehf->ecf', 'ecf,efh->ech',
-                              nb[token_at[at]], p('w1'), p('w3'),
-                              p('w2')).astype(self.dtype)
-                # a lane past its expert's count computed some other
-                # expert's row: nothing picks it. What was routed
-                # elsewhere, or comes in another pass, picks the zero row
-                y = jnp.concatenate([y.reshape(eh * cb, self.hidden),
-                                     jnp.zeros((1, self.hidden), y.dtype)])
-                pick = jnp.where(here & (rank // cb == j),
-                                 local * cb + rank % cb, eh * cb)
-                return acc + jnp.einsum(
-                    'skh,sk->sh',
-                    y[pick.reshape(s, k)].astype('float32'), w)
-
-            return lax.fori_loop(
-                0, (jnp.max(counts) + cb - 1) // cb, one_pass,
-                jnp.zeros((s, self.hidden), 'float32')), counts
+        return self._experts.grouped(n, w, top_i, length, p('w1'),
+                                     p('w3'), p('w2'))
 
     def _attend_blocks(self, q, k, v, sliding):
-        """Causal attention of one whole sequence, a block of
-        ``prefill_block`` queries at a time: q (S, kv_heads, group, d),
-        k / v (S, kv_heads, d) -> (S, heads * d) float32. No (S, S)
-        score tensor: a block scores against all S keys on a full
-        layer, against the ``window + block`` keys that can be visible
-        to it on a sliding layer."""
-        import jax.numpy as jnp
-        from jax import lax
-        s = q.shape[0]
-        blk = min(self.prefill_block, s)
-        nblk = -(-s // blk)
-        sp = nblk * blk
-        q = jnp.pad(q, ((0, sp - s),) + ((0, 0),) * 3)
-        k = jnp.pad(k, ((0, sp - s), (0, 0), (0, 0)))
-        v = jnp.pad(v, ((0, sp - s), (0, 0), (0, 0)))
-        span = min(sp, self.window + blk) if sliding else sp
-
-        def one_block(i):
-            qb = lax.dynamic_slice_in_dim(q, i * blk, blk, 0)
-            start = jnp.clip((i + 1) * blk - span, 0, sp - span)
-            kb = lax.dynamic_slice_in_dim(k, start, span, 0)
-            vb = lax.dynamic_slice_in_dim(v, start, span, 0)
-            qpos = i * blk + jnp.arange(blk)[:, None]
-            kpos = start + jnp.arange(span)[None, :]
-            seen = kpos <= qpos
-            if sliding:
-                seen &= qpos - kpos < self.window
-            scores = jnp.einsum('qkgd,lkd->kgql', qb, kb,
-                                preferred_element_type='float32') \
-                + jnp.where(seen, 0.0, -1e9)[None, None]
-            att = self._softmax(scores).astype(self.dtype)
-            return jnp.einsum('kgql,lkd->qkgd', att, vb,
-                              preferred_element_type='float32')
-
-        ctx = lax.map(one_block, jnp.arange(nblk))
-        return ctx.reshape(sp, self.heads * self.head_dim)[:s]
+        return blocks.attend_blocks(q, k, v, self.prefill_block,
+                                    self.window if sliding else None,
+                                    self.dtype)
 
     def _attend_rows(self, q, keys, values, seen):
-        """One query a slot over the rows its table gathered: q (S,
-        kv_heads, group, d), keys / values (S, L, kv_heads * d), seen
-        (S, L) bool -> (S, heads * d) float32."""
-        import jax.numpy as jnp
-        s, length = keys.shape[:2]
-        kh = keys.reshape(s, length, self.kv_heads, self.head_dim)
-        vh = values.reshape(s, length, self.kv_heads, self.head_dim)
-        scores = jnp.einsum('skgd,slkd->skgl', q, kh,
-                            preferred_element_type='float32') \
-            + jnp.where(seen, 0.0, -1e9)[:, None, None, :]
-        att = self._softmax(scores).astype(self.dtype)
-        return jnp.einsum('skgl,slkd->skgd', att, vh,
-                          preferred_element_type='float32').reshape(
-                              s, self.heads * self.head_dim)
+        return blocks.attend_rows(q, keys, values, seen, self.dtype)
 
     def _embed(self, params, tokens):
         import jax

@@ -403,6 +403,11 @@ class DecodeEngine:
             getattr(program, 'model', None), 'step_stats', ()))
         for name in self._step_stats:
             self._counts[name] = 0
+        # and what a prefill counts on the host (program.
+        # last_prefill_stats)
+        for name in getattr(getattr(program, 'model', None),
+                            'prefill_stats', ()):
+            self._counts[name] = 0
         # speculative decoding: draft proposes spec_k tokens per tick,
         # the target verifies them in one batched call
         self._draft = None
@@ -543,7 +548,7 @@ class DecodeEngine:
         if not prompt:
             raise ValueError('empty prompt')
         if prefill_only and self.paged:
-            self.program._no_window('prefill_only admission (its '
+            self.program._one_page_list('prefill_only admission (its '
                                     'seqstate export)')
         if len(prompt) > self.program.max_prompt_len():
             raise ValueError(
@@ -1155,6 +1160,11 @@ class DecodeEngine:
             self.program.run_prefill, self._cache,
             onp.asarray(prompt, 'int32'), ids,
             **self._prefill_extras(seq))
+        booked = getattr(self.program, 'last_prefill_stats', None)
+        if booked:
+            with self._lock:
+                for name, v in booked.items():
+                    self._counts[name] += v
         if self._draft is not None:
             self._draft_cache, _dt, _dl = self._device(
                 self._draft.run_prefill, self._draft_cache,
@@ -1759,7 +1769,7 @@ class DecodeEngine:
         slot/pages are available, :class:`BatcherClosed` after
         :meth:`close`."""
         if self.paged:
-            self.program._no_window('import_sequence')
+            self.program._one_page_list('import_sequence')
         state = decode_payload(payload)
         state['trace'] = trace
         # a pinned adapter must land in an engine that can CONTINUE

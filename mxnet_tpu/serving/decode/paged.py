@@ -58,6 +58,7 @@ from ...observability.spans import span as _span
 
 __all__ = ['PagedCacheSpec', 'PageAllocator', 'PrefixCache', 'PageOwner',
            'SeqPages', 'TRASH_PAGE', 'init_pool', 'pool_avals', 'pool_bytes',
+           'slot_state_bytes',
            'gather_pages', 'write_prefill_pages', 'copy_page', 'pages_for',
            'scatter_rows', 'scatter_pages', 'ring_key_positions',
            'window_table_pages']
@@ -97,13 +98,25 @@ class PagedCacheSpec:
     goes back to their allocator. A spec without window entries
     (``window_pages == 0``) is the one-kind case: one pool size, one
     table, nothing else changes.
+
+    A third kind of entry is no page at all: ``slot_entries``, ``{name:
+    (shape, dtype)}``, are recurrent state of fixed size a sequence
+    (a state-space layer's carried state and its convolution's last
+    inputs). A table for them would be the identity, so they have none:
+    the array is ``(slots,) + shape`` in the same donated pytree, row
+    ``s`` belongs to slot ``s``, a prefill rewrites its slot's row
+    whole and the step updates the rows of the slots that step. No
+    allocator hands them out and nothing is registered for them; a
+    cache that has them shares no prefix (:class:`PageOwner`): K/V
+    pages found by tokens without the state at that boundary would
+    decode wrongly.
     """
 
     __slots__ = ('entries', 'page_size', 'max_pages', 'window',
-                 'window_pages', 'window_entries')
+                 'window_pages', 'window_entries', 'slot_entries')
 
     def __init__(self, entries, page_size, max_len, window=None,
-                 window_entries=()):
+                 window_entries=(), slot_entries=None):
         self.page_size = int(page_size)
         if self.page_size < 1 or (self.page_size
                                   & (self.page_size - 1)):
@@ -121,8 +134,16 @@ class PagedCacheSpec:
         self.window_pages = window_table_pages(
             self.window, self.page_size, max_len) \
             if self.window_entries else 0
+        self.slot_entries = {
+            str(k): (tuple(int(d) for d in shape), str(dt))
+            for k, (shape, dt) in dict(slot_entries or {}).items()}
+        if set(self.slot_entries) & set(self.entries):
+            raise ValueError('entries %r are both paged and a slot\'s'
+                             % sorted(set(self.slot_entries)
+                                      & set(self.entries)))
 
     def items(self):
+        """The paged entries (:attr:`slot_entries` are apart)."""
         return self.entries.items()
 
     def kinds(self):
@@ -152,6 +173,9 @@ class PagedCacheSpec:
         if self.window_entries:
             out['window'] = self.window
             out['window_entries'] = sorted(self.window_entries)
+        if self.slot_entries:
+            out['slot_entries'] = {k: [list(s), dt] for k, (s, dt)
+                                   in self.slot_entries.items()}
         return out
 
     @classmethod
@@ -161,20 +185,31 @@ class PagedCacheSpec:
         return cls(entries, obj['page_size'],
                    obj['max_pages'] * obj['page_size'],
                    window=obj.get('window'),
-                   window_entries=obj.get('window_entries', ()))
+                   window_entries=obj.get('window_entries', ()),
+                   slot_entries={k: (tuple(s), dt) for k, (s, dt) in
+                                 obj.get('slot_entries', {}).items()})
 
     def __repr__(self):
         return ('PagedCacheSpec(page_size=%d, max_pages=%d, %r)'
                 % (self.page_size, self.max_pages, self.entries))
 
 
-def pool_bytes(spec, pages, window_pool=0):
+def slot_state_bytes(spec, slots=1):
+    """Bytes of ``spec``'s slot entries for ``slots`` sequences (0
+    where it has none): what a sequence costs whatever its length."""
+    total = 0
+    for shape, dt in spec.slot_entries.values():
+        total += int(onp.prod(shape, dtype='int64')) * _itemsize(dt)
+    return total * int(slots)
+
+
+def pool_bytes(spec, pages, window_pool=0, slots=0):
     """Static pool footprint in bytes for ``pages`` pages — the REAL
     device residency of the paged cache (the slot cache's
     ``slots × max_len`` figure this replaces reserved worst case per
     sequence whether it was used or not). Window layers' entries
-    count ``window_pool`` pages each."""
-    total = 0
+    count ``window_pool`` pages each, slot entries ``slots`` rows."""
+    total = slot_state_bytes(spec, slots)
     for name, (shape, dt) in spec.items():
         n = int(spec.pages_of(name, pages, window_pool)) * spec.page_size
         for d in shape:
@@ -189,22 +224,29 @@ def _itemsize(dt):
     return onp.dtype(dt).itemsize
 
 
-def init_pool(spec, pages, window_pool=0):
+def _pool_tree(spec, pages, window_pool, slots, leaf):
+    out = {name: leaf(spec.full_shape(
+               name, spec.pages_of(name, pages, window_pool)), dt)
+           for name, (_, dt) in spec.items()}
+    out.update({name: leaf((int(slots),) + shape, dt)
+                for name, (shape, dt) in spec.slot_entries.items()})
+    return out
+
+
+def init_pool(spec, pages, window_pool=0, slots=0):
     """Preallocated zeros pool pytree ``{name: (pages, page_size,
     *row_shape)}`` — zeros so stale rows stay finite under the
-    attention mask (cache.py's argument)."""
+    attention mask (cache.py's argument) — and, for a spec with slot
+    entries, ``{name: (slots, *shape)}`` beside them."""
     import jax.numpy as jnp
-    return {name: jnp.zeros(spec.full_shape(
-                name, spec.pages_of(name, pages, window_pool)), dt)
-            for name, (_, dt) in spec.items()}
+    return _pool_tree(spec, pages, window_pool, slots, jnp.zeros)
 
 
-def pool_avals(spec, pages, window_pool=0):
+def pool_avals(spec, pages, window_pool=0, slots=0):
     """ShapeDtypeStructs for AOT lowering (freeze.py idiom)."""
     import jax
-    return {name: jax.ShapeDtypeStruct(spec.full_shape(
-                name, spec.pages_of(name, pages, window_pool)), dt)
-            for name, (_, dt) in spec.items()}
+    return _pool_tree(spec, pages, window_pool, slots,
+                      jax.ShapeDtypeStruct)
 
 
 # ---------------------------------------------------------------------------
@@ -660,12 +702,24 @@ class PageOwner:
     ``window_pages_released`` are booked here and nowhere else.
     ``event(kind, **fields)`` takes the flight recorder's
     ``page_alloc`` and ``page_evict``. Worker thread only, but for the
-    readers and :meth:`drop`."""
+    readers and :meth:`drop`.
+
+    Slot entries (recurrent state, :class:`PagedCacheSpec`) are
+    accounted for here and allocated nowhere: a sequence that was
+    placed holds its slot's rows until it is dropped, :meth:`place`
+    names the slot beside the page ids for the prefill that rewrites
+    them, the step needs no table for them, and their bytes count in
+    :meth:`held_bytes` and in the gauge ``state_bytes_live``. With
+    slot entries no prefix is registered or shared, whatever
+    ``prefix_cache`` asks for: ``prefix_hits`` stays 0."""
 
     def __init__(self, spec, pool_pages, lock, prefix_cache, counts,
                  event=None):
         self.page_size = spec.page_size
         self._spec = spec
+        prefix_cache = bool(prefix_cache) and not spec.slot_entries
+        self._state_bytes = slot_state_bytes(spec)
+        self._stateful = set()        # slots whose state rows are live
         self._lock = lock
         self._counts = counts
         self._event = event or (lambda kind, **fields: None)
@@ -684,10 +738,11 @@ class PageOwner:
         if self._rings:
             counts.setdefault('window_pages_released', 0)
 
-    def _out(self, by_kind):
+    @staticmethod
+    def _out(by_kind):
         """``by_kind`` as the compiled programs take it."""
-        if len(self._kinds) == 1:
-            return by_kind[self._kinds[0].name]
+        if len(by_kind) == 1:
+            return next(iter(by_kind.values()))
         return by_kind
 
     # -- a sequence's life -------------------------------------------------
@@ -739,8 +794,10 @@ class PageOwner:
         ring keeps the last ``columns`` pages only: what lies behind
         it is never written. Registered prefixes are evicted, least
         recently used first, under pool pressure. Returns the page ids
-        of each logical page (the trash page for those behind a ring),
-        or None on exhaustion with nothing left held."""
+        of each logical page (the trash page for those behind a ring)
+        and, where the cache has slot entries, ``'slot'``: the row the
+        prefill rewrites; or None on exhaustion with nothing left
+        held."""
         npages = pages_for(n_tokens, self.page_size)
         out = {}
         for k in self._kinds:
@@ -760,6 +817,10 @@ class PageOwner:
             else:
                 table[:npages] = ids
             out[k.name] = [TRASH_PAGE] * behind + ids
+        if self._state_bytes:
+            out['slot'] = rec.slot
+            with self._lock:
+                self._stateful.add(rec.slot)
         return self._out(out)
 
     def register(self, prompt, ids, namespace=None):
@@ -877,6 +938,7 @@ class PageOwner:
             for page in held:
                 k.allocator.release(page)
             rec.held[k.name] = []
+        self._stateful.discard(rec.slot)
 
     def reset(self):
         """The device pools were rebuilt: free lists, reference counts
@@ -887,6 +949,7 @@ class PageOwner:
                 k.allocator.reset()
                 if k.prefix is not None:
                     k.prefix.clear()
+            self._stateful.clear()
 
     def _alloc(self, k, n, slot):
         """``n`` fresh pages of kind ``k``, evicting its least recently
@@ -953,19 +1016,27 @@ class PageOwner:
 
     def live_gauges(self):
         """Pages in use by kind of layer, sequences' holds and the
-        registry's alike (gauges, not sums); nothing where there is
-        one kind."""
-        if len(self._kinds) == 1:
-            return {}
-        return {'pages_live.%s' % k.name: k.allocator.used_pages
-                for k in self._kinds}
+        registry's alike (gauges, not sums), where there is more than
+        one kind; ``state_bytes_live``, the slot entries' bytes of the
+        sequences in flight, where the cache has slot entries."""
+        out = {}
+        if len(self._kinds) > 1:
+            out = {'pages_live.%s' % k.name: k.allocator.used_pages
+                   for k in self._kinds}
+        if self._state_bytes:
+            out['state_bytes_live'] = \
+                len(self._stateful) * self._state_bytes
+        return out
 
     def held_bytes(self, recs):
         """Device bytes behind the holds of ``recs``: a page of the
         window layers and a page of the full layers hold different
-        bytes."""
+        bytes, and a sequence in flight holds its slot's state rows."""
         held = dict.fromkeys((k.name for k in self._kinds), 0)
+        live = 0
         for rec in recs:
+            live += 1
             for name, pages in rec.held.items():
                 held[name] += len(pages)
-        return pool_bytes(self._spec, held['full'], held.get('window', 0))
+        return pool_bytes(self._spec, held['full'], held.get('window', 0),
+                          live)

@@ -48,7 +48,7 @@ from .cache import cache_avals, cache_bytes, init_cache
 from .model import (DecodeModel, FamilyUnsupported, from_gluon_rnn_lm,
                     model_from_config)
 from .paged import (TRASH_PAGE, init_pool, pages_for, pool_avals,
-                    pool_bytes, write_prefill_pages)
+                    pool_bytes, slot_state_bytes, write_prefill_pages)
 from . import paged as _paged
 
 # per thread: ``hook``, a callable that :meth:`DecodeProgram._call` runs
@@ -738,7 +738,11 @@ class PagedDecodeProgram(DecodeProgram):
     compiles and runs fixed shapes — page churn costs zero retraces.
     Page ids and tables arrive as the owner hands them out: bare where
     the model has one kind of layer, ``{'full': ..., 'window': ...}``
-    where it has two (:meth:`_by_kind`).
+    where it has two (:meth:`_by_kind`). A model with recurrent state
+    (``page_spec.slot_entries``) keeps it in ``(slots, ...)`` arrays of
+    the same donated pytree: a prefill's page ids then carry
+    ``'slot'``, the row it rewrites, and the step takes no table for
+    them.
     """
 
     paged = True
@@ -771,6 +775,9 @@ class PagedDecodeProgram(DecodeProgram):
             if self.window_pages else 0
         self._n_stats = len(getattr(model, 'step_stats', ()))
         self.last_step_stats = {}
+        # host-side counts of the last prefill (model.prefill_counts:
+        # a function of its bucket), for the scheduler to book
+        self.last_prefill_stats = {}
         if pages is None:
             # default pool = the slot cache's worst-case capacity
             # (every slot filling max_len) + the trash page; shrink it
@@ -785,17 +792,20 @@ class PagedDecodeProgram(DecodeProgram):
                           else _knob('MXNET_TPU_SERVE_SPEC_K', 0))
         if self.spec_k < 0:
             raise ValueError('spec_k must be >= 0')
-        if self.spec_k and self.window_pages:
+        if self.spec_k and (self.window_pages
+                            or self.page_spec.slot_entries):
             # at freeze time, not at the first verify call
             raise FamilyUnsupported(
                 model.family, 'paged_verify (speculative decoding, '
-                'spec_k > 0) over a window layer\'s ring of pages')
+                'spec_k > 0) over a window layer\'s ring of pages or a '
+                'recurrent state, which a rejected token cannot leave')
 
     # -- accounting (the satellite fix: report POOL bytes, not the
     # slots × max_len worst case the slot cache reserved) ------------------
 
     def cache_bytes(self):
-        return pool_bytes(self.page_spec, self.pages, self.window_pool_pages)
+        return pool_bytes(self.page_spec, self.pages,
+                          self.window_pool_pages, self.slots)
 
     def page_bytes(self):
         """Bytes one page holds across every cache entry."""
@@ -805,20 +815,22 @@ class PagedDecodeProgram(DecodeProgram):
         """Amortized cache bytes for a sequence of ``seq_len`` tokens
         (default: the worst case, max_len): pages are the granularity,
         so a 12-token sequence at page_size 16 holds ONE page, not
-        max_len rows. A window layer never holds more than its ring.
+        max_len rows. A window layer never holds more than its ring,
+        and recurrent state costs the same at every length.
         """
         n = self.model.max_len if seq_len is None else int(seq_len)
         held = pages_for(n, self.page_size)
         return pool_bytes(self.page_spec, held,
-                          min(held, self.window_pages))
+                          min(held, self.window_pages), 1)
 
     def new_cache(self):
-        """Fresh zeroed page pool."""
-        return init_pool(self.page_spec, self.pages, self.window_pool_pages)
+        """Fresh zeroed page pool (and slot state)."""
+        return init_pool(self.page_spec, self.pages,
+                         self.window_pool_pages, self.slots)
 
     def _cache_avals(self):
         return pool_avals(self.page_spec, self.pages,
-                          self.window_pool_pages)
+                          self.window_pool_pages, self.slots)
 
     def _manifest_extra(self):
         out = {'paged': True, 'page_size': self.page_size,
@@ -828,6 +840,8 @@ class PagedDecodeProgram(DecodeProgram):
         if self.window_pages:
             out.update(window_pages=self.window_pages,
                        window_pool_pages=self.window_pool_pages)
+        if self.page_spec.slot_entries:
+            out['state_bytes_per_slot'] = slot_state_bytes(self.page_spec)
         return out
 
     @property
@@ -839,18 +853,27 @@ class PagedDecodeProgram(DecodeProgram):
             out['window'] = self.window_pool_pages
         return out
 
-    def _by_kind(self, full, window):
+    def _by_kind(self, full, window, slot=None):
         """Avals of page ids or tables as the programs take them: the
         full layers' alone where the model has one kind of layer,
-        ``{'full': ..., 'window': ...}`` where it has two."""
-        return {'full': full, 'window': window} if self.window_pages \
-            else full
-
-    def _each_kind(self, arg, fn):
-        """``fn`` over page ids or tables in that form, as a
-        ``PageOwner`` hands them out."""
+        ``{'full': ..., 'window': ...}`` where it has two; a prefill's
+        page ids also name the ``slot`` whose state it rewrites, where
+        the cache has slot entries."""
+        out = {'full': full}
         if self.window_pages:
-            return {'full': fn(arg['full']), 'window': fn(arg['window'])}
+            out['window'] = window
+        if slot is not None and self.page_spec.slot_entries:
+            out['slot'] = slot
+        return out if len(out) > 1 else full
+
+    @staticmethod
+    def _each_kind(arg, fn):
+        """``fn`` over page ids or tables in that form, as a
+        ``PageOwner`` hands them out (a ``slot`` beside them is a
+        scalar)."""
+        if isinstance(arg, dict):
+            return {k: onp.int32(v) if k == 'slot' else fn(v)
+                    for k, v in arg.items()}
         return fn(arg)
 
     # -- program construction ----------------------------------------------
@@ -926,12 +949,14 @@ class PagedDecodeProgram(DecodeProgram):
         counts = self.trace_counts
 
         windowed = self.page_spec.window_entries
+        paged = self.page_spec.entries    # slot entries have no pages
 
         def fn(params, pool, src, dst):
             counts[key] = counts.get(key, 0) + 1
             del params
             if not windowed:
                 return {name: _paged.copy_page(arr, src, dst)
+                        if name in paged else arr
                         for name, arr in pool.items()}
             # each kind of layer copies within its own pools
             kind = {name: 'window' if name in windowed else 'full'
@@ -950,7 +975,9 @@ class PagedDecodeProgram(DecodeProgram):
                            self._paged_prefill_fn(key),
                            jax.ShapeDtypeStruct((1, bucket), 'int32'),
                            jax.ShapeDtypeStruct((), 'int32'),
-                           self._by_kind(ids, ids),
+                           self._by_kind(
+                               ids, ids,
+                               jax.ShapeDtypeStruct((), 'int32')),
                            self._extra_avals('prefill'))
 
     def compile_step(self):
@@ -1007,7 +1034,8 @@ class PagedDecodeProgram(DecodeProgram):
         host-allocated ``page_ids``: a list, one id a prompt page
         (padded here with the trash page to the bucket's page count),
         or such a list for each kind of layer, where a window layer's
-        holds the trash page for each page already behind the window.
+        holds the trash page for each page already behind the window
+        and ``'slot'`` names the row of the slot entries it rewrites.
         Returns (pool', first_token, logits | None)."""
         tokens = onp.asarray(tokens, 'int32').reshape(-1)
         n = tokens.shape[0]
@@ -1032,6 +1060,9 @@ class PagedDecodeProgram(DecodeProgram):
             self._each_kind(page_ids, padded_ids),
             self._extra_args('prefill', temps, top_ps, keys, masks,
                              apool, aidx))
+        counts = getattr(self.model, 'prefill_counts', None)
+        if counts is not None:
+            self.last_prefill_stats = counts(bucket)
         return pool, int(tok), logits
 
     def _tables_arg(self, tables):
@@ -1090,11 +1121,14 @@ class PagedDecodeProgram(DecodeProgram):
 
     # -- live migration (seqstate export/import) ----------------------------
 
-    def _no_window(self, what):
-        if self.window_pages:
+    def _one_page_list(self, what):
+        """Refuse ``what`` for a cache that is more than one page list
+        a sequence."""
+        if self.window_pages or self.page_spec.slot_entries:
             raise FamilyUnsupported(
                 self.model.family, '%s: the seqstate payload carries one '
-                'page list a sequence, not a ring beside it' % what)
+                'page list a sequence, not a ring or a recurrent state '
+                'beside it' % what)
 
     def export_pages(self, pool, page_ids):
         """Gather ``page_ids`` from the pool to host rows, keyed by
@@ -1103,7 +1137,7 @@ class PagedDecodeProgram(DecodeProgram):
         host, not the pool); migration is rare, so eager ops — the
         step program's zero-retrace contract is untouched."""
         import jax.numpy as jnp
-        self._no_window('export_pages (live migration)')
+        self._one_page_list('export_pages (live migration)')
         ids = onp.asarray(list(page_ids), 'int32')
         out = {}
         for name, arr in pool.items():
@@ -1120,7 +1154,7 @@ class PagedDecodeProgram(DecodeProgram):
         is exactly the pool's init state (additive masks keep unused
         rows inert). Returns the new pool."""
         import jax.numpy as jnp
-        self._no_window('import_pages (live migration)')
+        self._one_page_list('import_pages (live migration)')
         ids = onp.asarray(list(page_ids), 'int32')
         want = ids.shape[0] * self.page_size
         out = dict(pool)

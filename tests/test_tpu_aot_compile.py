@@ -1,4 +1,5 @@
-"""The decode step compiled for a described TPU v5e, at GPT-1's widths.
+"""Decode steps compiled for a described TPU v5e, at published widths:
+GPT-1's, and one Mamba-2 and one attention layer of Granite 4.0-H Small.
 
 Nothing runs and no chip is needed: the TPU's compiler is installed here
 and compiles for a chip that is described, not attached. What it shows is
@@ -7,6 +8,11 @@ view. PR 31 measured on the chip that a head split of the view
 (f32[128,512,12,64], minor dimension 64 in tiles of 128 lanes) costs a
 relayout of both views in every layer, 28 ms of a 60 ms step, and that a
 fill over the view hid another (PERF.md section 6).
+
+The Granite step shows what the recurrent state costs a step: every slot
+entry of the donated cache is updated in place (aliased input to output),
+one fusion reads the state, writes it and reduces it against C, and nothing
+else of the state's size is allocated.
 
 One file, one fixture, described inside the fixture: only one process may
 load the TPU's library (see the ``on-chip-measurement`` guide).
@@ -91,3 +97,88 @@ def test_the_append_is_one_scatter_a_pool(step_text):
     assert len(re.findall(r' scatter\(', step_text)) == 2 * LAYERS
     pool = r'\[%d,%d,%d\]' % (PAGES, PAGE, UNITS)
     assert not re.search(pool + r'\S* dynamic-update-slice\(', step_text)
+
+
+# ---------------------------------------------------------------------------
+# granitemoehybrid: recurrent state in slot entries of the donated cache
+# ---------------------------------------------------------------------------
+
+G_SLOTS, G_MAX_LEN = 64, 512
+G_STATE = (G_SLOTS, 128, 64, 128)                # float32, 268 MB
+
+
+@pytest.fixture(scope='module')
+def granite_step(one_chip):
+    """``GraniteHybridLM.paged_step`` at the published widths of one Mamba-2
+    layer and one attention layer (hidden 4096, 128 heads x 64 x state 128,
+    32 query heads on 8 KV heads of 128), 64 slots; two held experts and a
+    small vocabulary keep the compile to seconds."""
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache
+    from mxnet_tpu.serving.decode import GraniteHybridLM
+    from mxnet_tpu.serving.decode.paged import pool_avals
+    model = GraniteHybridLM(dict(
+        vocab=1024, max_len=G_MAX_LEN, hidden=4096,
+        layer_types=['mamba', 'attention'], eps=1e-5, head_dim=128,
+        heads=32, kv_heads=8, mamba_heads=128, mamba_head_dim=64,
+        mamba_state=128, mamba_conv=4, mamba_chunk=256, experts=72,
+        held_experts=[0, 1], top_k=10, expert_hidden=768,
+        shared_hidden=1536, embedding_multiplier=12.0,
+        residual_multiplier=0.22, attention_multiplier=0.0078125,
+        logits_scaling=16.0))
+
+    def on(tree):
+        return jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                           sharding=one_chip), tree)
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, 'int32', sharding=one_chip)
+
+    spec = model.paged_spec(PAGE)
+    params = on(jax.eval_shape(lambda: model.init_params(0)))
+    pool = on(pool_avals(spec, G_SLOTS * spec.max_pages + 1, 0, G_SLOTS))
+    cached = jax.config.jax_enable_compilation_cache
+    jax.config.update('jax_enable_compilation_cache', False)
+    compilation_cache.reset_cache()
+    try:
+        with jax.default_matmul_precision('default'):
+            compiled = jax.jit(model.paged_step, donate_argnums=(1,)).lower(
+                params, pool, i32(G_SLOTS), i32(G_SLOTS),
+                i32(G_SLOTS, spec.max_pages)).compile()
+        return spec, compiled.as_text(), compiled.memory_analysis()
+    finally:
+        jax.config.update('jax_enable_compilation_cache', cached)
+        compilation_cache.reset_cache()
+
+
+def test_every_cache_entry_of_the_granite_step_is_updated_in_place(
+        granite_step):
+    spec, text, _memory = granite_step
+    aliases = re.search(r'input_output_alias=\{(.*?)\}, entry', text).group(1)
+    # outputs 0..3 are the cache entries, in the pytree's (sorted) order
+    entries = sorted(list(spec.entries) + list(spec.slot_entries))
+    assert entries == ['l0_conv', 'l0_ssm', 'l1_k', 'l1_v']
+    assert len(re.findall(r'\{\d+\}: \(\d+, \{\}, may-alias\)', aliases)) \
+        == len(entries)
+
+
+def test_the_granite_step_makes_no_second_buffer_of_the_states_size(
+        granite_step):
+    _spec, text, memory = granite_step
+    state = 4
+    for d in G_STATE:
+        state *= d
+    assert memory.alias_size_in_bytes > state
+    # what the step allocates beside its operands (the gathered K/V views,
+    # the experts' products) is less than one state entry
+    assert memory.temp_size_in_bytes < state
+    # one fusion reads the state, writes it and reduces it against C; no
+    # copy of it is made
+    shape = ','.join(str(d) for d in G_STATE)
+    assert not [n for n in _results(text, shape) if n.startswith('copy')]
+    out = r'f32\[%s\]\S*' % re.escape(shape)
+    fused = re.findall(
+        r'^\s*%?[\w.\-]+ = \(f32\[' + re.escape(shape.rsplit(',', 1)[0])
+        + r'\]\S*, ' + out + r'\) fusion\(', text, re.M)
+    assert len(fused) == 1, fused

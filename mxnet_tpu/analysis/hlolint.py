@@ -24,7 +24,8 @@ reads — one shared instruction iterator,
     host transfer, guardrail idle or not (docs/GUARDRAILS.md);
   * ``paged_decode`` — the paged decode-step contract
     (docs/SERVING.md "Paged KV cache"): the per-slot K/V view must
-    read through the page table (a gather must be present) and no
+    read through the page table (a gather, or on a TPU the paged
+    walk's kernel call, must be present) and no
     instruction may materialize an O(pool)-sized ``copy`` of the KV
     pool (``pool_bytes`` sets the threshold) — cache updates stay
     O(rows) writes in place on donated pool buffers.
@@ -182,12 +183,19 @@ def check(hlo_text, expect, program='program'):
         # must never be copied whole — a silent fallback to a dense
         # per-slot cache (or a partitioner materializing the pool)
         # would reintroduce the memory wall the layout removes
-        if not bases.get('gather') and not bases.get('dynamic-gather'):
+        # (placed on a TPU the one-token step reads through the table
+        # inside the walk's kernel, which takes the tables as operands)
+        from ..ops.pallas.costs import PAGED_WALK_TAG
+        walks = any(PAGED_WALK_TAG in i.line
+                    for i in bases.get('custom-call', ()))
+        if not walks and not bases.get('gather') \
+                and not bases.get('dynamic-gather'):
             findings.append(_finding(
                 'HLO-DECODE-PAGED', program,
-                'paged decode-step program contains no gather — the '
-                'per-slot K/V view is not reading through the page '
-                'table (docs/SERVING.md "Paged KV cache")'))
+                'paged decode-step program contains no gather and no '
+                'page-table walk — the per-slot K/V history is not '
+                'read through the page table (docs/SERVING.md "Paged '
+                'KV cache")'))
         # the no-O(pool)-copy half is accelerator-only: XLA:CPU
         # ignores donation and lowers the in-place row update as a
         # functional whole-buffer copy — exactly the traffic donation

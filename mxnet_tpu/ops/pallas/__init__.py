@@ -15,8 +15,11 @@ interpreter-mode CPU path — the NMS pattern: the same kernel logic is
 exercised everywhere, Mosaic-compiled only on TPU):
 
   * :mod:`.attention` — blockwise online-softmax flash attention
-    (never materializes the (S, S) scores matrix) + the single-token
-    decode variant that reads the slot KV cache in place;
+    (never materializes the (S, S) scores matrix), the single-token
+    decode variant that reads the slot KV cache in place, and the
+    paged decode walk that reads a sequence's live pages through its
+    page table (not behind the knob: the paged step runs it wherever
+    it is placed on a TPU);
   * :mod:`.epilogue` — fused normalize/activation/residual-add
     elementwise epilogues (BatchNorm apply, activation save-output
     cores, add+relu);
@@ -48,7 +51,7 @@ from __future__ import annotations
 
 __all__ = ['KINDS', 'parse_spec', 'resolve_spec', 'enabled',
            'interpret_mode', 'flash_attention', 'flash_decode_attention',
-           'flash_paged_decode_attention',
+           'flash_paged_decode_attention', 'paged_walk_fits',
            'online_softmax_block', 'fused_bn_apply', 'fused_act',
            'fused_add_act', 'fused_softmax_xent_rows', 'greedy_nms_keep',
            'selftest']
@@ -140,6 +143,7 @@ _LAZY_EXPORTS = {
     'flash_attention': '.attention',
     'flash_decode_attention': '.attention',
     'flash_paged_decode_attention': '.attention',
+    'paged_walk_fits': '.attention',
     'online_softmax_block': '.attention',
     'fused_bn_apply': '.epilogue',
     'fused_act': '.epilogue',

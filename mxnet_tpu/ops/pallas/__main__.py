@@ -20,7 +20,8 @@ Off-TPU they run through the Pallas interpreter at small shapes. On a
 selftest adds the shapes the two full-width models hit: BERT-base
 attention (S 128, 12 heads, d 64, bf16), softmax-xent over V = 30522,
 ResNet-50 BatchNorm/activation epilogues at batch 128, decode
-attention at units 768 / 12 heads / max_len 512, NMS at SSD-300's
+attention at units 768 / 12 heads / max_len 512 and, paged, at 32 query
+heads on 8 groups of 128 bfloat16 columns, NMS at SSD-300's
 8732 boxes. References are computed at ``highest`` matmul precision,
 and the tiers widen to what separately compiled TPU programs can
 promise (Mosaic and XLA round exp/log differently).
@@ -166,26 +167,45 @@ def run_selftest(out=None):
         assert err < RED, 'decode err %g' % err
         return {'shape': [slots, L, U], 'heads': H, 'err': err}
 
-    def check_paged_decode(slots, L, U, H, ps, pos):
+    def check_paged_decode(slots, L, U, H, ps, pos, dtype=f32, groups=None):
+        """The walk against gather + dense softmax; ``groups`` column
+        groups of the pool serve ``H`` query heads (``H`` of them, one
+        each, where not given)."""
+        groups = groups or H
+        D = U // H
+        W = groups * D
         pages = slots * (L // ps) + 1
-        kp, vp = randn(pages, ps, U), randn(pages, ps, U)
-        qd = randn(slots, U)
+        kp = randn(pages, ps, W, dtype=dtype)
+        vp = randn(pages, ps, W, dtype=dtype)
+        qd = randn(slots, U, dtype=dtype)
         pos = jnp.asarray(pos, 'int32')
         tables = jnp.asarray(1 + rs.permutation(pages - 1).reshape(
             slots, L // ps), 'int32')
         ctx = jax.jit(lambda q, k, v: flash_paged_decode_attention(
             q, k, v, tables, pos, heads=H))(qd, kp, vp)
-        ck = jnp.take(kp, tables, axis=0).reshape(slots, L, U)
-        cv = jnp.take(vp, tables, axis=0).reshape(slots, L, U)
-        err = amax(ctx, decode_ref(qd, ck, cv, pos, H))
-        assert err < RED, 'paged decode err %g' % err
-        return {'pool': [pages, ps, U], 'heads': H, 'err': err}
+        assert ctx.dtype == f32, ctx.dtype
+        # every query head beside its own group's columns
+        rep = H // groups
+        ck = jnp.repeat(jnp.take(kp, tables, axis=0).reshape(
+            slots, L, groups, 1, D), rep, 3).reshape(slots, L, U)
+        cv = jnp.repeat(jnp.take(vp, tables, axis=0).reshape(
+            slots, L, groups, 1, D), rep, 3).reshape(slots, L, U)
+        err = amax(ctx, decode_ref(qd.astype(f32), ck.astype(f32),
+                                   cv.astype(f32), pos, H))
+        tol = RED if dtype == f32 else BF16
+        assert err < tol, 'paged decode err %g' % err
+        return {'pool': [pages, ps, W], 'heads': H, 'groups': groups,
+                'dtype': jnp.dtype(dtype).name, 'err': err}
 
     _check('flash_decode_attention vs dense softmax',
            lambda: check_decode(3, 40, 32, 4, [5, 0, 39]),
            failures, results)
     _check('flash_paged_decode_attention vs dense softmax',
-           lambda: check_paged_decode(3, 48, 32, 4, 8, [5, 0, 47]),
+           lambda: check_paged_decode(3, 48, 128, 4, 8, [5, 0, 47]),
+           failures, results)
+    _check('flash_paged_decode_attention, grouped queries bf16',
+           lambda: check_paged_decode(3, 64, 512, 4, 16, [5, 0, 63],
+                                      dtype=jnp.bfloat16, groups=2),
            failures, results)
 
     def check_decode_token_streams():
@@ -374,6 +394,11 @@ def run_selftest(out=None):
                failures, results)
         _check('full width: flash_paged_decode_attention 768/12/512',
                lambda: check_paged_decode(8, 512, 768, 12, 16, pos),
+               failures, results)
+        _check('full width: flash_paged_decode_attention 32 on 8 x 128 '
+               'bf16',
+               lambda: check_paged_decode(8, 512, 4096, 32, 16, pos,
+                                          dtype=bf16, groups=8),
                failures, results)
         for dt in (bf16, f32):
             _check('full width: fused_softmax_xent V=30522 %s'

@@ -40,6 +40,14 @@ exactly this kernel's math over ICI-rotated K/V blocks; a manually
 DMA-pipelined K walk (double-buffered ``make_async_copy``) is the
 chip-side follow-up if single-device long-context ever needs it.
 
+The paged decode walk (:func:`flash_paged_decode_attention`) is that
+pipelined walk for the one case that needs it every step: a slot's
+history lies in pages scattered over a pool, so the kernel takes the
+page tables as scalars and copies a slot's live pages, and no others,
+from HBM into two VMEM buffers in turn. Its blocks are its own
+(``_WALK_ROWS``, a whole number of pages), so its reduction tree is
+not the dense kernels': it promises equality to rounding, not bits.
+
 All kernels accept bf16/fp16 inputs and accumulate in float32 (AMP
 composition); everything runs through the Pallas interpreter off-TPU.
 """
@@ -52,8 +60,8 @@ import jax
 import jax.numpy as jnp
 
 __all__ = ['flash_attention', 'flash_decode_attention',
-           'flash_paged_decode_attention', 'online_softmax_block',
-           'K_BLOCK']
+           'flash_paged_decode_attention', 'paged_walk_fits',
+           'online_softmax_block', 'K_BLOCK']
 
 # fixed key-axis block: part of the bit-identity contract (see module
 # docstring) — every call path pads the key axis to a multiple of this
@@ -83,7 +91,8 @@ def _online_block_cols(scores, v_blk, m, l, o):
     per-row carries held as columns.
 
     ``scores``: (..., q, k) float32 with masked entries at exactly
-    -inf; ``v_blk``: (..., k, d) float32; carries ``m`` / ``l``
+    -inf; ``v_blk``: (..., k, d), float32 or the dtype the weights are
+    rounded to for its product; carries ``m`` / ``l``
     (..., q, 1) and ``o`` (..., q, d). Fully-masked rows stay
     (m=-inf, l=0, o=0) — the caller divides by max(l, eps). The kernel
     bodies call this form directly: Mosaic lays vectors out as
@@ -99,7 +108,8 @@ def _online_block_cols(scores, v_blk, m, l, o):
     l_new = l * corr + p.sum(axis=-1, keepdims=True)
     batch = tuple(range(p.ndim - 2))
     o_new = o * corr + jax.lax.dot_general(
-        p, v_blk, (((p.ndim - 1,), (v_blk.ndim - 2,)), (batch, batch)),
+        p.astype(v_blk.dtype), v_blk,
+        (((p.ndim - 1,), (v_blk.ndim - 2,)), (batch, batch)),
         preferred_element_type=jnp.float32)
     return m_new, l_new, o_new
 
@@ -527,34 +537,246 @@ def flash_decode_attention(q, keys, values, positions, heads,
     return out[:, 0, :]
 
 
+# ---------------------------------------------------------------------------
+# single-token decode over a PAGED cache: the kernel walks the page
+# table itself and reads a sequence's live pages from the pool where
+# they lie — no per-slot view of the history is written to HBM
+# ---------------------------------------------------------------------------
+
+# rows of one compute block of the walk: a whole number of pages (one
+# page where a page is larger). On a v5e GPT-1's tables read alike at
+# 128 and 256, Granite's longer ones 11 % faster at 256 and no faster
+# at 512 (PERF.md section 6, PR 35). The dense kernels' K_BLOCK is
+# their bit-identity contract and stays theirs
+_WALK_ROWS = 256
+
+
+def mxnet_tpu_paged_decode_walk(tables_ref, pos_ref, next_ref, q_ref,
+                                k_hbm, v_hbm, o_ref, kbuf, vbuf, sems,
+                                parity_ref, *, page_size, block_pages,
+                                max_pages, group_rows, group_width,
+                                trash_page):
+    """One slot per program: the slot's query rows attend the pages its
+    table names, up to its position and no further.
+
+    ``tables_ref`` (slots * max_pages,), ``pos_ref`` (slots,) and
+    ``next_ref`` (slots + 1,: the first live slot at or after each
+    index) are scalar-prefetched; ``k_hbm`` / ``v_hbm`` are the pools
+    (pages, page_size, width), left where they are. A slot at position
+    ``p`` walks ``p // page_size + 1`` pages in blocks of
+    ``block_pages``: page copies into one of two VMEM buffers, the next
+    block's in flight while this block computes, across the slot
+    boundary too (``parity_ref`` carries the buffer in use from one
+    program to the next; the grid runs in order). A slot whose first
+    table entry is the trash page is empty: it copies nothing and
+    writes zeros.
+
+    No head is split out of a page. ``q_ref`` (1, rep, width) holds, in
+    row ``r``, the queries of the ``groups`` heads that are the
+    ``r``-th of their group of columns, each in its own group's
+    columns (``group_rows``: the groups, padded to whole sublane
+    tiles); the kernel lays head ``(r, g)`` over all ``width``
+    columns, zero outside group ``g``, so both contractions run over
+    the rows as they lie in the pool, and each column of the context
+    keeps its own head's sum. Rows past the position get weight
+    exactly 0 and their values are never multiplied (a select, not a
+    product: what lies there may be anything)."""
+    s = pl.program_id(0)
+    nslots = pl.num_programs(0)
+    ps, rows = page_size, block_pages * page_size
+    rep, width = q_ref.shape[1], q_ref.shape[2]
+
+    def block_copies(fn, slot, j, buf):
+        """``fn`` on the K and the V copy of every page of block ``j``
+        of ``slot``, into (or, waiting, out of) buffer ``buf``."""
+        first = j * block_pages
+        count = jnp.minimum(block_pages,
+                            pos_ref[slot] // ps + 1 - first)
+
+        def one_page(p, _):
+            page = tables_ref[slot * max_pages + first + p]
+            dst = pl.ds(pl.multiple_of(p * ps, ps), ps)
+            fn(pltpu.make_async_copy(
+                k_hbm.at[page], kbuf.at[buf, dst], sems.at[buf, 0]))
+            fn(pltpu.make_async_copy(
+                v_hbm.at[page], vbuf.at[buf, dst], sems.at[buf, 1]))
+            return _
+
+        jax.lax.fori_loop(0, count, one_page, 0)
+
+    def start(slot, j, buf):
+        @pl.when(slot < nslots)
+        def _():
+            block_copies(lambda c: c.start(), slot, j, buf)
+
+    @pl.when(s == 0)
+    def _():
+        parity_ref[0] = 0
+        start(next_ref[0], 0, 0)
+
+    live = tables_ref[s * max_pages] != trash_page
+
+    @pl.when(jnp.logical_not(live))
+    def _():
+        o_ref[...] = jnp.zeros(o_ref.shape, o_ref.dtype)
+
+    @pl.when(live)
+    def _():
+        pos = pos_ref[s]
+        nblocks = pos // rows + 1
+        heads_rows = rep * group_rows
+        col = jax.lax.broadcasted_iota(jnp.int32, (group_rows, width), 1)
+        low = jax.lax.broadcasted_iota(
+            jnp.int32, (group_rows, width), 0) * group_width
+        own = (col >= low) & (col < low + group_width)   # (G, width)
+        qx = jnp.concatenate(
+            [jnp.where(own, q_ref[0, r:r + 1, :], 0.0)
+             for r in range(rep)], axis=0).astype(kbuf.dtype)
+
+        def body(j, carry):
+            m, l, acc, buf = carry
+            # the next block's copies go out before this block's are
+            # waited for: this slot's next, or the next live slot's first
+            last = j + 1 == nblocks
+            start(jnp.where(last, next_ref[s + 1], s),
+                  jnp.where(last, 0, j + 1), 1 - buf)
+            block_copies(lambda c: c.wait(), s, j, buf)
+            kb, vb = kbuf[buf], vbuf[buf]
+            sc = jax.lax.dot_general(
+                qx, kb, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32)   # (heads, rows)
+            at = j * rows + jax.lax.broadcasted_iota(
+                jnp.int32, (heads_rows, rows), 1)
+            sc = jnp.where(at <= pos, sc, _NEG_INF)
+            seen = j * rows + jax.lax.broadcasted_iota(
+                jnp.int32, (rows, 1), 0) <= pos
+            vb = jnp.where(seen, vb, jnp.zeros_like(vb))
+            m, l, acc = _online_block_cols(sc, vb, m, l, acc)
+            return m, l, acc, 1 - buf
+
+        m0 = jnp.full((heads_rows, 1), _NEG_INF, jnp.float32)
+        l0 = jnp.zeros((heads_rows, 1), jnp.float32)
+        a0 = jnp.zeros((heads_rows, width), jnp.float32)
+        _m, l, acc, buf = jax.lax.fori_loop(
+            0, nblocks, body, (m0, l0, a0, parity_ref[0]))
+        parity_ref[0] = buf
+        ctx = acc / jnp.maximum(l, 1e-20)
+        for r in range(rep):
+            mine = ctx[r * group_rows:(r + 1) * group_rows]
+            o_ref[0, r:r + 1, :] = jnp.sum(
+                jnp.where(own, mine, 0.0), axis=0,
+                keepdims=True).astype(o_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=('heads', 'scale', 'interpret'))
+def _paged_walk(q, key_pool, value_pool, tables, positions, *, heads,
+                scale, interpret):
+    """:func:`flash_paged_decode_attention` behind a ``jit`` of its own:
+    the layers of one step call it with the same shapes, so the kernel
+    is traced, lowered and serialized once a step program, not once a
+    layer (12 Mosaic modules cost a GPT-1 process seconds of set-up)."""
+    from ...serving.decode.paged import TRASH_PAGE
+    slots, qw = q.shape
+    _pages, ps, width = key_pool.shape
+    d = qw // heads
+    groups = width // d
+    rep = heads // groups
+    max_pages = tables.shape[1]
+    block_pages = max(1, _WALK_ROWS // ps)
+    # sublane tiles: a group's heads fill whole float32 tiles, and the
+    # heads together whole tiles of the pool's dtype
+    tile = _sublane_tile(key_pool.dtype)
+    group_rows = _cdiv(groups, 8) * 8
+    if rep * group_rows % tile:
+        group_rows = _cdiv(groups, tile) * tile
+    # row r: the r-th head of every group, each in its group's columns
+    q3 = (q.astype(jnp.float32) * scale).reshape(
+        slots, groups, rep, d).transpose(0, 2, 1, 3).reshape(
+            slots, rep, width)
+    live = tables[:, 0] != TRASH_PAGE
+    first_live_from = jax.lax.cummin(
+        jnp.where(live, jnp.arange(slots, dtype=jnp.int32), slots),
+        reverse=True)
+    nxt = jnp.concatenate(
+        [first_live_from, jnp.full((1,), slots, jnp.int32)])
+    kern = functools.partial(
+        mxnet_tpu_paged_decode_walk, page_size=ps,
+        block_pages=block_pages, max_pages=max_pages,
+        group_rows=group_rows, group_width=d, trash_page=TRASH_PAGE)
+    row_spec = pl.BlockSpec((1, rep, width), lambda s, *_: (s, 0, 0),
+                            memory_space=pltpu.VMEM)
+    buf = pltpu.VMEM((2, block_pages * ps, width), key_pool.dtype)
+    out = pl.pallas_call(
+        kern,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(slots,),
+            in_specs=[row_spec,
+                      pl.BlockSpec(memory_space=pl.ANY),
+                      pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=row_spec,
+            scratch_shapes=[buf, buf, pltpu.SemaphoreType.DMA((2, 2)),
+                            pltpu.SMEM((1,), jnp.int32)]),
+        out_shape=jax.ShapeDtypeStruct((slots, rep, width), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=('arbitrary',)),
+        name='mxnet_tpu_paged_decode_walk',
+        interpret=interpret,
+    )(tables.reshape(-1).astype(jnp.int32), positions.astype(jnp.int32),
+      nxt, q3, key_pool, value_pool)
+    return out.reshape(slots, rep, groups, d).transpose(
+        0, 2, 1, 3).reshape(slots, heads * d)
+
+
 def flash_paged_decode_attention(q, key_pool, value_pool, tables,
                                  positions, heads, scale=None):
-    """Decode-step attention over a PAGED KV cache: ``q`` (slots, U)
-    single-token queries; ``key_pool``/``value_pool``
-    (pages, page_size, U) — the shared pool every sequence's pages
-    live in; ``tables`` (slots, max_pages) int32 page tables.
+    """Decode-step attention over a PAGED KV cache, read through the
+    page table inside one kernel: ``q`` (slots, heads * d) single-token
+    queries; ``key_pool`` / ``value_pool`` (pages, page_size, width) —
+    the shared pools every sequence's pages live in, ``width`` a whole
+    number of groups of ``d`` columns, ``heads`` a whole number of
+    heads a group (one for plain multi-head attention, several for
+    grouped queries: head ``h`` reads group ``h // (heads // groups)``);
+    ``tables`` (slots, max_pages) int32 under ``paged.gather_pages``'
+    contract; ``positions`` (slots,). Returns the context (slots,
+    heads * d) in float32.
 
-    The per-slot history view is ``paged.gather_pages``' (its
-    contract on ``tables`` holds here too): one XLA gather that
-    writes slots x max_len rows of K and of V to HBM whatever the
-    sequences' real lengths, then the same single-token online-softmax
-    kernel reads them back in the fixed K_BLOCK steps. Gathered rows
-    past a slot's position — including trash-page garbage behind
-    unused table entries — carry exactly 0.0 attention weight, so the
-    paged path combines the same reduction tree over the real keys as
-    the slot path (the decode bit-identity contract). A chip-side
-    follow-up can fold the gather into the kernel via scalar-prefetch
-    BlockSpec index maps (one page id per grid step) and read live
-    pages only; the program structure — table in, O(1) row writes,
-    no O(pool) copy — is already the paged contract hlolint gates.
-    """
-    from ...serving.decode.paged import gather_pages
-    keys = gather_pages(key_pool, tables)       # (S, P * ps, U)
-    values = gather_pages(value_pool, tables)
-    return flash_decode_attention(q, keys, values, positions, heads,
-                                  scale=scale)
+    Each slot attends rows ``0 .. positions[slot]`` of its own pages
+    and reads no other: the work follows the live pages, not ``slots x
+    max_pages``. A slot whose first table entry is the trash page is
+    empty and gets zeros. The softmax is online over blocks of
+    ``_WALK_ROWS`` rows with float32 carries; the contractions take
+    their operands in the pool's dtype, accumulate in float32 and run
+    at the default precision of where they are placed, as the gathered
+    view's did. The reduction tree is this kernel's own: equal to
+    ``gather_pages`` + dense softmax to rounding, not bit for bit
+    (docs/DIVERGENCES.md).
+
+    Mosaic wants ``page_size`` a whole number of the pool dtype's
+    sublane tiles and ``width`` of 128 lanes (:func:`paged_walk_fits`);
+    the interpreter takes any shape."""
+    from . import interpret_mode
+    if scale is None:
+        scale = 1.0 / math.sqrt(q.shape[1] // heads)
+    return _paged_walk(q, key_pool, value_pool, tables, positions,
+                       heads=int(heads), scale=float(scale),
+                       interpret=interpret_mode())
+
+
+def _sublane_tile(dtype):
+    """Rows of one (sublane, lane) tile of ``dtype``: 8 of 4 bytes."""
+    return 8 * 4 // jnp.dtype(dtype).itemsize
+
+
+def paged_walk_fits(page_size, width, dtype):
+    """Whether Mosaic takes :func:`flash_paged_decode_attention` at this
+    pool geometry: a page is a whole number of the dtype's sublane
+    tiles and a row a whole number of 128 lanes, so that a page copy
+    lands on tile boundaries."""
+    return page_size % _sublane_tile(dtype) == 0 and width % 128 == 0
 
 
 # module-level pl import for the kernel bodies (resolved lazily at
 # trace time would shadow per-call; kernels only run under pallas_call)
 from jax.experimental import pallas as pl  # noqa: E402
+from jax.experimental.pallas import tpu as pltpu  # noqa: E402

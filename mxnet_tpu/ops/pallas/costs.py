@@ -16,7 +16,7 @@ roofline's lazy load on any rig.
 """
 from __future__ import annotations
 
-__all__ = ['register_all', 'KERNEL_TAGS']
+__all__ = ['register_all', 'KERNEL_TAGS', 'PAGED_WALK_TAG']
 
 # kernel function names (what lands in the custom-call metadata /
 # payload) by family — also what the hlolint HLO-PALLAS rules match
@@ -31,6 +31,13 @@ KERNEL_TAGS = {
     'xent': ('mxnet_tpu_softmax_xent_fwd',
              'mxnet_tpu_softmax_xent_bwd'),
 }
+
+
+# the paged decode walk (attention.flash_paged_decode_attention): behind
+# no knob, so in no family above. The paged step calls it wherever it is
+# placed on a TPU, and hlolint takes it as the step's read through the
+# page table
+PAGED_WALK_TAG = 'mxnet_tpu_paged_decode_walk'
 
 
 def _dims(instr, idx):
@@ -74,6 +81,20 @@ def _decode_flops(instr):
     return 4 * slots * length * u + 5 * slots * length
 
 
+def _paged_walk_flops(instr):
+    # operands: tables (slots * max_pages,), positions, the chain of
+    # live slots, q (slots, heads a group, width), K pool, V pool (pages,
+    # page_size, width). What the walk multiplies follows the positions,
+    # which the text does not hold: a full table, the most it can be.
+    # Two GEMM-equivalents over the real query rows, as _decode_flops
+    tables, q, pool = _dims(instr, 0), _dims(instr, 3), _dims(instr, 4)
+    if len(tables) != 1 or len(q) < 3 or len(pool) < 3:
+        return 0
+    slots, rep, width = q[-3], q[-2], q[-1]
+    rows = tables[0] // max(slots, 1) * pool[-2]
+    return 4 * slots * rows * rep * width + 5 * slots * rows * rep
+
+
 def _elementwise_flops(per_elem):
     def fn(instr):
         return per_elem * _elems(instr, 0)
@@ -90,6 +111,7 @@ def register_all(registry):
     registry.setdefault('mxnet_tpu_flash_attention_dkv',
                         _attention_flops(4))
     registry.setdefault('mxnet_tpu_flash_decode_fwd', _decode_flops)
+    registry.setdefault(PAGED_WALK_TAG, _paged_walk_flops)
     for tag in KERNEL_TAGS['epilogue']:
         registry.setdefault(tag, _elementwise_flops(3))
     # xent: max + exp + sum + log + pick over the (B, V) block

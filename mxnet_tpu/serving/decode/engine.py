@@ -1383,6 +1383,8 @@ class DecodeEngine:
             tables = self._pages.tables(
                 self.slots, ((slot, seq.pages)
                              for slot, seq in active.items()))
+            kv_pages = self._pages.step_pages(
+                self.slots, [seq.pos for seq in active.values()])
             extras = self._step_extras(active)
         self._cache, toks, _logits = self._device(
             self.program.run_step, self._cache, tokens, positions,
@@ -1396,10 +1398,12 @@ class DecodeEngine:
                 self._draft.run_step, self._draft_cache, tokens,
                 positions)
         return lambda: self._emit_paged_step(active, toks,
-                                             self._clock() - t0)
+                                             self._clock() - t0, kv_pages)
 
-    def _emit_paged_step(self, active, toks, dt):
-        """Advance positions, stream each slot's token, book the step."""
+    def _emit_paged_step(self, active, toks, dt, kv_pages):
+        """Advance positions, stream each slot's token, book the step
+        (``kv_pages``: the pages its attention had to read and those
+        of a gathered view, ``PageOwner.step_pages``)."""
         emitted = 0
         sampled = 0
         sampled_step = _samples(active)
@@ -1428,6 +1432,8 @@ class DecodeEngine:
             self._counts['sampled_steps'] += sampled_step
             self._counts['tokens'] += emitted
             self._counts['sampled_tokens'] += sampled
+            self._counts['kv_pages_walked'] += kv_pages[0]
+            self._counts['kv_pages_view'] += kv_pages[1]
             if self._step_stats:
                 # what the device counted came back behind the tokens
                 for name, v in self.program.last_step_stats.items():

@@ -65,7 +65,8 @@ import numpy as onp
 
 from . import blocks
 from .model import _FAMILIES, DecodeModel, FamilyUnsupported
-from .paged import PagedCacheSpec, gather_pages, scatter_pages, scatter_rows
+from .paged import (PagedCacheSpec, gather_pages, scatter_pages,
+                    scatter_rows, walks_pages)
 
 __all__ = ['GraniteHybridLM', 'init_granite_hybrid_lm']
 
@@ -517,8 +518,11 @@ class GraniteHybridLM(DecodeModel):
 
     def _attention_step(self, p, r, pool, i, positions, tables):
         """An attention layer's mixer in the step: append this token's
-        K and V (``pool`` is updated in place, a dict), gather the
-        table and attend over what each row's position has seen."""
+        K and V (``pool`` is updated in place, a dict) and attend over
+        what each row's position has seen: placed on a TPU one kernel
+        walks the table and reads the live pages
+        (``paged.walks_pages``), anywhere else the table is gathered
+        into a view."""
         import jax
         import jax.numpy as jnp
         kk, vk = 'l%d_k' % i, 'l%d_v' % i
@@ -532,6 +536,14 @@ class GraniteHybridLM(DecodeModel):
                                     at, offsets)
             pool[vk] = scatter_rows(pool[vk], v.reshape(v.shape[0], -1),
                                     at, offsets)
+        if walks_pages(pool[kk].shape, pool[kk].dtype):
+            from ...ops.pallas import flash_paged_decode_attention
+            with jax.named_scope('attn'):
+                # q carries the attention multiplier already
+                ctx = flash_paged_decode_attention(
+                    q.reshape(q.shape[0], -1), pool[kk], pool[vk], tables,
+                    positions, heads=self.heads, scale=1.0)
+                return self._mm('to,oh->th', ctx, p('o_w'))
         keys = gather_pages(pool[kk], tables)
         values = gather_pages(pool[vk], tables)
         with jax.named_scope('attn'):

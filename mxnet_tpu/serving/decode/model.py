@@ -33,7 +33,7 @@ import numpy as onp
 
 from .cache import CacheSpec, write_position, write_slot
 from .paged import (PagedCacheSpec, gather_pages, scatter_rows,
-                    write_prefill_pages)
+                    walks_pages, write_prefill_pages)
 
 __all__ = ['DecodeModel', 'RNNLM', 'TransformerLM', 'from_gluon_rnn_lm',
            'model_from_config', 'init_rnn_lm', 'init_transformer_lm',
@@ -600,12 +600,18 @@ class TransformerLM(DecodeModel):
     def paged_step(self, params, pool, tokens, positions, tables,
                    ad=None):
         """One decode step over the page pool: identical math to
-        :meth:`step` except the per-slot K/V view is a gather of the
-        slot's page-table entries and the row write is addressed
-        ``(table[pos // ps], pos % ps)``. Gathered rows beyond a
-        slot's position (incl. trash-page garbage) carry exactly 0.0
-        attention weight, so the paged token stream is bit-identical
-        to the slot cache's (module docstring argument)."""
+        :meth:`step` except that the row write is addressed
+        ``(table[pos // ps], pos % ps)`` and the history is read
+        through the page table. Placed on a TPU the attention walks
+        the table inside one kernel and reads a slot's live pages from
+        the pool (``paged.walks_pages``): its reduction tree is the
+        kernel's own, so the stream equals the slot cache's to
+        rounding there (docs/DIVERGENCES.md). Anywhere else the
+        per-slot K/V view is a gather of the slot's table entries;
+        gathered rows beyond a slot's position (incl. trash-page
+        garbage) carry exactly 0.0 attention weight, so the paged
+        token stream is bit-identical to the slot cache's (module
+        docstring argument)."""
         import jax
         import jax.numpy as jnp
         ps = pool[next(iter(pool))].shape[1]
@@ -613,13 +619,13 @@ class TransformerLM(DecodeModel):
         page_ids = jnp.take_along_axis(
             tables, (positions // ps)[:, None], axis=1)[:, 0]
         offsets = positions % ps
-        lp = tables.shape[1] * ps
-        ar = jnp.arange(lp)
-        bias = jnp.where(ar[None, :] <= positions[:, None],
-                         0.0, -1e9)[:, None, :]           # (S, 1, Lp)
         scale = 1.0 / float(onp.sqrt(self.units // self.heads))
-        flash = _flash_on()
         pool = dict(pool)
+        walk = walks_pages(pool['l0_k'].shape, pool['l0_k'].dtype)
+        if not walk:
+            ar = jnp.arange(tables.shape[1] * ps)
+            bias = jnp.where(ar[None, :] <= positions[:, None],
+                             0.0, -1e9)[:, None, :]       # (S, 1, Lp)
         for i in range(self.layers):
             p = lambda n: params['l%d_%s' % (i, n)]       # noqa: E731
             with jax.named_scope('layer%d' % i):
@@ -631,11 +637,7 @@ class TransformerLM(DecodeModel):
                         pool['l%d_k' % i], k, page_ids, offsets)
                     pool['l%d_v' % i] = scatter_rows(
                         pool['l%d_v' % i], v, page_ids, offsets)
-                if flash:
-                    # page-table gather + the same single-token kernel
-                    # the slot cache used — the kernel walks the
-                    # gathered view in the fixed K_BLOCK steps, so the
-                    # reduction tree over the real keys is unchanged
+                if walk:
                     from ...ops.pallas import \
                         flash_paged_decode_attention
                     with jax.named_scope('attn'):

@@ -59,7 +59,8 @@ from ...observability.spans import span as _span
 __all__ = ['PagedCacheSpec', 'PageAllocator', 'PrefixCache', 'PageOwner',
            'SeqPages', 'TRASH_PAGE', 'init_pool', 'pool_avals', 'pool_bytes',
            'slot_state_bytes',
-           'gather_pages', 'write_prefill_pages', 'copy_page', 'pages_for',
+           'gather_pages', 'walks_pages', 'write_prefill_pages',
+           'copy_page', 'pages_for',
            'scatter_rows', 'scatter_pages', 'ring_key_positions',
            'window_table_pages']
 
@@ -278,12 +279,28 @@ def gather_pages(pool_arr, tables):
     read back: the same traffic the slot cache's per-step view cost,
     independent of pool size (the HLO-DECODE-PAGED lint asserts no
     O(pool) materializing copy appears instead). Only attention that
-    walks the table itself would read the live rows alone."""
+    walks the table itself reads the live rows alone: the one-token
+    step's does on a TPU (:func:`walks_pages`); the verify's chunk,
+    the prefills and the ring tables of a window layer gather."""
     import jax
     with jax.named_scope('kv_gather'):
         g = pool_arr.at[tables].get(mode='promise_in_bounds')
         s, p, ps = g.shape[:3]                   # (S, P, ps, *row)
         return g.reshape((s, p * ps) + g.shape[3:])
+
+
+def walks_pages(pool_shape, dtype):
+    """Whether the one-token step's attention over a pool of
+    ``pool_shape`` (pages, page_size, width) walks the page table
+    inside one kernel (``ops.pallas.flash_paged_decode_attention``: a
+    slot's live pages are read from the pool where they lie) or
+    gathers a view (:func:`gather_pages`). The walk runs where the
+    computation being traced is placed on a TPU and the pool's
+    geometry is one Mosaic takes; the CPU rig and the serving CPU
+    replay gather. Decided on what the trace can observe, by no knob."""
+    from ...ops.pallas import interpret_mode, paged_walk_fits
+    return not interpret_mode() and paged_walk_fits(
+        pool_shape[1], pool_shape[2], dtype)
 
 
 def write_prefill_pages(pool_arr, rows, page_ids):
@@ -650,11 +667,14 @@ class _Kind:
     its prefix registry (None: the owner was built without one) and
     its table geometry."""
 
-    __slots__ = ('name', 'columns', 'ring', 'allocator', 'prefix')
+    __slots__ = ('name', 'columns', 'ring', 'layers', 'allocator',
+                 'prefix')
 
-    def __init__(self, name, columns, ring, allocator, prefix):
+    def __init__(self, name, columns, ring, layers, allocator, prefix):
         self.name = name
         self.columns = int(columns)
+        # attention layers of this kind (a layer has a K and a V entry)
+        self.layers = int(layers)
         # a ring keeps logical page p in column p % columns and gives
         # back the page that falls behind; a table that is no ring has
         # a column for every page a sequence can reach
@@ -699,7 +719,9 @@ class PageOwner:
     lock) never sees a pool half reset. ``counts`` is the scheduler's
     counter dict: ``prefix_hits``, ``prefix_tokens_saved``,
     ``page_evictions``, ``cow_copies`` and, with a ring,
-    ``window_pages_released`` are booked here and nowhere else.
+    ``window_pages_released`` are booked here and nowhere else
+    (``kv_pages_walked`` and ``kv_pages_view`` are reckoned here,
+    :meth:`step_pages`, and booked by the scheduler with its step).
     ``event(kind, **fields)`` takes the flight recorder's
     ``page_alloc`` and ``page_evict``. Worker thread only, but for the
     readers and :meth:`drop`.
@@ -724,16 +746,22 @@ class PageOwner:
         self._counts = counts
         self._event = event or (lambda kind, **fields: None)
         self._kinds = []
+        window = len(spec.window_entries)
         for name, columns, ring in spec.kinds():
             allocator = PageAllocator(pool_pages[name])
             self._kinds.append(_Kind(
-                name, columns, ring, allocator,
+                name, columns, ring,
+                (window if ring else len(spec.entries) - window) // 2,
+                allocator,
                 PrefixCache(spec.page_size, allocator)
                 if prefix_cache else None))
         self._rings = [k for k in self._kinds if k.ring]
         self._registers = bool(prefix_cache)
+        # pages of one slot's gathered views, over all attention layers
+        self._view_pages = sum(k.layers * k.columns for k in self._kinds)
         for name in ('prefix_hits', 'prefix_tokens_saved',
-                     'page_evictions', 'cow_copies'):
+                     'page_evictions', 'cow_copies',
+                     'kv_pages_walked', 'kv_pages_view'):
             counts.setdefault(name, 0)
         if self._rings:
             counts.setdefault('window_pages_released', 0)
@@ -984,6 +1012,22 @@ class PageOwner:
             for name, table in out.items():
                 table[slot] = rec.tables[name]
         return self._out(out)
+
+    def step_pages(self, slots, positions):
+        """What one step's attention has to read and what a gathered
+        view holds, in pages over all attention layers: ``positions``
+        are the live sequences' newest positions. A sequence at
+        position ``p`` has ``p // page_size + 1`` live pages a full
+        layer (a ring no more than its columns): what attention that
+        walks the table reads (:func:`walks_pages`). The view is
+        ``slots`` x table columns a layer, whatever is live. Returns
+        (walked, view): the scheduler books them as ``kv_pages_walked``
+        and ``kv_pages_view``, whose ratio is the share of the view
+        that was live."""
+        tops = [p // self.page_size + 1 for p in positions]
+        walked = sum(k.layers * sum(min(t, k.columns) for t in tops)
+                     for k in self._kinds)
+        return walked, slots * self._view_pages
 
     def first_pages(self, rec, n_tokens):
         """The pages that hold positions ``[0, n_tokens)`` of ``rec``

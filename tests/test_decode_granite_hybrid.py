@@ -421,3 +421,54 @@ def test_named_scopes_of_the_step_program(toy):
     names = {k: prog._compiled[k].as_text().split('HloModule ')[1]
              .split(',')[0].split(' ')[0] for k in prog._compiled}
     assert names['step'] == 'jit_fn_step'
+
+
+# ---------------------------------------------------------------------------
+# the attention layer's step: the walk on a TPU, the gather anywhere else
+# ---------------------------------------------------------------------------
+
+def test_kv_pages_walked_and_view_count_the_attention_layers_alone(toy):
+    """One attention layer in the toy's four: a step books ``position //
+    page_size + 1`` pages a live sequence and ``slots x max_pages`` of
+    view; the Mamba layers' state is no page."""
+    _cfg_, _w, prog = toy
+    spec = prog.page_spec
+    prompts = [_tokens(6, 2), _tokens(13, 5)]
+    new = [9, 5]
+    eng = DecodeEngine(prog, max_new_tokens=16)
+    try:
+        outs = [eng.generate(p, max_new_tokens=n).result(timeout=120)
+                for p, n in zip(prompts, new)]
+        counts = eng.stats()['counts']
+    finally:
+        eng.close()
+    walked = sum(pos // spec.page_size + 1
+                 for prompt, out in zip(prompts, outs)
+                 for pos in range(len(prompt), len(prompt) + len(out) - 1))
+    assert counts['kv_pages_walked'] == walked
+    assert counts['kv_pages_view'] == \
+        counts['steps'] * prog.slots * spec.max_pages
+
+
+def test_the_attention_step_traced_for_the_cpu_gathers(toy):
+    """``_attention_step`` chooses by where it is placed
+    (``paged.walks_pages``), at the published head geometry too: on the
+    CPU rig it holds the gather and no kernel."""
+    import jax
+    from mxnet_tpu.serving.decode.paged import pool_avals, walks_pages
+    model = GraniteHybridLM(dict(
+        _model(_cfg()).config, hidden=64, head_dim=128, heads=32,
+        kv_heads=8, layer_types=['attention'], dtype='bfloat16'))
+    spec = model.paged_spec(16)
+    pool = pool_avals(spec, 9, 0, 2)
+    assert tuple(pool['l0_k'].shape) == (9, 16, 1024)
+    assert not walks_pages(pool['l0_k'].shape, pool['l0_k'].dtype)
+    params = jax.eval_shape(lambda: model.init_params(0))
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, 'int32')
+
+    text = str(jax.make_jaxpr(model.paged_step)(
+        params, pool, i32(2), i32(2), i32(2, spec.max_pages)))
+    assert 'pallas_call' not in text
+    assert text.count('gather[') >= 2

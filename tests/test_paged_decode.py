@@ -855,10 +855,12 @@ def test_registered_prefixes_evicted_lru_under_pressure():
 
 
 def test_paged_bit_identity_with_flash_attention_knob():
-    """MXNET_TPU_PALLAS=attention routes the paged step through the
-    page-table gather + flash decode kernel: token streams stay
-    bit-identical to the knob-off paged path and the reference, and
-    the knob splits the compiled-program keys (no latching)."""
+    """MXNET_TPU_PALLAS=attention puts the prefill's attention on the
+    flash kernel; the paged step itself is behind no knob (it gathers
+    here, and walks the table on a TPU: ``paged.walks_pages``). Token
+    streams stay bit-identical to the knob-off paged path and the
+    reference, and the knob splits the compiled-program keys (no
+    latching)."""
     import mxnet_tpu as mx
     model, params = _model(max_len=48)
     requests = [([7, 2, 9], 5), ([1, 2, 3, 4, 5], 5)]
@@ -1241,3 +1243,76 @@ def test_attention_over_the_view_equals_attention_head_by_head(queries):
         jnp.asarray(q[:, 0]), jnp.asarray(keys), jnp.asarray(values),
         jnp.asarray(bias[:, :1])))
     np.testing.assert_allclose(rows, want[:, 0], rtol=2e-5, atol=2e-6)
+
+
+# ---------------------------------------------------------------------------
+# the one-token step walks the table on a TPU, gathers anywhere else;
+# the counters that say how much of the view was live
+# ---------------------------------------------------------------------------
+
+def test_kv_pages_walked_and_view_follow_the_positions():
+    """``kv_pages_walked``: over a run's steps, live slots and attention
+    layers, ``position // page_size + 1``; ``kv_pages_view``: ``slots x
+    max_pages x layers`` a step. Booked by the engine from the positions
+    it holds (``PageOwner.step_pages``), no device read."""
+    model, params = _model(max_len=48)
+    ps, slots = 8, 3
+    prog = PagedDecodeProgram(model, params, slots=slots,
+                              prefill_buckets=(4, 8, 16), page_size=ps)
+    requests = [([7, 2, 9], 9), ([1, 2, 3, 4, 5, 6, 7, 8, 9, 10, 11], 6)]
+    outs, stats = _run_engine(prog, requests, prefix_cache=False)
+    counts = stats['counts']
+    # a request of n new tokens: one from its prefill, then a step at
+    # every position from the prompt's length on
+    walked = sum(model.layers * (pos // ps + 1)
+                 for (prompt, _n), out in zip(requests, outs)
+                 for pos in range(len(prompt), len(prompt) + len(out) - 1))
+    assert counts['kv_pages_walked'] == walked
+    assert counts['kv_pages_view'] == \
+        counts['steps'] * slots * (48 // ps) * model.layers
+    assert 0 < counts['kv_pages_walked'] < counts['kv_pages_view']
+
+
+def test_step_pages_counts_a_ring_no_further_than_its_columns():
+    from mxnet_tpu.serving.decode.paged import PageOwner
+    spec = PagedCacheSpec(
+        {'l0_k': ((8,), 'float32'), 'l0_v': ((8,), 'float32'),
+         'l1_k': ((8,), 'float32'), 'l1_v': ((8,), 'float32'),
+         'l2_k': ((8,), 'float32'), 'l2_v': ((8,), 'float32')},
+        4, 64, window=8, window_entries=('l0_k', 'l0_v', 'l1_k', 'l1_v'))
+    import threading
+    owner = PageOwner(spec, {'full': 40, 'window': 20}, threading.Lock(),
+                      False, {})
+    ring = spec.window_pages
+    walked, view = owner.step_pages(5, [0, 9, 63])
+    # one full layer: 1 + 3 + 16 pages; two window layers: the ring's
+    # columns at most
+    assert walked == (1 + 3 + 16) + 2 * (1 + min(3, ring) + ring)
+    assert view == 5 * (16 + 2 * ring)
+
+
+def test_the_step_traced_for_the_cpu_gathers_and_calls_no_kernel():
+    """The walk's choice is made by where the computation is placed
+    (``paged.walks_pages``): on the CPU rig the step holds the gather
+    and no ``pallas_call``, whatever the pool's geometry."""
+    import jax
+    from mxnet_tpu.serving.decode import TransformerLM
+    from mxnet_tpu.serving.decode.paged import pool_avals, walks_pages
+    # GPT-1's pool geometry, which Mosaic takes: placement alone decides
+    model = TransformerLM(dict(vocab=64, units=768, hidden=64, layers=1,
+                               heads=12, max_len=64))
+    pool = pool_avals(model.paged_spec(16), 9)
+    assert not walks_pages(pool['l0_k'].shape, pool['l0_k'].dtype)
+    params = jax.eval_shape(lambda: model.init_params(0))
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, 'int32')
+
+    jaxpr = jax.make_jaxpr(model.paged_step)(
+        params, pool, i32(2), i32(2), i32(2, 4))
+    prims = [op[0] for op in _pool_ops(jaxpr.jaxpr)]
+    assert prims.count('gather') >= 2
+    assert 'pallas_call' not in str(jaxpr)
+    # the verify's chunk gathers wherever it is placed
+    import inspect
+    assert 'walks_pages' not in inspect.getsource(model.paged_verify)

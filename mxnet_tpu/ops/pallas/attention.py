@@ -45,7 +45,7 @@ pipelined walk for the one case that needs it every step: a slot's
 history lies in pages scattered over a pool, so the kernel takes the
 page tables as scalars and copies a slot's live pages, and no others,
 from HBM into two VMEM buffers in turn. Its blocks are its own
-(``_WALK_ROWS``, a whole number of pages), so its reduction tree is
+(``_walk_rows``, a whole number of pages), so its reduction tree is
 not the dense kernels': it promises equality to rounding, not bits.
 
 All kernels accept bf16/fp16 inputs and accumulate in float32 (AMP
@@ -543,17 +543,28 @@ def flash_decode_attention(q, keys, values, positions, heads,
 # they lie — no per-slot view of the history is written to HBM
 # ---------------------------------------------------------------------------
 
-# rows of one compute block of the walk: a whole number of pages (one
-# page where a page is larger). On a v5e GPT-1's tables read alike at
-# 128 and 256, Granite's longer ones 11 % faster at 256 and no faster
-# at 512 (PERF.md section 6, PR 35). The dense kernels' K_BLOCK is
-# their bit-identity contract and stays theirs
-_WALK_ROWS = 256
+# VMEM the walk's page buffers may fill: two buffers a pool, each one
+# compute block of rows. A block is the largest power of two of rows
+# that fits, in whole pages (one page where a page is larger). On a v5e
+# that is 256 rows for GPT-1's and Granite's two pools (GPT-1's tables
+# read alike at 128 and 256, Granite's longer ones 11 % faster at 256
+# and no faster at 512: PERF.md section 6, PR 35) and 1 024 for the one
+# pool of 640 bfloat16 columns (48 slots of about 8 200 rows: 2 235 /
+# 1 720 / 1 446 / 1 356 us a call at 128 / 256 / 512 / 1 024 rows: what
+# a block costs beside its rows is 1.0 us; PERF.md section 6, PR 36).
+# The dense kernels' K_BLOCK is their bit-identity contract and stays
+# theirs
+_WALK_VMEM_BYTES = 3 << 20
+
+
+def _walk_rows(pools, width, dtype):
+    fit = _WALK_VMEM_BYTES // (2 * pools * width
+                               * jnp.dtype(dtype).itemsize)
+    return 1 << (fit.bit_length() - 1)
 
 
 def mxnet_tpu_paged_decode_walk(tables_ref, pos_ref, next_ref, q_ref,
-                                k_hbm, v_hbm, o_ref, kbuf, vbuf, sems,
-                                parity_ref, *, page_size, block_pages,
+                                *refs, page_size, block_pages,
                                 max_pages, group_rows, group_width,
                                 trash_page):
     """One slot per program: the slot's query rows attend the pages its
@@ -561,8 +572,14 @@ def mxnet_tpu_paged_decode_walk(tables_ref, pos_ref, next_ref, q_ref,
 
     ``tables_ref`` (slots * max_pages,), ``pos_ref`` (slots,) and
     ``next_ref`` (slots + 1,: the first live slot at or after each
-    index) are scalar-prefetched; ``k_hbm`` / ``v_hbm`` are the pools
-    (pages, page_size, width), left where they are. A slot at position
+    index) are scalar-prefetched. ``refs``: the pools (pages,
+    page_size, width), left where they are; the output; a VMEM buffer a
+    pool; the copies' semaphores and the buffer parity. Keys are read
+    from the first pool and values from the last one's leading columns,
+    as many as the output is wide: with a K and a V pool those are all
+    of V's, with one pool (latent rows) a row's values are its own
+    leading columns, taken from the one VMEM copy of the page that its
+    keys are read from. A slot at position
     ``p`` walks ``p // page_size + 1`` pages in blocks of
     ``block_pages``: page copies into one of two VMEM buffers, the next
     block's in flight while this block computes, across the slot
@@ -575,19 +592,25 @@ def mxnet_tpu_paged_decode_walk(tables_ref, pos_ref, next_ref, q_ref,
     row ``r``, the queries of the ``groups`` heads that are the
     ``r``-th of their group of columns, each in its own group's
     columns (``group_rows``: the groups, padded to whole sublane
-    tiles); the kernel lays head ``(r, g)`` over all ``width``
+    tiles; 1 where every head reads the whole row); the kernel lays
+    head ``(r, g)`` over all ``width``
     columns, zero outside group ``g``, so both contractions run over
     the rows as they lie in the pool, and each column of the context
     keeps its own head's sum. Rows past the position get weight
     exactly 0 and their values are never multiplied (a select, not a
     product: what lies there may be anything)."""
+    pools = (len(refs) - 3) // 2
+    hbm, o_ref, bufs = refs[:pools], refs[pools], refs[pools + 1:-2]
+    sems, parity_ref = refs[-2:]
+    kbuf, vbuf = bufs[0], bufs[-1]
     s = pl.program_id(0)
     nslots = pl.num_programs(0)
     ps, rows = page_size, block_pages * page_size
     rep, width = q_ref.shape[1], q_ref.shape[2]
+    out_width = o_ref.shape[2]
 
     def block_copies(fn, slot, j, buf):
-        """``fn`` on the K and the V copy of every page of block ``j``
+        """``fn`` on every pool's copy of every page of block ``j``
         of ``slot``, into (or, waiting, out of) buffer ``buf``."""
         first = j * block_pages
         count = jnp.minimum(block_pages,
@@ -596,10 +619,9 @@ def mxnet_tpu_paged_decode_walk(tables_ref, pos_ref, next_ref, q_ref,
         def one_page(p, _):
             page = tables_ref[slot * max_pages + first + p]
             dst = pl.ds(pl.multiple_of(p * ps, ps), ps)
-            fn(pltpu.make_async_copy(
-                k_hbm.at[page], kbuf.at[buf, dst], sems.at[buf, 0]))
-            fn(pltpu.make_async_copy(
-                v_hbm.at[page], vbuf.at[buf, dst], sems.at[buf, 1]))
+            for i in range(pools):
+                fn(pltpu.make_async_copy(
+                    hbm[i].at[page], bufs[i].at[buf, dst], sems.at[buf, i]))
             return _
 
         jax.lax.fori_loop(0, count, one_page, 0)
@@ -641,7 +663,7 @@ def mxnet_tpu_paged_decode_walk(tables_ref, pos_ref, next_ref, q_ref,
             start(jnp.where(last, next_ref[s + 1], s),
                   jnp.where(last, 0, j + 1), 1 - buf)
             block_copies(lambda c: c.wait(), s, j, buf)
-            kb, vb = kbuf[buf], vbuf[buf]
+            kb, vb = kbuf[buf], vbuf[buf][:, :out_width]
             sc = jax.lax.dot_general(
                 qx, kb, (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32)   # (heads, rows)
@@ -656,7 +678,7 @@ def mxnet_tpu_paged_decode_walk(tables_ref, pos_ref, next_ref, q_ref,
 
         m0 = jnp.full((heads_rows, 1), _NEG_INF, jnp.float32)
         l0 = jnp.zeros((heads_rows, 1), jnp.float32)
-        a0 = jnp.zeros((heads_rows, width), jnp.float32)
+        a0 = jnp.zeros((heads_rows, out_width), jnp.float32)
         _m, l, acc, buf = jax.lax.fori_loop(
             0, nblocks, body, (m0, l0, a0, parity_ref[0]))
         parity_ref[0] = buf
@@ -664,13 +686,14 @@ def mxnet_tpu_paged_decode_walk(tables_ref, pos_ref, next_ref, q_ref,
         for r in range(rep):
             mine = ctx[r * group_rows:(r + 1) * group_rows]
             o_ref[0, r:r + 1, :] = jnp.sum(
-                jnp.where(own, mine, 0.0), axis=0,
+                jnp.where(own[:, :out_width], mine, 0.0), axis=0,
                 keepdims=True).astype(o_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=('heads', 'scale', 'interpret'))
+@functools.partial(jax.jit, static_argnames=('heads', 'scale', 'interpret',
+                                             'value_cols'))
 def _paged_walk(q, key_pool, value_pool, tables, positions, *, heads,
-                scale, interpret):
+                scale, interpret, value_cols=None):
     """:func:`flash_paged_decode_attention` behind a ``jit`` of its own:
     the layers of one step call it with the same shapes, so the kernel
     is traced, lowered and serialized once a step program, not once a
@@ -682,13 +705,19 @@ def _paged_walk(q, key_pool, value_pool, tables, positions, *, heads,
     groups = width // d
     rep = heads // groups
     max_pages = tables.shape[1]
-    block_pages = max(1, _WALK_ROWS // ps)
+    # one pool: a row's values are its own leading columns
+    pools = (key_pool,) if value_pool is None else (key_pool, value_pool)
+    block_pages = max(
+        1, _walk_rows(len(pools), width, key_pool.dtype) // ps)
     # sublane tiles: a group's heads fill whole float32 tiles, and the
     # heads together whole tiles of the pool's dtype
     tile = _sublane_tile(key_pool.dtype)
     group_rows = _cdiv(groups, 8) * 8
     if rep * group_rows % tile:
         group_rows = _cdiv(groups, tile) * tile
+    if groups == 1 and rep % tile == 0:
+        group_rows = 1            # every head reads the whole row
+    out_width = value_cols or width
     # row r: the r-th head of every group, each in its group's columns
     q3 = (q.astype(jnp.float32) * scale).reshape(
         slots, groups, rep, d).transpose(0, 2, 1, 3).reshape(
@@ -703,33 +732,40 @@ def _paged_walk(q, key_pool, value_pool, tables, positions, *, heads,
         mxnet_tpu_paged_decode_walk, page_size=ps,
         block_pages=block_pages, max_pages=max_pages,
         group_rows=group_rows, group_width=d, trash_page=TRASH_PAGE)
-    row_spec = pl.BlockSpec((1, rep, width), lambda s, *_: (s, 0, 0),
+
+    def rows_of(w):
+        return pl.BlockSpec((1, rep, w), lambda s, *_: (s, 0, 0),
                             memory_space=pltpu.VMEM)
+
     buf = pltpu.VMEM((2, block_pages * ps, width), key_pool.dtype)
     out = pl.pallas_call(
         kern,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
             grid=(slots,),
-            in_specs=[row_spec,
-                      pl.BlockSpec(memory_space=pl.ANY),
-                      pl.BlockSpec(memory_space=pl.ANY)],
-            out_specs=row_spec,
-            scratch_shapes=[buf, buf, pltpu.SemaphoreType.DMA((2, 2)),
-                            pltpu.SMEM((1,), jnp.int32)]),
-        out_shape=jax.ShapeDtypeStruct((slots, rep, width), jnp.float32),
+            in_specs=[rows_of(width)]
+            + [pl.BlockSpec(memory_space=pl.ANY)] * len(pools),
+            out_specs=rows_of(out_width),
+            scratch_shapes=[buf] * len(pools)
+            + [pltpu.SemaphoreType.DMA((2, 2)),
+               pltpu.SMEM((1,), jnp.int32)]),
+        out_shape=jax.ShapeDtypeStruct((slots, rep, out_width),
+                                       jnp.float32),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=('arbitrary',)),
         name='mxnet_tpu_paged_decode_walk',
         interpret=interpret,
     )(tables.reshape(-1).astype(jnp.int32), positions.astype(jnp.int32),
-      nxt, q3, key_pool, value_pool)
+      nxt, q3, *pools)
+    if value_pool is None:
+        return out.reshape(slots, heads * out_width)
     return out.reshape(slots, rep, groups, d).transpose(
         0, 2, 1, 3).reshape(slots, heads * d)
 
 
 def flash_paged_decode_attention(q, key_pool, value_pool, tables,
-                                 positions, heads, scale=None):
+                                 positions, heads, scale=None,
+                                 value_cols=None):
     """Decode-step attention over a PAGED KV cache, read through the
     page table inside one kernel: ``q`` (slots, heads * d) single-token
     queries; ``key_pool`` / ``value_pool`` (pages, page_size, width) —
@@ -745,12 +781,18 @@ def flash_paged_decode_attention(q, key_pool, value_pool, tables,
     and reads no other: the work follows the live pages, not ``slots x
     max_pages``. A slot whose first table entry is the trash page is
     empty and gets zeros. The softmax is online over blocks of
-    ``_WALK_ROWS`` rows with float32 carries; the contractions take
+    ``_walk_rows`` rows with float32 carries; the contractions take
     their operands in the pool's dtype, accumulate in float32 and run
     at the default precision of where they are placed, as the gathered
     view's did. The reduction tree is this kernel's own: equal to
     ``gather_pages`` + dense softmax to rounding, not bit for bit
     (docs/DIVERGENCES.md).
+
+    The latent geometry: ``value_pool`` None and ``value_cols`` given.
+    A row of ``key_pool`` is the key of every head (``q`` is (slots,
+    heads * width): one group of ``width`` columns) and its leading
+    ``value_cols`` columns are the values: each page is copied once and
+    read as both. Returns the context (slots, heads * value_cols).
 
     Mosaic wants ``page_size`` a whole number of the pool dtype's
     sublane tiles and ``width`` of 128 lanes (:func:`paged_walk_fits`);
@@ -758,9 +800,13 @@ def flash_paged_decode_attention(q, key_pool, value_pool, tables,
     from . import interpret_mode
     if scale is None:
         scale = 1.0 / math.sqrt(q.shape[1] // heads)
+    if (value_pool is None) != (value_cols is not None):
+        raise ValueError('a value pool, or the key pool\'s value_cols')
     return _paged_walk(q, key_pool, value_pool, tables, positions,
                        heads=int(heads), scale=float(scale),
-                       interpret=interpret_mode())
+                       interpret=interpret_mode(),
+                       value_cols=None if value_cols is None
+                       else int(value_cols))
 
 
 def _sublane_tile(dtype):

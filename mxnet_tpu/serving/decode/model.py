@@ -739,6 +739,10 @@ _FAMILIES = {RNNLM.family: RNNLM, TransformerLM.family: TransformerLM}
 def model_from_config(family, config):
     """Factory the frozen-artifact loader dispatches through."""
     from . import cohere2  # noqa: F401  (registers its family)
+    if family == 'xing4_0':
+        # not imported with the package: a process that serves another
+        # family pays nothing for it
+        from . import xing4  # noqa: F401  (registers its family)
     cls = _FAMILIES.get(family)
     if cls is None:
         raise ValueError('unknown decode family %r (have %s)'

@@ -114,11 +114,16 @@ class PagedCacheSpec:
     """
 
     __slots__ = ('entries', 'page_size', 'max_pages', 'window',
-                 'window_pages', 'window_entries', 'slot_entries')
+                 'window_pages', 'window_entries', 'slot_entries',
+                 'entries_per_layer')
 
     def __init__(self, entries, page_size, max_len, window=None,
-                 window_entries=(), slot_entries=None):
+                 window_entries=(), slot_entries=None,
+                 entries_per_layer=2):
         self.page_size = int(page_size)
+        # a K and a V entry an attention layer, or one entry of latent
+        # rows that are keys and values both
+        self.entries_per_layer = int(entries_per_layer)
         if self.page_size < 1 or (self.page_size
                                   & (self.page_size - 1)):
             raise ValueError('page_size must be a positive power of '
@@ -177,6 +182,8 @@ class PagedCacheSpec:
         if self.slot_entries:
             out['slot_entries'] = {k: [list(s), dt] for k, (s, dt)
                                    in self.slot_entries.items()}
+        if self.entries_per_layer != 2:
+            out['entries_per_layer'] = self.entries_per_layer
         return out
 
     @classmethod
@@ -188,7 +195,8 @@ class PagedCacheSpec:
                    window=obj.get('window'),
                    window_entries=obj.get('window_entries', ()),
                    slot_entries={k: (tuple(s), dt) for k, (s, dt) in
-                                 obj.get('slot_entries', {}).items()})
+                                 obj.get('slot_entries', {}).items()},
+                   entries_per_layer=obj.get('entries_per_layer', 2))
 
     def __repr__(self):
         return ('PagedCacheSpec(page_size=%d, max_pages=%d, %r)'
@@ -673,7 +681,8 @@ class _Kind:
     def __init__(self, name, columns, ring, layers, allocator, prefix):
         self.name = name
         self.columns = int(columns)
-        # attention layers of this kind (a layer has a K and a V entry)
+        # attention layers of this kind (a layer has a K and a V entry,
+        # or one entry of latent rows: spec.entries_per_layer)
         self.layers = int(layers)
         # a ring keeps logical page p in column p % columns and gives
         # back the page that falls behind; a table that is no ring has
@@ -751,7 +760,8 @@ class PageOwner:
             allocator = PageAllocator(pool_pages[name])
             self._kinds.append(_Kind(
                 name, columns, ring,
-                (window if ring else len(spec.entries) - window) // 2,
+                (window if ring else len(spec.entries) - window)
+                // spec.entries_per_layer,
                 allocator,
                 PrefixCache(spec.page_size, allocator)
                 if prefix_cache else None))

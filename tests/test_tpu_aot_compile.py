@@ -273,3 +273,85 @@ def test_the_granite_step_walks_the_table_and_gathers_no_view(granite_step):
     assert len(_kernel_calls(text)) == 1
     pool = '%d,%d,%d' % (G_SLOTS * spec.max_pages + 1, PAGE, width)
     assert not [n for n in _results(text, pool) if n.startswith('copy')]
+
+
+# ---------------------------------------------------------------------------
+# xing4_0: latent rows, one pool a layer, keys and values from one copy
+# ---------------------------------------------------------------------------
+
+X_SLOTS, X_MAX_LEN, X_PAGES = 48, 17408, 4097
+X_WIDTH, X_LATENT = 640, 512
+
+
+@pytest.fixture(scope='module')
+def xing4_step(chip, one_chip):
+    """``Xing4LM.paged_step`` at the published widths of one dense and one
+    expert layer (hidden 3584 in four streams, 32 heads of 128 + 64 on a
+    latent of 512 + 64, queries of rank 768), 48 slots whose tables have
+    the served 1088 columns; two held experts and a small vocabulary keep
+    the compile to seconds."""
+    import jax
+    from mxnet_tpu.serving.decode.paged import pool_avals
+    from mxnet_tpu.serving.decode.xing4 import Xing4LM
+    model = Xing4LM(dict(
+        vocab=1024, max_len=X_MAX_LEN, hidden=3584, layers=2,
+        dense_layers=1, eps=1e-6, heads=32, q_rank=768, kv_rank=X_LATENT,
+        nope_dim=128, rope_dim=64, v_dim=128, dense_hidden=9216,
+        experts=64, held_experts=[0, 1], top_k=4, expert_hidden=1024,
+        shared_hidden=1024, routed_scale=2.0, hc_mult=4, hc_iters=20,
+        hc_eps=1e-6, hc_clamp=(-30.0, 30.0), rope_theta=10000.0,
+        yarn=dict(factor=64, original_max=4096, beta_fast=32, beta_slow=1,
+                  mscale=1, mscale_all_dim=1)))
+
+    def on(tree):
+        return jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                           sharding=one_chip), tree)
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, 'int32', sharding=one_chip)
+
+    spec = model.paged_spec(PAGE)
+    params = on(jax.eval_shape(lambda: model.init_params(0)))
+    pool = on(pool_avals(spec, X_PAGES))
+    compiled = _compiled(model.paged_step, chip, params, pool,
+                         i32(X_SLOTS), i32(X_SLOTS),
+                         i32(X_SLOTS, spec.max_pages))
+    return spec, compiled.as_text()
+
+
+def test_the_xing4_step_walks_latent_pages_one_kernel_a_layer(xing4_step):
+    spec, text = xing4_step
+    assert spec.max_pages == 1088 and sorted(spec.entries) == ['l0_c',
+                                                               'l1_c']
+    # one kernel an attention layer, reading its one pool
+    assert len(_kernel_calls(text)) == 2
+    assert text.count('mxnet_tpu_paged_decode_walk') >= 2
+    assert 'kv_gather' not in text
+    # no array of slots x max_pages x page_size rows, latent or padded,
+    # in any shape: neither a gathered view nor the values cut out of it
+    assert not _arrays_of(text, X_SLOTS * X_MAX_LEN * X_WIDTH,
+                          (X_WIDTH, X_LATENT))
+    assert not _arrays_of(text, X_SLOTS * X_MAX_LEN * X_LATENT,
+                          (X_LATENT, 128))
+
+
+def test_the_xing4_step_makes_no_second_copy_of_a_latent_page(xing4_step):
+    spec, text = xing4_step
+    aliases = re.search(r'input_output_alias=\{(.*?)\}, entry', text).group(1)
+    assert len(re.findall(r'\{\d+\}: \(\d+, \{\}, may-alias\)', aliases)) \
+        == len(spec.entries)
+    pool = '%d,%d,%d' % (X_PAGES, PAGE, X_WIDTH)
+    assert not [n for n in _results(text, pool) if n.startswith('copy')]
+    # nothing of a pool's size with the values' 512 columns: the values
+    # are read out of the page's one VMEM copy, not sliced out in HBM
+    assert not _results(text, '%d,%d,%d' % (X_PAGES, PAGE, X_LATENT))
+    # the append is one scatter a pool
+    assert len(re.findall(r' scatter\(', text)) == len(spec.entries)
+    # the kernel takes five operands: table, positions and next live slot
+    # (prefetched scalars), the query rows, and one pool (GPT-1's and
+    # Granite's take a K and a V pool: six)
+    calls = re.findall(r'custom-call\(([^)]*)\), custom_call_target='
+                       r'"tpu_custom_call"', text)
+    assert len(calls) == 2 and all(len(c.split(', ')) == 5 for c in calls), \
+        calls

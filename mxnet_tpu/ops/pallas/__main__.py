@@ -197,6 +197,43 @@ def run_selftest(out=None):
         return {'pool': [pages, ps, W], 'heads': H, 'groups': groups,
                 'dtype': jnp.dtype(dtype).name, 'err': err}
 
+    def check_latent_walk(slots, L, ps, pos, heads=32, width=640,
+                          values=512, used=576):
+        """The walk's latent geometry (one pool; every head scores the
+        whole row and reads its leading ``values`` columns) over
+        consecutive tables, every chunk one copy, and over the same
+        rows laid out shuffled, every page a copy of its own: one
+        result bit for bit, and gather + dense softmax to rounding."""
+        bf16 = jnp.bfloat16
+        per = L // ps
+        pages = slots * per + 1
+        keep = (jnp.arange(width) < used).astype(f32)
+        pool = (randn(pages, ps, width) * keep).astype(bf16)
+        q = (0.2 * randn(slots, heads, width) * keep).astype(bf16)
+        pos = jnp.asarray(pos, 'int32')
+        perm = 1 + rs.permutation(pages - 1)
+        tables = {
+            'consecutive': (pool, 1 + onp.arange(pages - 1)),
+            'shuffled': (pool.at[perm].set(pool[1:]), perm)}
+        walk = jax.jit(lambda q, pool, t: flash_paged_decode_attention(
+            q.reshape(slots, -1), pool, None, t, pos, heads=heads,
+            scale=1.0, value_cols=values))
+        got = {name: walk(q, pool, jnp.asarray(
+            ids.reshape(slots, per), 'int32'))
+            for name, (pool, ids) in tables.items()}
+        assert bool(jnp.array_equal(got['consecutive'], got['shuffled'])), \
+            'where the pages lie changed the result'
+        rows = pool[1:].reshape(slots, L, width).astype(f32)
+        seen = jnp.arange(L)[None] <= pos[:, None]
+        with jax.default_matmul_precision('highest'):
+            sc = jnp.einsum('shw,slw->shl', q.astype(f32), rows)
+            att = jax.nn.softmax(jnp.where(seen[:, None], sc, -1e9), -1)
+            want = jnp.einsum('shl,slc->shc', att, rows[..., :values])
+        err = amax(got['consecutive'].reshape(slots, heads, values), want)
+        assert err < BF16, 'latent walk err %g' % err
+        return {'pool': [pages, ps, width], 'heads': heads,
+                'values': values, 'err': err, 'bitwise': True}
+
     _check('flash_decode_attention vs dense softmax',
            lambda: check_decode(3, 40, 32, 4, [5, 0, 39]),
            failures, results)
@@ -206,6 +243,10 @@ def run_selftest(out=None):
     _check('flash_paged_decode_attention, grouped queries bf16',
            lambda: check_paged_decode(3, 64, 512, 4, 16, [5, 0, 63],
                                       dtype=jnp.bfloat16, groups=2),
+           failures, results)
+
+    _check('flash_paged_decode_attention, latent rows, runs == pages',
+           lambda: check_latent_walk(3, 1280, 16, [1279, 0, 700]),
            failures, results)
 
     def check_decode_token_streams():
@@ -399,6 +440,12 @@ def run_selftest(out=None):
                'bf16',
                lambda: check_paged_decode(8, 512, 4096, 32, 16, pos,
                                           dtype=bf16, groups=8),
+               failures, results)
+        _check('full width: flash_paged_decode_attention latent 32 x 640 '
+               'bf16, consecutive and shuffled',
+               lambda: check_latent_walk(
+                   8, 16384, 16,
+                   [16383, 0, 8200, 1023, 1024, 12345, 16, 4095]),
                failures, results)
         for dt in (bf16, f32):
             _check('full width: fused_softmax_xent V=30522 %s'

@@ -563,30 +563,77 @@ def _walk_rows(pools, width, dtype):
     return 1 << (fit.bit_length() - 1)
 
 
-def mxnet_tpu_paged_decode_walk(tables_ref, pos_ref, next_ref, q_ref,
-                                *refs, page_size, block_pages,
+def walk_block_pages(pools, page_size, width, dtype):
+    """Pages of one compute block of the walk over ``pools`` pools of
+    ``width`` columns of ``dtype``."""
+    return max(1, _walk_rows(pools, width, dtype) // page_size)
+
+
+# Pages one copy moves where a table's entries are consecutive. A page
+# copied alone costs 37.5 ns to issue and wait for, whatever its bytes,
+# and the scalar loops that do it stand in front of the contractions:
+# at 20 KB a page that is 2.35 ns a row where the bytes are 1.56. Copies
+# of 2 / 4 / 8 / 16 pages all move the bytes at 735 GB/s, one copy of a
+# whole block of 64 is 5 % slower (PERF.md section 6, PR 37)
+_RUN_PAGES = 8
+
+
+def walk_copy_runs(xp, tables, positions, page_size, block_pages,
+                   trash_page):
+    """The walk's copy schedule, read off the tables: which aligned
+    chunks of ``_RUN_PAGES`` table columns one copy a pool can move.
+
+    Chunk ``c`` of slot ``s`` is a run where its entries are
+    consecutive pages (``tables[s, i + 1] == tables[s, i] + 1`` across
+    it) and all of it lies at or below the slot's last live page; every
+    other live page is copied alone, so any table is served and the
+    result does not depend on where pages lie. ``xp`` is ``jax.numpy``
+    (the kernel's flags, computed beside it) or ``numpy``
+    (``PageOwner.step_copies``' count): one statement of the rule.
+
+    Returns ``(run, copies)``: ``run`` (slots, whole chunks of the
+    table) bool; ``copies`` (slots,) the copies a pool that the slot's
+    walk issues: its live pages (none for an empty slot: first entry
+    the trash page), each run counted once."""
+    chunk = min(_RUN_PAGES, block_pages)
+    whole = tables.shape[1] // chunk
+    tops = xp.where(tables[:, 0] != trash_page,
+                    positions // page_size + 1, 0)
+    inside = tables[:, :whole * chunk]     # a table's tail is no run
+    follows = (inside[:, 1:] - inside[:, :-1]) == 1
+    run = (xp.arange(1, whole + 1) * chunk)[None] <= tops[:, None]
+    for i in range(chunk - 1):
+        run = run & follows[:, i::chunk]
+    return run, tops - (chunk - 1) * run.sum(-1)
+
+
+def mxnet_tpu_paged_decode_walk(tables_ref, pos_ref, next_ref, runs_ref,
+                                q_ref, *refs, page_size, block_pages,
                                 max_pages, group_rows, group_width,
                                 trash_page):
     """One slot per program: the slot's query rows attend the pages its
     table names, up to its position and no further.
 
-    ``tables_ref`` (slots * max_pages,), ``pos_ref`` (slots,) and
+    ``tables_ref`` (slots * max_pages,), ``pos_ref`` (slots,),
     ``next_ref`` (slots + 1,: the first live slot at or after each
-    index) are scalar-prefetched. ``refs``: the pools (pages,
+    index) and ``runs_ref`` (slots * blocks,: :func:`walk_copy_runs`'
+    bits) are scalar-prefetched. ``refs``: the pools (pages,
     page_size, width), left where they are; the output; a VMEM buffer a
-    pool; the copies' semaphores and the buffer parity. Keys are read
+    pool, two blocks in the pool's own (pages, page_size, width) shape;
+    the copies' semaphores and the buffer parity. Keys are read
     from the first pool and values from the last one's leading columns,
     as many as the output is wide: with a K and a V pool those are all
     of V's, with one pool (latent rows) a row's values are its own
     leading columns, taken from the one VMEM copy of the page that its
     keys are read from. A slot at position
     ``p`` walks ``p // page_size + 1`` pages in blocks of
-    ``block_pages``: page copies into one of two VMEM buffers, the next
+    ``block_pages``: copies into one of two VMEM buffers, the next
     block's in flight while this block computes, across the slot
     boundary too (``parity_ref`` carries the buffer in use from one
-    program to the next; the grid runs in order). A slot whose first
-    table entry is the trash page is empty: it copies nothing and
-    writes zeros.
+    program to the next; the grid runs in order). A chunk of
+    ``_RUN_PAGES`` consecutive pages is one copy a pool and one wait,
+    any other page a copy of its own. A slot whose first table entry is
+    the trash page is empty: it copies nothing and writes zeros.
 
     No head is split out of a page. ``q_ref`` (1, rep, width) holds, in
     row ``r``, the queries of the ``groups`` heads that are the
@@ -609,22 +656,44 @@ def mxnet_tpu_paged_decode_walk(tables_ref, pos_ref, next_ref, q_ref,
     rep, width = q_ref.shape[1], q_ref.shape[2]
     out_width = o_ref.shape[2]
 
+    run_pages = min(_RUN_PAGES, block_pages)
+    blocks = _cdiv(max_pages, block_pages)
+
     def block_copies(fn, slot, j, buf):
-        """``fn`` on every pool's copy of every page of block ``j``
-        of ``slot``, into (or, waiting, out of) buffer ``buf``."""
+        """``fn`` on every pool's every copy of block ``j`` of
+        ``slot``, into (or, waiting, out of) buffer ``buf``."""
         first = j * block_pages
         count = jnp.minimum(block_pages,
                             pos_ref[slot] // ps + 1 - first)
+        runs = runs_ref[slot * blocks + j]
 
-        def one_page(p, _):
-            page = tables_ref[slot * max_pages + first + p]
-            dst = pl.ds(pl.multiple_of(p * ps, ps), ps)
+        def copy(at, pages):
+            page = tables_ref[slot * max_pages + first + at]
             for i in range(pools):
                 fn(pltpu.make_async_copy(
-                    hbm[i].at[page], bufs[i].at[buf, dst], sems.at[buf, i]))
+                    hbm[i].at[pl.ds(page, pages)],
+                    bufs[i].at[buf, pl.ds(at, pages)], sems.at[buf, i]))
+
+        def one_page(p, _):
+            copy(p, 1)
             return _
 
-        jax.lax.fori_loop(0, count, one_page, 0)
+        def one_chunk(c, _):
+            at = pl.multiple_of(c * run_pages, run_pages)
+            run = (runs >> c) & 1 == 1
+
+            @pl.when(run)
+            def _run():
+                copy(at, run_pages)
+
+            @pl.when(jnp.logical_not(run))
+            def _pages():
+                jax.lax.fori_loop(
+                    at, jnp.minimum(at + run_pages, count), one_page, 0)
+            return _
+
+        jax.lax.fori_loop(0, (count + run_pages - 1) // run_pages,
+                          one_chunk, 0)
 
     def start(slot, j, buf):
         @pl.when(slot < nslots)
@@ -655,32 +724,37 @@ def mxnet_tpu_paged_decode_walk(tables_ref, pos_ref, next_ref, q_ref,
             [jnp.where(own, q_ref[0, r:r + 1, :], 0.0)
              for r in range(rep)], axis=0).astype(kbuf.dtype)
 
-        def body(j, carry):
+        def block(j, carry, then_slot, then_block, masked):
+            """Block ``j``: the next copies (this slot's next block, or
+            the next live slot's first) go out before this block's are
+            waited for. Only a slot's last block has rows past its
+            position: the selects are taken there alone."""
             m, l, acc, buf = carry
-            # the next block's copies go out before this block's are
-            # waited for: this slot's next, or the next live slot's first
-            last = j + 1 == nblocks
-            start(jnp.where(last, next_ref[s + 1], s),
-                  jnp.where(last, 0, j + 1), 1 - buf)
+            start(then_slot, then_block, 1 - buf)
             block_copies(lambda c: c.wait(), s, j, buf)
-            kb, vb = kbuf[buf], vbuf[buf][:, :out_width]
+            kb = kbuf[buf].reshape(rows, width)
+            vb = vbuf[buf].reshape(rows, width)[:, :out_width]
             sc = jax.lax.dot_general(
                 qx, kb, (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32)   # (heads, rows)
-            at = j * rows + jax.lax.broadcasted_iota(
-                jnp.int32, (heads_rows, rows), 1)
-            sc = jnp.where(at <= pos, sc, _NEG_INF)
-            seen = j * rows + jax.lax.broadcasted_iota(
-                jnp.int32, (rows, 1), 0) <= pos
-            vb = jnp.where(seen, vb, jnp.zeros_like(vb))
+            if masked:
+                at = j * rows + jax.lax.broadcasted_iota(
+                    jnp.int32, (heads_rows, rows), 1)
+                sc = jnp.where(at <= pos, sc, _NEG_INF)
+                seen = j * rows + jax.lax.broadcasted_iota(
+                    jnp.int32, (rows, 1), 0) <= pos
+                vb = jnp.where(seen, vb, jnp.zeros_like(vb))
             m, l, acc = _online_block_cols(sc, vb, m, l, acc)
             return m, l, acc, 1 - buf
 
         m0 = jnp.full((heads_rows, 1), _NEG_INF, jnp.float32)
         l0 = jnp.zeros((heads_rows, 1), jnp.float32)
         a0 = jnp.zeros((heads_rows, out_width), jnp.float32)
-        _m, l, acc, buf = jax.lax.fori_loop(
-            0, nblocks, body, (m0, l0, a0, parity_ref[0]))
+        carry = jax.lax.fori_loop(
+            0, nblocks - 1,
+            lambda j, carry: block(j, carry, s, j + 1, False),
+            (m0, l0, a0, parity_ref[0]))
+        _m, l, acc, buf = block(nblocks - 1, carry, next_ref[s + 1], 0, True)
         parity_ref[0] = buf
         ctx = acc / jnp.maximum(l, 1e-20)
         for r in range(rep):
@@ -707,8 +781,7 @@ def _paged_walk(q, key_pool, value_pool, tables, positions, *, heads,
     max_pages = tables.shape[1]
     # one pool: a row's values are its own leading columns
     pools = (key_pool,) if value_pool is None else (key_pool, value_pool)
-    block_pages = max(
-        1, _walk_rows(len(pools), width, key_pool.dtype) // ps)
+    block_pages = walk_block_pages(len(pools), ps, width, key_pool.dtype)
     # sublane tiles: a group's heads fill whole float32 tiles, and the
     # heads together whole tiles of the pool's dtype
     tile = _sublane_tile(key_pool.dtype)
@@ -728,6 +801,15 @@ def _paged_walk(q, key_pool, value_pool, tables, positions, *, heads,
         reverse=True)
     nxt = jnp.concatenate(
         [first_live_from, jnp.full((1,), slots, jnp.int32)])
+    # bit c of block j: chunk c of it is a run
+    run, _copies = walk_copy_runs(jnp, tables, positions, ps, block_pages,
+                                  TRASH_PAGE)
+    per_block = block_pages // min(_RUN_PAGES, block_pages)
+    blocks = _cdiv(max_pages, block_pages)
+    runs = jnp.sum(
+        jnp.pad(run, ((0, 0), (0, blocks * per_block - run.shape[1])))
+        .reshape(slots, blocks, per_block).astype(jnp.int32)
+        << jnp.arange(per_block, dtype=jnp.int32), axis=-1)
     kern = functools.partial(
         mxnet_tpu_paged_decode_walk, page_size=ps,
         block_pages=block_pages, max_pages=max_pages,
@@ -737,11 +819,11 @@ def _paged_walk(q, key_pool, value_pool, tables, positions, *, heads,
         return pl.BlockSpec((1, rep, w), lambda s, *_: (s, 0, 0),
                             memory_space=pltpu.VMEM)
 
-    buf = pltpu.VMEM((2, block_pages * ps, width), key_pool.dtype)
+    buf = pltpu.VMEM((2, block_pages, ps, width), key_pool.dtype)
     out = pl.pallas_call(
         kern,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3,
+            num_scalar_prefetch=4,
             grid=(slots,),
             in_specs=[rows_of(width)]
             + [pl.BlockSpec(memory_space=pl.ANY)] * len(pools),
@@ -756,7 +838,7 @@ def _paged_walk(q, key_pool, value_pool, tables, positions, *, heads,
         name='mxnet_tpu_paged_decode_walk',
         interpret=interpret,
     )(tables.reshape(-1).astype(jnp.int32), positions.astype(jnp.int32),
-      nxt, q3, *pools)
+      nxt, runs.reshape(-1), q3, *pools)
     if value_pool is None:
         return out.reshape(slots, heads * out_width)
     return out.reshape(slots, rep, groups, d).transpose(

@@ -1384,7 +1384,8 @@ class DecodeEngine:
                 self.slots, ((slot, seq.pages)
                              for slot, seq in active.items()))
             kv_pages = self._pages.step_pages(
-                self.slots, [seq.pos for seq in active.values()])
+                self.slots, [seq.pos for seq in active.values()]) \
+                + (self._pages.step_copies(tables, positions),)
             extras = self._step_extras(active)
         self._cache, toks, _logits = self._device(
             self.program.run_step, self._cache, tokens, positions,
@@ -1402,8 +1403,9 @@ class DecodeEngine:
 
     def _emit_paged_step(self, active, toks, dt, kv_pages):
         """Advance positions, stream each slot's token, book the step
-        (``kv_pages``: the pages its attention had to read and those
-        of a gathered view, ``PageOwner.step_pages``)."""
+        (``kv_pages``: the pages its attention had to read, those of
+        a gathered view and the copies of a walk,
+        ``PageOwner.step_pages`` and ``step_copies``)."""
         emitted = 0
         sampled = 0
         sampled_step = _samples(active)
@@ -1434,6 +1436,7 @@ class DecodeEngine:
             self._counts['sampled_tokens'] += sampled
             self._counts['kv_pages_walked'] += kv_pages[0]
             self._counts['kv_pages_view'] += kv_pages[1]
+            self._counts['kv_page_copies'] += kv_pages[2]
             if self._step_stats:
                 # what the device counted came back behind the tokens
                 for name, v in self.program.last_step_stats.items():

@@ -729,8 +729,9 @@ class PageOwner:
     counter dict: ``prefix_hits``, ``prefix_tokens_saved``,
     ``page_evictions``, ``cow_copies`` and, with a ring,
     ``window_pages_released`` are booked here and nowhere else
-    (``kv_pages_walked`` and ``kv_pages_view`` are reckoned here,
-    :meth:`step_pages`, and booked by the scheduler with its step).
+    (``kv_pages_walked``, ``kv_pages_view`` and ``kv_page_copies`` are
+    reckoned here, :meth:`step_pages` and :meth:`step_copies`, and
+    booked by the scheduler with its step).
     ``event(kind, **fields)`` takes the flight recorder's
     ``page_alloc`` and ``page_evict``. Worker thread only, but for the
     readers and :meth:`drop`.
@@ -769,9 +770,19 @@ class PageOwner:
         self._registers = bool(prefix_cache)
         # pages of one slot's gathered views, over all attention layers
         self._view_pages = sum(k.layers * k.columns for k in self._kinds)
+        # pages of one block of the step's walk, None where the step
+        # gathers: off a TPU (:func:`walks_pages`), and every layer of
+        # a cache that has a ring (cohere2.py)
+        self._walk_block = None
+        (width, *more), dtype = next(iter(spec.entries.values()))
+        if not self._rings and not more and walks_pages(
+                (0, spec.page_size, width), dtype):
+            from ...ops.pallas.attention import walk_block_pages
+            self._walk_block = walk_block_pages(
+                spec.entries_per_layer, spec.page_size, width, dtype)
         for name in ('prefix_hits', 'prefix_tokens_saved',
-                     'page_evictions', 'cow_copies',
-                     'kv_pages_walked', 'kv_pages_view'):
+                     'page_evictions', 'cow_copies', 'kv_pages_walked',
+                     'kv_pages_view', 'kv_page_copies'):
             counts.setdefault(name, 0)
         if self._rings:
             counts.setdefault('window_pages_released', 0)
@@ -1038,6 +1049,25 @@ class PageOwner:
         walked = sum(k.layers * sum(min(t, k.columns) for t in tops)
                      for k in self._kinds)
         return walked, slots * self._view_pages
+
+    def step_copies(self, tables, positions):
+        """The copies one step's walks issue over all attention layers,
+        a K and a V page counted once as :meth:`step_pages` counts
+        them: ``tables`` as :meth:`tables` handed them out and
+        ``positions`` (slots,) are the step's own operands, and the
+        rule is the kernel's (``ops.pallas.attention.walk_copy_runs``:
+        a chunk of consecutive pages is one copy). 0 where the step
+        gathers. The scheduler books it as ``kv_page_copies``:
+        ``kv_pages_walked`` over it is the pages a copy moves."""
+        if self._walk_block is None:
+            return 0
+        from ...ops.pallas.attention import walk_copy_runs
+        # columns past the highest live page hold no copy
+        live = int(positions.max()) // self.page_size + 1
+        _run, copies = walk_copy_runs(
+            onp, tables[:, :live], positions, self.page_size,
+            self._walk_block, TRASH_PAGE)
+        return self._kinds[0].layers * int(copies.sum())
 
     def first_pages(self, rec, n_tokens):
         """The pages that hold positions ``[0, n_tokens)`` of ``rec``

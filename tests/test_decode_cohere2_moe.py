@@ -514,8 +514,8 @@ def test_one_kind_programs_are_what_they_were():
 
 
 _COUNTS = ['adapter_rejects', 'cow_copies', 'drain_timeouts',
-           'fallback_tokens', 'handoff_pages', 'kv_pages_view',
-           'kv_pages_walked', 'migrated_in', 'migrated_out',
+           'fallback_tokens', 'handoff_pages', 'kv_page_copies',
+           'kv_pages_view', 'kv_pages_walked', 'migrated_in', 'migrated_out',
            'page_evictions', 'pool_exhausted', 'prefill_exports', 'prefills',
            'prefix_hits', 'prefix_tokens_saved', 'rejected', 'requests',
            'retired', 'sampled_steps', 'sampled_tokens', 'spec_accepted',

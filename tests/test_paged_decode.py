@@ -1271,6 +1271,61 @@ def test_kv_pages_walked_and_view_follow_the_positions():
     assert counts['kv_pages_view'] == \
         counts['steps'] * slots * (48 // ps) * model.layers
     assert 0 < counts['kv_pages_walked'] < counts['kv_pages_view']
+    assert counts['kv_page_copies'] == 0      # the CPU rig's step gathers
+
+
+def test_kv_page_copies_are_the_copies_the_kernels_flags_give(monkeypatch):
+    """``PageOwner.step_copies`` counts the copies of a step's walks by
+    the kernel's own rule (``ops.pallas.attention.walk_copy_runs``: a
+    chunk of consecutive pages is one copy): hand-made tables through
+    the owner's numpy and through the kernel's ``jax.numpy`` give one
+    count, a layer; nothing where no layer walks."""
+    import jax.numpy as jnp
+    from mxnet_tpu.ops.pallas.attention import (_RUN_PAGES,
+                                                walk_block_pages,
+                                                walk_copy_runs)
+    from mxnet_tpu.serving.decode import paged
+    from mxnet_tpu.serving.decode.paged import PageOwner
+    layers, ps, width = 3, 16, 768
+    spec = PagedCacheSpec(
+        {'l%d_%s' % (i, kv): ((width,), 'float32')
+         for i in range(layers) for kv in 'kv'}, ps, 64 * ps)
+
+    def owner(spec=spec, pools={'full': 400}):
+        return PageOwner(spec, pools, threading.Lock(), False, {})
+
+    gathers = owner()
+    monkeypatch.setattr(paged, 'walks_pages', lambda shape, dtype: True)
+    walks = owner()
+    block = walk_block_pages(2, ps, width, 'float32')
+    assert block == 16 and _RUN_PAGES == 8
+    rows = [list(range(1, 41)),                   # one run: 5 chunks
+            list(range(100, 108)) + [300, 120, 121, 122, 123],
+            [],                                   # an empty slot
+            list(range(90, 70, -1)),              # descending: 20 pages
+            [7], []]
+    positions = np.asarray([40 * ps - 1, 13 * ps - 5, 0, 20 * ps - 16, 3,
+                            0], 'int32')
+    tables = np.full((6, 64), TRASH_PAGE, 'int32')
+    for slot, row in enumerate(rows):
+        tables[slot, :len(row)] = row
+    assert walks.step_copies(tables, positions) \
+        == layers * (5 + (1 + 5) + 20 + 1)
+    # the kernel's flags for the step's operands: the same rule, traced
+    run, copies = walk_copy_runs(jnp, jnp.asarray(tables),
+                                 jnp.asarray(positions), ps, block,
+                                 TRASH_PAGE)
+    assert [int(x) for x in copies] == [5, 6, 0, 20, 1, 0]
+    assert [int(x) for x in run.sum(-1)] == [5, 1, 0, 0, 0, 0]
+    # where the step gathers, nothing: the CPU rig, and a cache with a
+    # ring on a TPU too (cohere2.py gathers its full layers as well)
+    assert gathers.step_copies(tables, positions) == 0
+    ring = PagedCacheSpec(
+        {'l0_k': ((width,), 'float32'), 'l0_v': ((width,), 'float32'),
+         'l1_k': ((width,), 'float32'), 'l1_v': ((width,), 'float32')},
+        ps, 64 * ps, window=32, window_entries=('l0_k', 'l0_v'))
+    assert owner(ring, {'full': 40, 'window': 20}).step_copies(
+        {'full': tables, 'window': tables[:, :4]}, positions) == 0
 
 
 def test_step_pages_counts_a_ring_no_further_than_its_columns():

@@ -18,6 +18,8 @@ import jax.numpy as jnp
 
 from mxnet_tpu.ops.pallas import (flash_paged_decode_attention,
                                   paged_walk_fits)
+from mxnet_tpu.ops.pallas.attention import (_RUN_PAGES, walk_block_pages,
+                                            walk_copy_runs)
 from mxnet_tpu.serving.decode.paged import TRASH_PAGE, gather_pages
 
 PAGE, MAX_PAGES = 16, 40                  # three blocks of 16 pages a table
@@ -72,7 +74,7 @@ def _dense(q, keys, values, tables, positions, geometry):
     rounds them)."""
     heads, groups, d, dtype, scale, _tol = GEOMETRY[geometry]
     s = q.shape[0]
-    seen = jnp.arange(MAX_PAGES * PAGE)[None] <= positions[:, None]
+    seen = jnp.arange(tables.shape[1] * PAGE)[None] <= positions[:, None]
     k = jnp.where(seen[:, :, None],
                   gather_pages(keys, tables).astype('float32'), 0.0)
     v = jnp.where(seen[:, :, None],
@@ -152,17 +154,18 @@ def test_which_pool_geometries_mosaic_takes():
     assert not paged_walk_fits(16, 32, 'float32')     # a toy's 32 columns
 
 
-# What the walk traces to at GPT-1's and Granite's geometries, as PR 35
-# measured it on the chip (`gpt1-chat-steady`, `gpt1-batch-saturated`,
+# What the walk traces to at GPT-1's and Granite's geometries, as PR 37
+# measured it on the chip (copies by run; `gpt1-chat-steady`,
 # `granite-h-small-reasoning-saturated`): sha256 of the kernel's jaxpr
-# with source locations stripped, on this repo's one installation. A
-# change for another geometry must leave these alone; a change meant for
-# these is measured on their cells again, and then the pins move.
+# with source locations stripped, on this repo's one installation. PR 36
+# pinned PR 35's to show it left these two geometries alone. A change
+# for another geometry must leave these alone; a change meant for these
+# is measured on their cells again, and then the pins move.
 WALK_JAXPR = {
     'gpt1-12x64-f32':
-        '90e87968691ce905c0ea8cf3ba104383078f3e77e3f0fa530c85f20bb50bc88a',
+        '5b1d3b9deeb412c3f7df2bc120e22fcf7e06dcf38c177754ff55818b0a716af6',
     'granite-32on8x128-bf16':
-        '05299415182b60ea8761970f704b6fc9cfe54111ed9c3beac70870dfc15977e8',
+        'cfc1d5a65404e7927e74b2b4f9f38a7af7b1a8a83f8c77fac107fa71802e17d9',
 }
 
 
@@ -198,7 +201,7 @@ def _latent_dense(q, pool, tables, positions):
     every head scores the whole row and reads its first L_VALUES
     columns."""
     s = q.shape[0]
-    seen = jnp.arange(MAX_PAGES * PAGE)[None] <= positions[:, None]
+    seen = jnp.arange(tables.shape[1] * PAGE)[None] <= positions[:, None]
     rows = jnp.where(seen[:, :, None],
                      gather_pages(pool, tables).astype('float32'), 0.0)
     qh = q.astype('float32').reshape(s, L_HEADS, L_WIDTH)
@@ -278,3 +281,164 @@ def test_the_latent_geometry_is_one_mosaic_takes_padded():
         flash_paged_decode_attention(
             jnp.zeros((1, L_WIDTH)), jnp.zeros((2, PAGE, L_WIDTH)), None,
             jnp.zeros((1, 2), 'int32'), jnp.zeros((1,), 'int32'), heads=1)
+
+
+# ---------------------------------------------------------------------------
+# copies by run (PR 37): a chunk of consecutive pages is one copy a pool;
+# where the pages lie changes no bit of the result
+# ---------------------------------------------------------------------------
+
+LAYOUTS = ('one-run', 'broken-mid-chunk-and-at-an-edge', 'descending',
+           'shared-prefix-then-scattered', 'empty-slots-between',
+           'last-block-under-a-chunk')
+LATENT_GEOMETRY = 'xing4-32x640-bf16'
+
+
+def _block_pages(geometry):
+    if geometry == LATENT_GEOMETRY:
+        return walk_block_pages(1, PAGE, L_WIDTH, 'bfloat16')
+    _heads, groups, d, dtype, _scale, _tol = GEOMETRY[geometry]
+    return walk_block_pages(2, PAGE, groups * d, dtype)
+
+
+def _lay(layout, b):
+    """(positions, tables) of a layout of pages over blocks of ``b``
+    pages: page ids from 1, the trash page everywhere else; an empty
+    slot's position is None."""
+    def run(first, n):
+        return list(range(first, first + n))
+
+    r = _RUN_PAGES
+    rows = {
+        # whole blocks and a tail of a whole chunk and 3 pages; one
+        # block; less than a chunk
+        'one-run': [run(1, 2 * b + r + 3), run(400, b), run(600, 5)],
+        # a break inside chunk 0, then runs; a break at a chunk's edge
+        'broken-mid-chunk-and-at-an-edge': [
+            run(1, 3) + run(50, b + 2 * r - 3), run(400, r) + run(500, 2 * r)],
+        # what a freed sequence's pages give back
+        'descending': [run(1, b + 5)[::-1], run(300, 2 * r)[::-1]],
+        'shared-prefix-then-scattered': [
+            run(10, b + r + 4) + [301, 299, 305, 290, 291],
+            run(10, b + r + 4) + [400, 300, 403]],
+        'empty-slots-between': [run(1, b + 1), [], [], run(200, 2 * r), []],
+        # a last block of fewer pages than a chunk
+        'last-block-under-a-chunk': [run(1, b + 3), run(200, 2),
+                                     run(300, r - 1)],
+    }[layout]
+    tables = np.full((len(rows), max(MAX_PAGES, max(map(len, rows)))),
+                     TRASH_PAGE, 'int32')
+    for s, row in enumerate(rows):
+        tables[s, :len(row)] = row
+    # somewhere in the last page, its end too
+    positions = [len(row) * PAGE - 1 - (5 * s) % PAGE if row else None
+                 for s, row in enumerate(rows)]
+    return positions, tables
+
+
+def _flags(tables, positions, block_pages):
+    """The scalars ``_paged_walk`` hands the kernel: bit ``c`` of block
+    ``j``."""
+    run, _copies = walk_copy_runs(np, tables, np.asarray(positions), PAGE,
+                                  block_pages, TRASH_PAGE)
+    per_block = block_pages // min(_RUN_PAGES, block_pages)
+    blocks = -(-tables.shape[1] // block_pages)
+    run = np.pad(run, ((0, 0), (0, blocks * per_block - run.shape[1])))
+    return (run.reshape(len(run), blocks, per_block)
+            << np.arange(per_block)).sum(-1)
+
+
+def _copies_by_hand(tables, positions, block_pages):
+    """The copies a pool that the kernel's loops issue, a slot: the
+    flags read the way ``block_copies`` reads them."""
+    runs = _flags(tables, positions, block_pages)
+    chunk = min(_RUN_PAGES, block_pages)
+    out = []
+    for s, pos in enumerate(positions):
+        n = 0
+        if tables[s, 0] != TRASH_PAGE:
+            for j in range(pos // (block_pages * PAGE) + 1):
+                count = min(block_pages, pos // PAGE + 1 - j * block_pages)
+                for c in range(-(-count // chunk)):
+                    n += 1 if runs[s, j] >> c & 1 \
+                        else min(chunk, count - c * chunk)
+        out.append(n)
+    return out
+
+
+@pytest.mark.parametrize('layout', LAYOUTS)
+@pytest.mark.parametrize('geometry', sorted(GEOMETRY) + [LATENT_GEOMETRY])
+def test_where_the_pages_lie_changes_no_bit(geometry, layout):
+    """The walk over tables with runs equals, bit for bit, the same walk
+    over a pool whose pages were permuted so that no two of a table are
+    consecutive (the same rows in the same order, every copy a single
+    page: the parent's schedule), and gather + dense softmax to
+    rounding."""
+    latent = geometry == LATENT_GEOMETRY
+    block_pages = _block_pages(geometry)
+    positions, tables = _lay(layout, block_pages)
+    live = np.asarray([p is not None for p in positions])
+    pos = np.asarray([p or 0 for p in positions], 'int32')
+    rs = np.random.RandomState(len(layout))
+    pages = int(tables.max()) + 1
+    if latent:
+        tol, width = 2e-2, L_WIDTH
+        pool = rs.randn(pages, PAGE, width)
+        pool[..., L_USED:] = 0.0
+        pools = (jnp.asarray(pool, 'bfloat16'),)
+        q = rs.randn(len(positions), L_HEADS, width) * 0.2
+        q[..., L_USED:] = 0.0
+        q = jnp.asarray(q.reshape(len(positions), -1), 'bfloat16')
+
+        def walk(pools, tables):
+            return _latent_walk(q, pools[0], jnp.asarray(tables), pos)
+        want = _latent_dense(q, pools[0], jnp.asarray(tables),
+                             jnp.asarray(pos))
+    else:
+        heads, groups, d, dtype, _scale, tol = GEOMETRY[geometry]
+        pools = tuple(jnp.asarray(rs.randn(pages, PAGE, groups * d), dtype)
+                      for _ in range(2))
+        q = jnp.asarray(rs.randn(len(positions), heads * d), dtype)
+
+        def walk(pools, tables):
+            return _walk(q, *pools, jnp.asarray(tables), jnp.asarray(pos),
+                         geometry)
+        want = _dense(q, *pools, jnp.asarray(tables), jnp.asarray(pos),
+                      geometry)
+    # the layout has what its name says: runs where there should be some
+    run, copies = walk_copy_runs(np, tables, pos, PAGE, block_pages,
+                                 TRASH_PAGE)
+    walked = np.where(live, pos // PAGE + 1, 0)
+    assert list(copies) == _copies_by_hand(tables, pos, block_pages)
+    assert (copies <= walked).all() and not copies[~live].any()
+    if layout == 'descending':
+        assert list(copies) == list(walked) and not run.any()
+    else:
+        assert copies.sum() < walked.sum()
+    # page p of the pool goes to 2 p: no table entry follows another
+    apart = tuple(jnp.zeros((2 * pages,) + x.shape[1:], x.dtype)
+                  .at[::2].set(x) for x in pools)
+    assert not walk_copy_runs(np, 2 * tables, pos, PAGE, block_pages,
+                              TRASH_PAGE)[0].any()
+    got = np.asarray(walk(pools, tables))
+    assert np.array_equal(got, np.asarray(walk(apart, 2 * tables)))
+    assert np.isfinite(got).all() and not got[~live].any()
+    np.testing.assert_allclose(got[live], np.asarray(want)[live],
+                               rtol=tol, atol=tol)
+
+
+def test_the_kernels_flags_are_the_owners_count():
+    """``walk_copy_runs`` through ``jax.numpy`` (the kernel's flags) and
+    through ``numpy`` (``PageOwner.step_copies``) is one rule."""
+    for layout in LAYOUTS:
+        for b in (1, 4, 16, 64):
+            positions, tables = _lay(layout, b)
+            pos = np.asarray([p or 0 for p in positions], 'int32')
+            run, copies = walk_copy_runs(np, tables, pos, PAGE, b,
+                                         TRASH_PAGE)
+            jrun, jcopies = jax.jit(
+                lambda t, p, b=b: walk_copy_runs(jnp, t, p, PAGE, b,
+                                                 TRASH_PAGE))(tables, pos)
+            assert np.array_equal(run, np.asarray(jrun))
+            assert np.array_equal(copies, np.asarray(jcopies))
+            assert list(copies) == _copies_by_hand(tables, pos, b)

@@ -348,10 +348,10 @@ def test_the_xing4_step_makes_no_second_copy_of_a_latent_page(xing4_step):
     assert not _results(text, '%d,%d,%d' % (X_PAGES, PAGE, X_LATENT))
     # the append is one scatter a pool
     assert len(re.findall(r' scatter\(', text)) == len(spec.entries)
-    # the kernel takes five operands: table, positions and next live slot
-    # (prefetched scalars), the query rows, and one pool (GPT-1's and
-    # Granite's take a K and a V pool: six)
+    # the kernel takes six operands: table, positions, next live slot and
+    # the chunks that are runs (prefetched scalars), the query rows, and
+    # one pool (GPT-1's and Granite's take a K and a V pool: seven)
     calls = re.findall(r'custom-call\(([^)]*)\), custom_call_target='
                        r'"tpu_custom_call"', text)
-    assert len(calls) == 2 and all(len(c.split(', ')) == 5 for c in calls), \
+    assert len(calls) == 2 and all(len(c.split(', ')) == 6 for c in calls), \
         calls

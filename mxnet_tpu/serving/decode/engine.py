@@ -157,6 +157,10 @@ class GenerateStream:
         self._exc = None
         self._cancelled = False
         self._outbox = None             # the admitting engine's
+        # once a server's stream pump adopts the stream, its put: every
+        # later item goes there, in order (serving/stream_pump.py)
+        self._sink = None
+        self._route = threading.Lock()
 
     # -- consumer side -----------------------------------------------------
 
@@ -205,12 +209,65 @@ class GenerateStream:
         self._put(_DONE)
 
     def _put(self, item):
-        """Hand ``item`` to whoever iterates this stream: at once, or,
-        from the scheduler thread of the engine that admitted it,
-        through that engine's outbox (:class:`_Outbox`)."""
+        """Hand ``item`` to whoever reads this stream: at once, or, from
+        the scheduler thread of the engine that admitted it, through that
+        engine's outbox (:class:`_Outbox`). The reader is the stream's own
+        queue until a stream pump adopts the stream, the pump after."""
         box = self._outbox
-        if box is None or not box.take(self._q, item):
+        if box is not None and box.take(self, item):
+            return
+        if self._sink is None and self._put_own(item):
+            return
+        self._sink([(self, item)])
+
+    def _put_own(self, item):
+        """Put ``item`` on the stream's own queue and return True, unless a
+        pump has adopted the stream (False: the item is the pump's)."""
+        with self._route:
+            if self._sink is not None:
+                return False
             self._q.put(item)
+            return True
+
+    # -- a stream pump's side (serving/stream_pump.py): a reader gets
+    # the tokens as ints, then one end mark ------------------------------
+
+    def _adopt(self, sink, adoption):
+        """Route every later item to ``sink``, after ``adoption``, which
+        goes first: the pump then takes what the stream's own queue got
+        before (:meth:`_take_queued`), and nothing lands there after
+        (:meth:`_put_own`)."""
+        with self._route:
+            sink([(self, adoption)])
+            self._sink = sink
+
+    def _take_queued(self):
+        """What the stream's own queue holds: (tokens, whether the end
+        mark came)."""
+        tokens = []
+        while True:
+            try:
+                item = self._q.get_nowait()
+            except _queue.Empty:
+                return tokens, False
+            if item is _DONE:
+                return tokens, True
+            tokens.append(item)
+
+    def _forward(self, item):
+        """Put a pumped ``item`` on the stream's own queue, for a handler
+        that took the stream back; True for the end mark."""
+        self._q.put(item)
+        return item is _DONE
+
+    def _disown(self):
+        """Take the stream back from a pump that failed: later items land
+        on its own queue, and a reader of that queue finds an end mark
+        (the one a cancelled stream gets, or one here if it ended)."""
+        with self._route:
+            self._sink = None
+            if self._done.is_set():
+                self._q.put(_DONE)
 
 
 class _Outbox:
@@ -228,25 +285,37 @@ class _Outbox:
     sequences to other threads. ``stream.tokens``, ``finish_reason``
     and ``done()`` are up to date at once: only the wake-up waits.
     Other threads (the reaper, ``close``, the CPU fallback) put
-    directly."""
+    directly.
+
+    The items of streams a server's stream pump has adopted go to the
+    pump as one batch a flush: one wake-up for all of them, not one a
+    token (serving/stream_pump.py)."""
 
     def __init__(self):
         self.owner = None               # the scheduler thread's ident
         self.items = []
         self.flushed = False            # since the tick began
 
-    def take(self, queue, item):
+    def take(self, stream, item):
         if threading.get_ident() != self.owner:
             return False
-        self.items.append((queue, item))
+        self.items.append((stream, item))
         return True
 
     def flush(self):
         """Scheduler thread only."""
         self.flushed = True
         items, self.items = self.items, []
-        for queue, item in items:
-            queue.put(item)
+        batches = {}
+        for stream, item in items:
+            if stream._sink is None and stream._put_own(item):
+                continue
+            batch = batches.get(stream._sink)
+            if batch is None:
+                batch = batches[stream._sink] = []
+            batch.append((stream, item))
+        for sink, batch in batches.items():
+            sink(batch)
 
 
 class _Seq:
@@ -393,7 +462,12 @@ class DecodeEngine:
                         # the share of its ticks in which the scheduler
                         # held the interpreter
                         'worker_cpu_s': 0.0, 'worker_wall_s': 0.0,
-                        'stream_cpu_s': 0.0, 'slow_ticks': 0}
+                        'stream_cpu_s': 0.0, 'slow_ticks': 0,
+                        # a server's stream pump (serving/stream_pump.py):
+                        # wake-ups that sent, tokens, sends, streams
+                        # handed back to their handlers with bytes unsent
+                        'pump_batches': 0, 'pump_tokens': 0,
+                        'pump_sends': 0, 'pump_fallbacks': 0}
         # live-migration requests serviced by the worker at tick
         # boundaries (the only thread that owns the device cache):
         # (op, arg, result_box, done_event)
@@ -807,6 +881,18 @@ class DecodeEngine:
         stream (``ServingHTTPServer``'s NDJSON relay)."""
         with self._lock:
             self._counts['stream_cpu_s'] += seconds
+
+    def note_pump(self, tokens, sends, fallbacks, cpu_s):
+        """A stream pump books one wake-up: the tokens it sent, its sends,
+        the streams it handed back with bytes unsent, and its thread's CPU
+        seconds since the last wake-up (``stream_cpu_s``)."""
+        with self._lock:
+            counts = self._counts
+            counts['pump_batches'] += 1 if sends else 0
+            counts['pump_tokens'] += tokens
+            counts['pump_sends'] += sends
+            counts['pump_fallbacks'] += fallbacks
+            counts['stream_cpu_s'] += cpu_s
 
     def _retire_abandoned(self):
         """Free slots whose stream is already done (timeout reaper or

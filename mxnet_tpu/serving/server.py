@@ -47,6 +47,28 @@ from .freeze import FrozenProgram
 __all__ = ['InferenceSession', 'ServingHTTPServer',
            'maybe_start_http_server']
 
+
+def _chunked(data):
+    """``data`` as one HTTP/1.1 chunk."""
+    return b'%x\r\n%s\r\n' % (len(data), data)
+
+
+def _done_line(stream, request_id):
+    """The last NDJSON line of a sound stream, as a chunk."""
+    done = {'done': True, 'tokens': stream.tokens,
+            'finish_reason': stream.finish_reason,
+            'degraded': stream.degraded}
+    # prefill_only admission: the exported seqstate rides the done line
+    # so the gateway can POST it straight to a decode-class replica (no
+    # /drain round-trip — this replica stays healthy)
+    seqst = getattr(stream, 'seqstate', None)
+    if seqst is not None:
+        done['seqstate'] = seqst
+    if request_id is not None:
+        done['request_id'] = request_id
+    return _chunked((json.dumps(done, sort_keys=True) + '\n').encode())
+
+
 # ceiling on an HTTP handler's wait when MXNET_TPU_SERVE_TIMEOUT_S=0
 # disables the per-request budget: handler threads must never block
 # forever (ThreadingHTTPServer wedges one thread per connection)
@@ -541,6 +563,10 @@ class ServingHTTPServer:
         # distinct sites; the site label resolves with the port
         self._trace_buf = _trace.SpanBuffer(site='replica:%d'
                                             % self.port)
+        # one writer thread for every stream's tokens, made at the first
+        # stream (serving/stream_pump.py)
+        self._pump = None
+        self._pump_lock = threading.Lock()
 
     def start(self):
         if self._httpd is not None:
@@ -635,7 +661,7 @@ class ServingHTTPServer:
             def _chunk_line(handler, data):
                 # one write a chunk: one system call, one segment, one
                 # wake-up of whoever reads the stream
-                handler.wfile.write(b'%x\r\n%s\r\n' % (len(data), data))
+                handler.wfile.write(_chunked(data))
                 handler.wfile.flush()
 
             def _generate(handler, req):
@@ -722,23 +748,29 @@ class ServingHTTPServer:
                 fetches the exported seqstate from /drain and splices
                 the continuation into the same client stream.
 
-                Each token's chunk is a ``srv.stream.write`` span, from
-                the token leaving the stream's queue to its chunk
-                flushed, and the end ``srv.stream.done``: on the
-                profiler's clock alone, so a device-idle gap over which
-                a writer was mid-token names the writers, not where the
-                scheduler waited for the interpreter
+                The server's stream pump writes the tokens, and at a
+                sound end the done line and the last chunk, where it can
+                adopt the connection (serving/stream_pump.py); this
+                thread waits for the stream to come back, then writes
+                what the pump could not: bytes a full window left, the
+                tokens after them, a typed error line. Each token's
+                chunk this thread writes is a ``srv.stream.write`` span,
+                from the token leaving the stream's queue to its chunk
+                flushed, and the end it writes ``srv.stream.done``: on
+                the profiler's clock alone, so a device-idle gap over
+                which a writer was mid-token names the writers, not
+                where the scheduler waited for the interpreter
                 (docs/OBSERVABILITY.md). No span covers the wait for a
-                token. The thread's CPU
-                seconds over the stream go to the engine's
-                ``stream_cpu_s``."""
+                token. The thread's CPU seconds over the stream go to
+                the engine's ``stream_cpu_s``."""
                 cpu0 = time.thread_time()
                 try:
-                    handler._relay(stream, start_index, request_id)
+                    handler._relay(stream, start_index, request_id,
+                                   srv._stream_pump(engine))
                 finally:
                     engine.note_stream_cpu(time.thread_time() - cpu0)
 
-            def _relay(handler, stream, start_index, request_id):
+            def _relay(handler, stream, start_index, request_id, pump):
                 handler.send_response(200)
                 handler.send_header('Content-Type',
                                     'application/x-ndjson')
@@ -747,7 +779,26 @@ class ServingHTTPServer:
                 write = _span.profiler_only('srv.stream.write')
                 end = _span.profiler_only('srv.stream.done')
                 try:
-                    for i, tok in enumerate(stream):
+                    tokens, index = stream, start_index
+                    back = pump.adopt(
+                        stream, handler.connection, start_index,
+                        lambda: (_done_line(stream, request_id)
+                                 + b'0\r\n\r\n'))
+                    if back is not None:
+                        if back.gone:
+                            return      # the pump cancelled the stream
+                        if back.unsent:
+                            with write:
+                                handler.wfile.write(back.unsent)
+                                handler.wfile.flush()
+                        if back.finished:
+                            return      # the pump wrote the end
+                        if back.failed is not None:
+                            raise back.failed
+                        if back.ended:
+                            raise stream.exception()
+                        index = back.index
+                    for tok in tokens:
                         with write:
                             # byte for byte what _chunk writes for
                             # {'token': tok, 'index': ...}, without an
@@ -755,23 +806,14 @@ class ServingHTTPServer:
                             # stream
                             handler._chunk_line(
                                 b'{"index": %d, "token": %d}\n'
-                                % (start_index + i, tok))
+                                % (index, tok))
+                        index += 1
+                    if back is not None and back.failed is not None:
+                        raise back.failed
                     with end:
-                        done = {'done': True,
-                                'tokens': stream.tokens,
-                                'finish_reason': stream.finish_reason,
-                                'degraded': stream.degraded}
-                        # prefill_only admission: the exported
-                        # seqstate rides the done line so the gateway
-                        # can POST it straight to a decode-class
-                        # replica (no /drain round-trip — this replica
-                        # stays healthy)
-                        seqst = getattr(stream, 'seqstate', None)
-                        if seqst is not None:
-                            done['seqstate'] = seqst
-                        if request_id is not None:
-                            done['request_id'] = request_id
-                        handler._chunk(done)
+                        handler.wfile.write(_done_line(stream,
+                                                       request_id))
+                        handler.wfile.flush()
                 except OSError:
                     # client went away mid-stream: retire the
                     # sequence so it stops occupying a decode slot,
@@ -1198,7 +1240,21 @@ class ServingHTTPServer:
         except Exception:
             pass
 
+    def _stream_pump(self, engine):
+        """The server's stream pump, writing for ``engine``: one a server,
+        which streams from one decode engine."""
+        from .stream_pump import StreamPump
+        name = 'serving-%d' % self.port
+        with self._pump_lock:
+            if self._pump is None:
+                self._pump = StreamPump(engine, name=name)
+            return self._pump
+
     def stop(self):
+        with self._pump_lock:
+            if self._pump is not None:
+                self._pump.stop()
+                self._pump = None
         self._preempt_stop.set()
         if self._preempt_thread is not None:
             self._preempt_thread.join(timeout=2.0)

@@ -518,7 +518,8 @@ _COUNTS = ['adapter_rejects', 'cow_copies', 'drain_timeouts',
            'gc_pause_s', 'handoff_pages', 'kv_page_copies', 'kv_pages_view',
            'kv_pages_walked', 'migrated_in', 'migrated_out', 'page_evictions',
            'pool_exhausted', 'prefill_exports', 'prefills', 'prefix_hits',
-           'prefix_tokens_saved', 'rejected', 'requests', 'retired',
+           'prefix_tokens_saved', 'pump_batches', 'pump_fallbacks',
+           'pump_sends', 'pump_tokens', 'rejected', 'requests', 'retired',
            'sampled_steps', 'sampled_tokens', 'slow_ticks', 'spec_accepted',
            'spec_proposed', 'spec_rounds', 'steps', 'stream_cpu_s',
            'timeouts', 'tokens', 'worker_cpu_s', 'worker_wall_s']

@@ -397,8 +397,8 @@ def test_engine_tick_and_its_phases_in_a_profiler_trace(tmp_path):
     """A jax.profiler trace of a few ticks behind the HTTP server:
     eng.tick with its phases nested inside it on one thread, no phase
     twice under one tick (eng.tick.admit apart: one an admission); the
-    handler threads open one srv.stream.write a token and nothing of
-    the engine's."""
+    server's stream pump opens one srv.stream.write a wake-up, on one
+    thread of its own, and nothing of the engine's."""
     import urllib.request
     from mxnet_tpu import serving
     from mxnet_tpu.serving.decode import PagedDecodeProgram
@@ -435,8 +435,11 @@ def test_engine_tick_and_its_phases_in_a_profiler_trace(tmp_path):
         'eng.tick.read_tokens', 'eng.tick.emit', 'eng.tick.telemetry'}
     assert len({e[0] for e in ours}) == 1      # the worker thread alone
     writes = [e for e in events if e[1] == 'srv.stream.write']
-    assert len(writes) == 3 * 8 and not any(e[4] for e in writes)
-    assert len([e for e in events if e[1] == 'srv.stream.done']) >= 3
+    # a wake-up carries a tick's tokens, or what an adoption found
+    assert 0 < len(writes) <= 3 * 8 + 3 and not any(e[4] for e in writes)
+    assert len({e[0] for e in writes}) == 1
+    # the pump writes each stream's end in the same send
+    assert not [e for e in events if e[1] == 'srv.stream.done']
     assert not {e[0] for e in writes} & {e[0] for e in ours}
     ticks = [e for e in ours if e[1] == 'eng.tick']
     assert len(ticks) >= 5
@@ -497,12 +500,15 @@ def _status(port):
 
 def test_stream_writer_opens_one_span_a_token_under_a_session(
         fake_annotation):
-    """A served stream opens one ``srv.stream.write`` a token on its
-    handler thread and ``srv.stream.done`` at its end while a profiler
-    session runs, and none of either without one."""
+    """A served stream's tokens are written by the server's stream pump
+    under one ``srv.stream.write`` a wake-up, on the pump's thread and
+    never on the worker's, with each stream's end in the same sends (no
+    handler opens ``srv.stream.done``), while a profiler session runs;
+    none without one."""
     sess, srv = _toy_server()
     try:
         _post_stream(srv.port, [1, 2, 3], 4)          # compile first
+        before = _status(srv.port)['decode']['counts']
         fake_annotation.enabled = True
         del fake_annotation.log[:]
         threads = [threading.Thread(target=_post_stream,
@@ -512,14 +518,19 @@ def test_stream_writer_opens_one_span_a_token_under_a_session(
             t.start()
         for t in threads:
             t.join(60)
+        fake_annotation.enabled = False
+        after = _status(srv.port)['decode']['counts']
         opened = [e for e in fake_annotation.log if e[0] == 'open']
         writes = [e for e in opened if e[1] == 'srv.stream.write']
-        assert len(writes) == 2 * 7
-        assert len({e[2] for e in writes}) == 2       # two handlers
-        assert len([e for e in opened if e[1] == 'srv.stream.done']) >= 2
+        batches = after['pump_batches'] - before['pump_batches']
+        assert after['pump_tokens'] - before['pump_tokens'] == 2 * 7
+        # one a wake-up; an adoption may wake the pump with nothing to
+        # send yet
+        assert 0 < batches <= len(writes) <= batches + 2
+        assert {e[2] for e in writes} == {srv._pump._thread.ident}
+        assert not [e for e in opened if e[1] == 'srv.stream.done']
         worker = {e[2] for e in opened if e[1] == 'eng.tick'}
         assert len(worker) == 1 and not worker & {e[2] for e in writes}
-        fake_annotation.enabled = False
         del fake_annotation.log[:]
         _post_stream(srv.port, [2, 7], 5)
         assert not [e for e in fake_annotation.log if e[0] == 'open']

@@ -612,9 +612,10 @@ def test_headerless_generate_with_tracing_off_builds_nothing(
 def test_stream_write_spans_never_reach_a_span_buffer(replica, traced,
                                                       monkeypatch, session):
     """The writers' spans are the profiler's alone: with request tracing
-    on, a streamed request's handler opens one ``srv.stream.write`` a
-    token under a profiler session and none without one, and adds no
-    record of them to the process's span buffer or the server's."""
+    on, a streamed request's writer (the server's stream pump) opens one
+    ``srv.stream.write`` a wake-up under a profiler session and none
+    without one, and adds no record of them to the process's span buffer
+    or the server's."""
     from mxnet_tpu.observability import spans
     opened = []
 
@@ -638,7 +639,9 @@ def test_stream_write_spans_never_reach_a_span_buffer(replica, traced,
                                           'max_new_tokens': 5,
                                           'stream': True})
     assert len(tokens) == 5 and done['finish_reason'] == 'length'
-    assert opened.count('srv.stream.write') == (5 if session else 0)
+    # at most one a token, and one for the adoption
+    writes = opened.count('srv.stream.write')
+    assert 0 < writes <= 5 + 1 if session else writes == 0
     recs = trace.get_buffer().read() + _replica_spans(replica, 1)
     assert 'srv.generate' in [r['name'] for r in recs]
     assert not [r for r in recs if 'srv.stream' in r['name']]

@@ -82,6 +82,54 @@ _DONE = object()          # stream sentinel
 _SLOW_TICK_S = 1.0
 
 
+class _RunLength:
+    """How many decode steps the next call of the step program runs:
+    ``max_run`` while the server is saturated and the host's own time a
+    tick is at least the device's time a step, else 1.
+
+    A call of n steps pays the tick's host time (the read, the loops over
+    the slots, the emit, one flush to the streams) once for n tokens a
+    slot. Where the step outlasts the host, the host already hides behind
+    it and a longer call would only hold tokens back; below saturation,
+    a call's last steps would keep a slot that finished early from the
+    next arrival. Both times are moving averages of what the scheduler
+    observes: the host's is a tick's wall less the time it blocked in
+    reads, the device's the step call's read over its steps. The rule
+    engages at a ratio of 1 and lets go below ``RELEASE``, so that n does
+    not flap."""
+
+    ALPHA = 0.2
+    RELEASE = 0.75
+
+    def __init__(self, max_run):
+        self.max_run = int(max_run)
+        self.host_s = self.step_s = None
+        self.engaged = False
+
+    def observe(self, host_s, step_s):
+        """One tick that ran the step: its host seconds, and the device
+        seconds of one of its steps."""
+        if self.host_s is None:
+            self.host_s, self.step_s = host_s, step_s
+        else:
+            a = self.ALPHA
+            self.host_s += a * (host_s - self.host_s)
+            self.step_s += a * (step_s - self.step_s)
+        floor = self.RELEASE if self.engaged else 1.0
+        self.engaged = self.host_s >= floor * self.step_s
+
+    def steps(self, live, pending, free, interleave):
+        """The steps of the tick that opens with ``live`` sequences,
+        ``pending`` requests and ``free`` slots: saturated when its
+        admissions (``interleave`` a step of the run, every free slot
+        where none is live) leave no slot free or a request waiting."""
+        if not self.engaged:
+            return 1
+        admits = min(interleave * self.max_run if live else free,
+                     pending, free)
+        return self.max_run if admits == free or pending > admits else 1
+
+
 def _knob(name, default):
     try:
         from ... import config as _config
@@ -467,7 +515,13 @@ class DecodeEngine:
                         # wake-ups that sent, tokens, sends, streams
                         # handed back to their handlers with bytes unsent
                         'pump_batches': 0, 'pump_tokens': 0,
-                        'pump_sends': 0, 'pump_fallbacks': 0}
+                        'pump_sends': 0, 'pump_fallbacks': 0,
+                        # calls of the step program, those that ran more
+                        # than one step, and the slot-steps a run spent
+                        # on slots that had finished or were past their
+                        # budget (``steps`` counts every step a call ran)
+                        'step_calls': 0, 'multi_step_calls': 0,
+                        'slot_steps_idle': 0}
         # live-migration requests serviced by the worker at tick
         # boundaries (the only thread that owns the device cache):
         # (op, arg, result_box, done_event)
@@ -525,6 +579,15 @@ class DecodeEngine:
             # compiled or loaded before now keep the names they have)
             draft.module_prefix = 'draft_'
             self.spec_k = spec_k
+        # decode steps a call: the paged step runs up to its program's
+        # ``max_run`` where no draft proposes (_RunLength); ``_run_n`` is
+        # the current tick's, ``_step_read`` its step call's (read
+        # seconds, steps)
+        self._runs = _RunLength(
+            getattr(program, 'max_run', 1)
+            if self.paged and draft is None else 1)
+        self._run_n = 1
+        self._step_read = None
         # multi-adapter serving: the id -> pool-index registry. The
         # program must have been frozen with an adapter_spec (the pool
         # argument is part of its compiled signature); ``adapters``
@@ -799,12 +862,13 @@ class DecodeEngine:
                 self._outbox.flush()
                 return None
             return (self._counts['steps'], len(self._active),
-                    len(self._pending))
+                    len(self._pending), len(self._free))
 
-    def _tick(self, step, active, pending):
+    def _tick(self, step, active, pending, free):
         """One scheduler iteration: retire finished/abandoned slots,
         service migration requests, admit prefills, advance the live
-        batch one token.
+        batch ``n`` tokens in one call (:class:`_RunLength`; admissions
+        ``prefill_interleave`` a step of it).
 
         The tick and each of its phases is a host span on the
         profiler's clock (``eng.tick`` / ``eng.tick.*``, worker thread
@@ -812,17 +876,21 @@ class DecodeEngine:
         in. ``step`` is the id ``eng.queue_wait`` / ``eng.prefill``
         carry as ``tick``; ``wall`` ties the profiler's clock to the
         span buffer's."""
+        n = self._runs.steps(active, pending, free,
+                             self.prefill_interleave)
         with _span('eng.tick', step=step, active=active,
-                   pending=pending, wall=time.time()):
+                   pending=pending, n=n, wall=time.time()):
             mark = (time.thread_time(), time.perf_counter(),
                     getattr(self.program, 'read_s', 0.0),
                     _spans.gc_pause_total())
+            self._run_n = n
+            self._step_read = None
             self._outbox.flushed = False
             with _span('eng.tick.retire'):
                 self._retire_abandoned()
             with _span('eng.tick.migrate'):
                 self._service_migrations()
-            budget = self.prefill_interleave if self._active \
+            budget = self.prefill_interleave * n if self._active \
                 else self.slots
             while budget > 0:
                 with self._lock:
@@ -862,6 +930,10 @@ class DecodeEngine:
         also leaves its parts in the flight recorder."""
         cpu = time.thread_time() - cpu0
         wall = time.perf_counter() - wall0
+        read = getattr(self.program, 'read_s', 0.0) - read0
+        if self._step_read is not None and self._runs.max_run > 1:
+            step_read, n = self._step_read
+            self._runs.observe(wall - read, step_read / n)
         slow = wall > _SLOW_TICK_S
         with self._lock:
             self._counts['worker_cpu_s'] += cpu
@@ -872,8 +944,7 @@ class DecodeEngine:
             _record_event(
                 'decode_slow_tick', step=step, wall_s=round(wall, 6),
                 cpu_s=round(cpu, 6),
-                read_s=round(getattr(self.program, 'read_s', 0.0)
-                             - read0, 6),
+                read_s=round(read, 6),
                 gc_pause_s=round(_spans.gc_pause_total() - gc0, 6))
 
     def note_stream_cpu(self, seconds):
@@ -1162,11 +1233,11 @@ class DecodeEngine:
 
     def _sampling_extras(self, active, spec_c=0, off=0):
         """Per-slot sampling arrays for one step call at absolute
-        positions ``pos + off`` (or one verify call: ``spec_c`` keys
-        per slot at ``pos .. pos + spec_c - 1``, exactly the keys the
-        plain path would burn at those positions). {} with no live
-        slot that samples: the program fills in the same neutral
-        values (greedy rows)."""
+        positions ``pos + off`` (or one verify call, or one call of a
+        run of steps: ``spec_c`` keys per slot at ``pos .. pos + spec_c
+        - 1``, exactly the keys the plain path would burn at those
+        positions). {} with no live slot that samples: the program
+        fills in the same neutral values (greedy rows)."""
         if not _samples(active):
             return {}
         temps = onp.zeros(self.slots, 'float32')
@@ -1186,8 +1257,8 @@ class DecodeEngine:
         return {'temps': temps, 'top_ps': top_ps, 'keys': keys}
 
     def _step_extras(self, active, spec_c=0):
-        """Per-slot sampling/adapter arrays for one step or verify
-        call of the target program."""
+        """Per-slot sampling/adapter arrays for one step, run or
+        verify call of the target program."""
         kw = self._sampling_extras(active, spec_c)
         if self._adapters is not None:
             aidx = onp.zeros(self.slots, 'int32')
@@ -1449,6 +1520,7 @@ class DecodeEngine:
         sampled_step = _samples(active)
         with self._lock:
             self._counts['steps'] += 1
+            self._counts['step_calls'] += 1
             self._counts['sampled_steps'] += sampled_step
             self._counts['tokens'] += len(active)
             self._ema_step_s = dt if self._ema_step_s is None \
@@ -1501,16 +1573,20 @@ class DecodeEngine:
     def _page_faults(self, active, lookahead=0):
         """Pre-step page maintenance for every live slot: lazy
         allocation at boundary crossings + copy-on-write of shared
-        pages. Pool exhaustion fails THAT stream typed and drops it
-        from this tick; device errors propagate to the caller."""
+        pages, for the positions from ``pos`` to ``pos + lookahead``
+        that the call writes (``lookahead`` one number, or one a slot).
+        Pool exhaustion fails THAT stream typed and drops it from this
+        tick; device errors propagate to the caller."""
         self._pages.advance(
             (seq.pages, seq.pos) for seq in active.values()
             if not (seq.stream.done() or seq.stream._cancelled))
         for slot, seq in list(active.items()):
             if seq.stream.done() or seq.stream._cancelled:
                 continue
+            ahead = lookahead[slot] if isinstance(lookahead, dict) \
+                else lookahead
             if not self._pages.make_writable(
-                    seq.pages, seq.pos, seq.pos + lookahead,
+                    seq.pages, seq.pos, seq.pos + ahead,
                     self._copy_page):
                 self._fail_pool_exhausted(seq, slot, where='step')
                 self._retire(slot, seq, 'error')
@@ -1518,14 +1594,19 @@ class DecodeEngine:
         return active
 
     def _paged_step(self, active):
-        """One decode step through the page tables. Extension slots
+        """One call of the step program through the page tables: ``n``
+        steps, this tick's (:class:`_RunLength`). Extension slots
         (prefix hits still consuming their prompt suffix) feed prompt
         tokens and emit nothing until the last prompt token's logits
         produce their first generated token. Returns what emits the
-        step's tokens, None where no sequence is left to step."""
+        call's tokens, None where no sequence is left to step."""
         t0 = self._clock()
+        n = self._run_n
         with _span('eng.tick.page_faults'):
-            active = self._page_faults(active)
+            budgets = self._budgets(active, n) if n > 1 else None
+            active = self._page_faults(
+                active, {slot: b - 1 for slot, b in budgets.items()}
+                if budgets else 0)
         if not active:
             return None
         with _span('eng.tick.build_inputs'):
@@ -1537,13 +1618,23 @@ class DecodeEngine:
             tables = self._pages.tables(
                 self.slots, ((slot, seq.pages)
                              for slot, seq in active.items()))
-            kv_pages = self._pages.step_pages(
-                self.slots, [seq.pos for seq in active.values()]) \
-                + (self._pages.step_copies(tables, positions),)
-            extras = self._step_extras(active)
+            run = {}
+            live = [seq.pos for seq in active.values()]
+            if budgets:
+                run = self._run_inputs(active, budgets)
+                live = [seq.pos + k for slot, seq in active.items()
+                        for k in range(budgets[slot])]
+            walked, view = self._pages.step_pages(self.slots, live)
+            # a run's copies are reckoned from its first step's operands
+            kv_pages = (walked, n * view,
+                        n * self._pages.step_copies(tables, positions))
+            extras = self._step_extras(active, spec_c=n if n > 1 else 0)
+        read0 = getattr(self.program, 'read_s', 0.0)
         toks, _logits = self._take_cache(self._device(
-            self.program.run_step, self._cache, tokens, positions,
-            tables, **extras))
+            self.program.run_steps, self._cache, tokens, positions,
+            tables, n, **run, **extras))
+        self._step_read = (getattr(self.program, 'read_s', 0.0) - read0,
+                           n)
         if self._draft is not None:
             # keep the draft's KV history in lockstep on
             # non-speculative ticks (extension / near-max_len):
@@ -1555,37 +1646,79 @@ class DecodeEngine:
         return lambda: self._emit_paged_step(active, toks,
                                              self._clock() - t0, kv_pages)
 
+    def _budgets(self, active, n):
+        """The steps each live slot takes in a call of ``n``: no further
+        than the step whose token ends it by length (``max_new`` or
+        ``max_len``), so that a slot writes no position past those its
+        pages were made writable for; 0 for a stream already done."""
+        last = self.program.max_len - 1
+        out = {}
+        for slot, seq in active.items():
+            if seq.stream.done() or seq.stream._cancelled:
+                out[slot] = 0
+                continue
+            extending = max(0, len(seq.prompt) - 1 - seq.pos)
+            out[slot] = min(n, extending + seq.max_new
+                            - len(seq.stream.tokens), last - seq.pos)
+        return out
+
+    def _run_inputs(self, active, budgets):
+        """``run_steps``' budgets and forced tokens for a call of several
+        steps: a slot still extending feeds the prompt's next tokens."""
+        budget = onp.zeros(self.slots, 'int32')
+        forced = onp.full((self.program.max_run, self.slots), -1, 'int32')
+        for slot, seq in active.items():
+            budget[slot] = b = budgets[slot]
+            prompt, pos = seq.prompt, seq.pos
+            for k in range(1, min(b, len(prompt) - pos)):
+                forced[k, slot] = prompt[pos + k]
+        return {'budgets': budget, 'forced': forced}
+
     def _emit_paged_step(self, active, toks, dt, kv_pages):
-        """Advance positions, stream each slot's token, book the step
-        (``kv_pages``: the pages its attention had to read, those of
-        a gathered view and the copies of a walk,
-        ``PageOwner.step_pages`` and ``step_copies``)."""
+        """Advance positions, stream each slot's tokens, book the call
+        (``toks`` (steps, slots); ``kv_pages``: the pages its attention
+        had to read, those of a gathered view and the copies of a walk,
+        ``PageOwner.step_pages`` and ``step_copies``). Each token's
+        finish checks run at its own position; a slot's tokens after its
+        finish are dropped and counted idle, as are the steps of a slot
+        whose stream was already done."""
+        n = len(toks)
+        rows = toks.tolist()
         emitted = 0
         sampled = 0
+        idle = 0
         sampled_step = _samples(active)
         for slot, seq in active.items():
             if seq.stream.done() or seq.stream._cancelled:
+                idle += n
                 continue            # retired at the next tick
-            fed_pos = seq.pos
-            seq.pos += 1
-            if fed_pos < seq.prompt_len - 1:
-                # extension: the fed token was a prompt token and the
-                # prediction is ignored; the next prompt token feeds
-                seq.last_token = int(seq.prompt[seq.pos])
-                continue
-            tok = int(toks[slot])
-            seq.last_token = tok
-            self._emit_token(seq, tok)
-            emitted += 1
-            if seq.temperature > 0:
-                sampled += 1
-            reason = self._finished_reason(seq, tok)
-            if reason is not None:
-                seq.stream._finish(reason)
-                self._retire(slot, seq, reason)
+            prompt_last = len(seq.prompt) - 1
+            for k in range(n):
+                fed_pos = seq.pos
+                seq.pos += 1
+                if fed_pos < prompt_last:
+                    # extension: the fed token was a prompt token and the
+                    # prediction is ignored; the next prompt token feeds
+                    seq.last_token = int(seq.prompt[seq.pos])
+                    continue
+                tok = rows[k][slot]
+                seq.last_token = tok
+                self._emit_token(seq, tok)
+                emitted += 1
+                if seq.temperature > 0:
+                    sampled += 1
+                reason = self._finished_reason(seq, tok)
+                if reason is not None:
+                    seq.stream._finish(reason)
+                    self._retire(slot, seq, reason)
+                    idle += n - 1 - k
+                    break
         with self._lock:
-            self._counts['steps'] += 1
-            self._counts['sampled_steps'] += sampled_step
+            self._counts['steps'] += n
+            self._counts['step_calls'] += 1
+            self._counts['multi_step_calls'] += n > 1
+            self._counts['slot_steps_idle'] += idle
+            self._counts['sampled_steps'] += sampled_step * n
             self._counts['tokens'] += emitted
             self._counts['sampled_tokens'] += sampled
             self._counts['kv_pages_walked'] += kv_pages[0]
@@ -1595,15 +1728,15 @@ class DecodeEngine:
                 # what the device counted came back behind the tokens
                 for name, v in self.program.last_step_stats.items():
                     self._counts[name] += v
-            self._ema_step_s = dt if self._ema_step_s is None \
-                else 0.7 * self._ema_step_s + 0.3 * dt
+            self._ema_step_s = dt / n if self._ema_step_s is None \
+                else 0.7 * self._ema_step_s + 0.3 * dt / n
         inst = _serving_instruments()
         if inst is not None:
-            inst.decode_steps.inc()
+            inst.decode_steps.inc(n)
             if sampled_step:
-                inst.sampled_steps.inc()
+                inst.sampled_steps.inc(n)
             inst.tokens.inc(emitted)
-            inst.tpot.observe(dt)
+            inst.tpot.observe(dt / n)
             if sampled:
                 inst.sampled_tokens.inc(sampled)
 
@@ -1716,6 +1849,7 @@ class DecodeEngine:
                 self._retire(slot, seq, reason)
         with self._lock:
             self._counts['steps'] += 1
+            self._counts['step_calls'] += 1
             self._counts['sampled_steps'] += sampled_step
             self._counts['spec_rounds'] += 1
             self._counts['spec_proposed'] += proposed_total

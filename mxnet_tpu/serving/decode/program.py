@@ -12,10 +12,12 @@ at freeze/warmup time, request time only *runs*. Two program kinds:
     (``lax.dynamic_update_slice``), emitting the first generated
     token.
   * **decode step** — exactly ONE fixed-shape executable: every
-    in-flight slot advances one token against the donated cache. The
-    shape never depends on which sequences are live, so continuous
-    batching joins/leaves without a single retrace. Total programs for
-    any workload: ``len(prefill ladder) + 1``.
+    in-flight slot advances one token against the donated cache (a
+    paged step up to ``MAX_RUN`` tokens a call, as many as a runtime
+    operand asks). The shape never depends on which sequences are live
+    or how many steps a call runs, so continuous batching joins/leaves
+    without a single retrace. Total programs for any workload:
+    ``len(prefill ladder) + 1``.
 
 Cache buffers are donated on accelerator backends — XLA updates the
 KV/state arrays in place instead of copying ``slots × max_len × units``
@@ -58,9 +60,13 @@ from . import paged as _paged
 while_device_runs = threading.local()
 
 __all__ = ['DecodeProgram', 'PagedDecodeProgram', 'freeze_decode',
-           'load_decode']
+           'load_decode', 'MAX_RUN']
 
 _DECODE_KIND = 'decode'
+
+# the most decode steps one call of a paged step program runs
+# (PagedDecodeProgram.run_steps)
+MAX_RUN = 4
 
 
 def _knob(name, default):
@@ -225,9 +231,8 @@ class DecodeProgram:
         rows = 1 if kind == 'prefill' else S
         extras['temps'] = jax.ShapeDtypeStruct((rows,), 'float32')
         extras['top_ps'] = jax.ShapeDtypeStruct((rows,), 'float32')
-        kshape = (S, self.spec_k + 1, 2) if kind == 'verify' \
-            else (rows, 2)
-        extras['keys'] = jax.ShapeDtypeStruct(kshape, 'uint32')
+        extras['keys'] = jax.ShapeDtypeStruct(self._keys_shape(kind),
+                                              'uint32')
         if self.logit_mask:
             extras['masks'] = jax.ShapeDtypeStruct((rows, V), 'float32')
         if self.adapter_spec is not None:
@@ -261,8 +266,7 @@ class DecodeProgram:
         extras['top_ps'] = (
             onp.ones((rows,), 'float32') if top_ps is None
             else onp.asarray(top_ps, 'float32').reshape(rows))
-        kshape = (S, self.spec_k + 1, 2) if kind == 'verify' \
-            else (rows, 2)
+        kshape = self._keys_shape(kind)
         extras['keys'] = (
             onp.zeros(kshape, 'uint32') if keys is None
             else onp.asarray(keys, 'uint32').reshape(kshape))
@@ -280,6 +284,13 @@ class DecodeProgram:
                     onp.zeros((S,), 'int32') if aidx is None
                     else onp.asarray(aidx, 'int32').reshape(S))
         return extras
+
+    def _keys_shape(self, kind):
+        """A call's PRNG keys: one a row, and one a position of the
+        verify's chunk."""
+        if kind == 'verify':
+            return (self.slots, self.spec_k + 1, 2)
+        return (1 if kind == 'prefill' else self.slots, 2)
 
     @staticmethod
     def _gather_ad(extras):
@@ -705,8 +716,13 @@ class DecodeProgram:
                   and manifest.get('platform') == jax.default_backend()
                   and manifest.get('sample_args',
                                    'logit_mask' in manifest))
+        # a paged step compiled for another run length, or before the
+        # ``steps`` operand, has another signature
+        step_ok = not manifest.get('paged') \
+            or manifest.get('max_run') == prog.max_run
         for key, fname in (manifest.get('programs') or {}).items():
-            if not env_ok:
+            if not env_ok or (key.split(':')[0] == 'step'
+                              and not step_ok):
                 prog.retraced_buckets.append(key)
                 continue
             try:
@@ -806,6 +822,13 @@ class PagedDecodeProgram(DecodeProgram):
                 model.family, 'paged_verify (speculative decoding, '
                 'spec_k > 0) over a window layer\'s ring of pages or a '
                 'recurrent state, which a rejected token cannot leave')
+        # decode steps one call of the step program may run: one where a
+        # longer call would lose what is one step's (the logits it emits,
+        # the mask it is given) or where the host gives a ring's pages
+        # back as the positions advance
+        self.max_run = 1 if (self.window_pages or self.emit_logits
+                             or self.logit_mask) else MAX_RUN
+        self._steps_operands = {}     # (n, devices) -> _uniform_steps
 
     # -- accounting (the satellite fix: report POOL bytes, not the
     # slots × max_len worst case the slot cache reserved) ------------------
@@ -842,6 +865,7 @@ class PagedDecodeProgram(DecodeProgram):
     def _manifest_extra(self):
         out = {'paged': True, 'page_size': self.page_size,
                'pages': self.pages, 'spec_k': self.spec_k,
+               'max_run': self.max_run,
                'max_pages': self.max_pages,
                'page_bytes': self.page_bytes()}
         if self.window_pages:
@@ -873,6 +897,12 @@ class PagedDecodeProgram(DecodeProgram):
             out['slot'] = slot
         return out if len(out) > 1 else full
 
+    def _keys_shape(self, kind):
+        """The step's keys: one a row and a step of the run."""
+        if kind == 'step':
+            return (self.slots, self.max_run, 2)
+        return super()._keys_shape(kind)
+
     @staticmethod
     def _each_kind(arg, fn):
         """``fn`` over page ids or tables in that form, as a
@@ -903,22 +933,67 @@ class PagedDecodeProgram(DecodeProgram):
         return fn
 
     def _paged_step_fn(self, key):
+        import jax
         import jax.numpy as jnp
+        from jax import lax
         from .sampling import sample_tokens
         counts = self.trace_counts
         model, emit = self.model, self.emit_logits
         gather = self._gather_ad
+        run, slots, n_stats = self.max_run, self.slots, self._n_stats
 
-        def fn(params, pool, tokens, positions, tables, extras):
-            counts[key] = counts.get(key, 0) + 1
+        def one(params, pool, tokens, positions, tables, extras, k):
             pool, logits, *stats = model.paged_step(
                 params, pool, tokens, positions, tables, gather(extras))
             tok = sample_tokens(logits, extras['temps'],
-                                extras['top_ps'], extras['keys'],
+                                extras['top_ps'], extras['keys'][:, k],
                                 extras.get('masks'))
+            return pool, tok, logits, stats
+
+        def fn(params, pool, tokens, positions, tables, steps, extras):
+            counts[key] = counts.get(key, 0) + 1
+            if run == 1:
+                pool, tok, logits, stats = one(params, pool, tokens,
+                                               positions, tables,
+                                               extras, 0)
+                out = tok[None]
+            else:
+                # up to ``run`` steps, as many as ``steps['n']`` asks:
+                # step k feeds the token step k - 1 produced, or the
+                # prompt token ``forced[k]`` of a slot still extending;
+                # a slot past its budget steps as an empty slot does,
+                # at position 0 through the trash page
+                def body(k, carry):
+                    pool, prev, out, total = carry
+                    live = k < steps['budgets']
+                    fed = steps['forced'][k]
+                    pool, tok, _logits, stats = one(
+                        params, pool, jnp.where(fed >= 0, fed, prev),
+                        jnp.where(live, positions + k, 0),
+                        jax.tree.map(lambda t: jnp.where(
+                            live[:, None], t, TRASH_PAGE), tables),
+                        extras, k)
+                    out = lax.dynamic_update_index_in_dim(out, tok, k, 0)
+                    if stats:
+                        total = total + stats[0]
+                    return pool, tok, out, total
+
+                # one step straight, several in a loop: a loop has the
+                # compiler convert the weights it reads to bfloat16 once
+                # a call, ahead of it, which GPT-1's one step at low
+                # occupancy pays 0.5 ms for and a run of steps pays back
+                pool, _tok, out, total = lax.cond(
+                    steps['n'] > 1,
+                    lambda carry: lax.fori_loop(0, steps['n'], body, carry),
+                    lambda carry: body(0, carry),
+                    (pool, tokens, jnp.zeros((run, slots), 'int32'),
+                     jnp.zeros((n_stats,), 'int32')))
+                stats = [total] if n_stats else []
+            tok = out.reshape(-1)
             if stats:
-                # a family's device-side counts (model.step_stats) ride
-                # behind the tokens: one array, one read a tick
+                # a family's device-side counts (model.step_stats), summed
+                # over the run's steps, ride behind the tokens: one
+                # array, one read a call
                 tok = jnp.concatenate([tok, stats[0]])
             return (pool, tok, logits) if emit else (pool, tok)
         return fn
@@ -999,6 +1074,10 @@ class PagedDecodeProgram(DecodeProgram):
                                      'int32'),
                 jax.ShapeDtypeStruct((self.slots, self.window_pages),
                                      'int32')),
+            {'n': jax.ShapeDtypeStruct((), 'int32'),
+             'budgets': jax.ShapeDtypeStruct((self.slots,), 'int32'),
+             'forced': jax.ShapeDtypeStruct((self.max_run, self.slots),
+                                            'int32')},
             self._extra_avals('step'))
 
     def compile_verify(self):
@@ -1082,22 +1161,82 @@ class PagedDecodeProgram(DecodeProgram):
                  aidx=None):
         """Advance every slot one token through its page table
         (``tables``: one (slots, columns) array, or one for each kind
-        of layer). A family's device-side counts come back behind the
-        tokens and are left in ``last_step_stats``."""
+        of layer; ``keys`` (slots, 2)): :meth:`run_steps` at ``n`` 1.
+        Returns (pool', tokens np (slots,), logits np | None)."""
+        if keys is not None:
+            keys = onp.asarray(keys, 'uint32').reshape(self.slots, 1, 2)
+        pool, toks, logits = self.run_steps(
+            pool, tokens, positions, tables, 1, temps=temps,
+            top_ps=top_ps, keys=keys, masks=masks, apool=apool,
+            aidx=aidx)
+        return pool, toks[0], logits
+
+    def run_steps(self, pool, tokens, positions, tables, n=1,
+                  budgets=None, forced=None, temps=None, top_ps=None,
+                  keys=None, masks=None, apool=None, aidx=None):
+        """Advance every slot up to ``n`` tokens (1 to ``max_run``) in
+        one call of the step program, each step fed the token the one
+        before it produced. ``budgets`` (slots,) is the steps each slot
+        takes (default ``n``): a slot past its budget steps as an empty
+        one, through the trash page. ``forced`` (max_run, slots) feeds
+        step k a prompt token wherever it is not negative (default
+        none). ``keys`` (slots, up to max_run, 2) is one key a step, for
+        the position that step reads. A family's device-side counts
+        come back behind the tokens, summed over the steps, and are
+        left in ``last_step_stats``. Returns (pool', tokens np (n,
+        slots), logits np | None)."""
+        n = int(n)
+        if not 1 <= n <= self.max_run:
+            raise ValueError('a call runs 1 to %d steps, not %d'
+                             % (self.max_run, n))
+        S, R = self.slots, self.max_run
+        if keys is not None:
+            keys = onp.asarray(keys, 'uint32').reshape(S, -1, 2)
+            keys = onp.concatenate(
+                [keys, onp.zeros((S, R - keys.shape[1], 2), 'uint32')],
+                axis=1)
+        if budgets is None and forced is None:
+            steps = self._uniform_steps(n, pool)
+        else:
+            steps = {
+                'n': onp.int32(n),
+                'budgets': (onp.full(S, n, 'int32') if budgets is None
+                            else onp.asarray(budgets, 'int32').reshape(S)),
+                'forced': (onp.full((R, S), -1, 'int32') if forced is None
+                           else onp.asarray(forced, 'int32').reshape(R, S))}
         prog = self.compile_step()
         pool, toks, logits = self._call(
             prog, pool,
-            onp.asarray(tokens, 'int32').reshape(self.slots),
-            onp.asarray(positions, 'int32').reshape(self.slots),
-            self._tables_arg(tables),
+            onp.asarray(tokens, 'int32').reshape(S),
+            onp.asarray(positions, 'int32').reshape(S),
+            self._tables_arg(tables), steps,
             self._extra_args('step', temps, top_ps, keys, masks,
                              apool, aidx))
         if self._n_stats:
             self.last_step_stats = dict(zip(
                 self.model.step_stats,
-                (int(v) for v in toks[self.slots:])))
-            toks = toks[:self.slots]
-        return pool, toks, logits
+                (int(v) for v in toks[R * S:])))
+            toks = toks[:R * S]
+        return pool, toks.reshape(R, S)[:n], logits
+
+    def _uniform_steps(self, n, pool):
+        """:meth:`run_steps`' operand where every slot takes ``n`` steps
+        and no prompt token is forced, put once on the device that holds
+        the pool: each host array a call carries is a transfer the call
+        waits for, which a call of one step at low occupancy feels."""
+        import jax
+        devices = jax.tree.leaves(pool)[0].devices()
+        key = (n, frozenset(devices))
+        steps = self._steps_operands.get(key)
+        if steps is None:
+            steps = {'n': onp.int32(n),
+                     'budgets': onp.full(self.slots, n, 'int32'),
+                     'forced': onp.full((self.max_run, self.slots), -1,
+                                        'int32')}
+            if len(devices) == 1:
+                steps = jax.device_put(steps, next(iter(devices)))
+            self._steps_operands[key] = steps
+        return steps
 
     def run_verify(self, pool, tokens, positions, tables, temps=None,
                    top_ps=None, keys=None, masks=None, apool=None,

@@ -514,15 +514,17 @@ def test_one_kind_programs_are_what_they_were():
 
 
 _COUNTS = ['adapter_rejects', 'cow_copies', 'drain_timeouts',
-           'fallback_tokens', 'gc_collections', 'gc_pause_max_s',
-           'gc_pause_s', 'handoff_pages', 'kv_page_copies', 'kv_pages_view',
-           'kv_pages_walked', 'migrated_in', 'migrated_out', 'page_evictions',
-           'pool_exhausted', 'prefill_exports', 'prefills', 'prefix_hits',
-           'prefix_tokens_saved', 'pump_batches', 'pump_fallbacks',
-           'pump_sends', 'pump_tokens', 'rejected', 'requests', 'retired',
-           'sampled_steps', 'sampled_tokens', 'slow_ticks', 'spec_accepted',
-           'spec_proposed', 'spec_rounds', 'steps', 'stream_cpu_s',
-           'timeouts', 'tokens', 'worker_cpu_s', 'worker_wall_s']
+           'fallback_tokens', 'gc_collections', 'gc_pause_max_s', 'gc_pause_s',
+           'handoff_pages', 'kv_page_copies', 'kv_pages_view',
+           'kv_pages_walked', 'migrated_in', 'migrated_out',
+           'multi_step_calls', 'page_evictions', 'pool_exhausted',
+           'prefill_exports', 'prefills', 'prefix_hits', 'prefix_tokens_saved',
+           'pump_batches', 'pump_fallbacks', 'pump_sends', 'pump_tokens',
+           'rejected', 'requests', 'retired', 'sampled_steps',
+           'sampled_tokens', 'slot_steps_idle', 'slow_ticks', 'spec_accepted',
+           'spec_proposed', 'spec_rounds', 'step_calls', 'steps',
+           'stream_cpu_s', 'timeouts', 'tokens', 'worker_cpu_s',
+           'worker_wall_s']
 _POOL = ['occupancy_pct', 'pages_free', 'pages_total', 'pages_used',
          'prefix_entries']
 _ACCOUNTING = ['cache_bytes', 'max_concurrent_sequences_per_gb',

@@ -544,6 +544,8 @@ def test_status_carries_the_host_cpu_and_gc_counters():
     import gc
     sess, srv = _toy_server()
     try:
+        _post_stream(srv.port, [1, 2, 3], 3)      # compiles: slow ticks
+        before = _status(srv.port)['decode']['counts']['slow_ticks']
         _post_stream(srv.port, [1, 2, 3], 6)
         gc.collect()
         counts = _status(srv.port)['decode']['counts']
@@ -553,7 +555,10 @@ def test_status_carries_the_host_cpu_and_gc_counters():
     assert 0 < counts['worker_cpu_s']
     assert counts['worker_cpu_s'] <= counts['worker_wall_s'] * 1.05
     assert counts['stream_cpu_s'] > 0
-    assert counts['slow_ticks'] == 0
+    assert counts['slow_ticks'] == before
+    # the step program's calls: one step each at this occupancy
+    assert counts['step_calls'] == counts['steps'] > 0
+    assert counts['multi_step_calls'] == counts['slot_steps_idle'] == 0
     for key in ('gc_collections', 'gc_pause_s', 'gc_pause_max_s'):
         assert sorted(counts[key]) == ['0', '1', '2']     # by generation
     assert counts['gc_collections']['2'] >= 1

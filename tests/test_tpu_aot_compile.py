@@ -186,6 +186,79 @@ def test_the_append_is_one_scatter_a_pool(step_text):
 
 
 # ---------------------------------------------------------------------------
+# the program's step: a run of up to ``MAX_RUN`` of these steps in a loop
+# ---------------------------------------------------------------------------
+
+def _run_program(model, slots, pages, chip, one_chip):
+    """``PagedDecodeProgram``'s step program for ``model``, a loop over up
+    to ``MAX_RUN`` steps, compiled for the described chip as the served
+    program is: (text, memory analysis). The step function is a function
+    of its operands alone, so the program is built without weights."""
+    import jax
+    from mxnet_tpu.serving.decode import PagedDecodeProgram
+
+    def on(tree):
+        return jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                           sharding=one_chip), tree)
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, 'int32', sharding=one_chip)
+
+    prog = PagedDecodeProgram(model, {}, slots=slots,
+                              prefill_buckets=(64,), page_size=PAGE,
+                              pages=pages, emit_logits=False)
+    assert prog.max_run == 4
+    compiled = _compiled(
+        prog._paged_step_fn('step'), chip,
+        on(jax.eval_shape(lambda: model.init_params(0))),
+        on(prog._cache_avals()), i32(slots), i32(slots),
+        i32(slots, prog.max_pages),
+        {'n': i32(), 'budgets': i32(slots),
+         'forced': i32(prog.max_run, slots)},
+        on(prog._extra_avals('step')))
+    return compiled.as_text(), compiled.memory_analysis()
+
+
+@pytest.fixture(scope='module')
+def gpt1_run(chip, one_chip, gpt1):
+    return _run_program(gpt1[0], SLOTS, PAGES, chip, one_chip)
+
+
+def _computations(text):
+    """{computation name: its body} of a compiled module's text."""
+    return dict(re.findall(r'^(?:ENTRY )?%?([\w.\-]+) .*?\{\n(.*?)^\}',
+                           text, re.M | re.S))
+
+
+def test_the_gpt1_run_loops_over_the_walking_step_in_place(gpt1_run):
+    """One step straight and the run's loop, chosen by ``n``: the weights
+    converted to bfloat16 ahead of the loop are the loop's branch's own, so
+    a call of one step pays nothing for them."""
+    text, memory = gpt1_run
+    assert len(re.findall(r' while\(', text)) == 1
+    assert len(_kernel_calls(text)) == 2 * LAYERS
+    weights = r'= bf16\[(%d,%d|%d,%d)\]\S* convert\(' % (
+        4 * UNITS, UNITS, UNITS, 4 * UNITS)
+    converting = [name for name, body in _computations(text).items()
+                  if re.search(weights, body)]
+    entry = re.search(r'^ENTRY %?([\w.\-]+)', text, re.M).group(1)
+    assert len(converting) == 1 and converting[0] != entry
+    assert ' while(' in _computations(text)[converting[0]]
+    assert 'kv_gather' not in text
+    aliases = re.search(r'input_output_alias=\{(.*?)\}, entry',
+                        text).group(1)
+    assert len(re.findall(r'\{\d+\}: \(\d+, \{\}, may-alias\)', aliases)) \
+        == 2 * LAYERS
+    pool = '%d,%d,%d' % (PAGES, PAGE, UNITS)
+    assert not [n for n in _results(text, pool) if n.startswith('copy')]
+    assert not _arrays_of(text, SLOTS * MAX_LEN * UNITS,
+                          (UNITS, UNITS // HEADS))
+    # what the run allocates beside its operands is a step's, not a pool
+    assert memory.temp_size_in_bytes < PAGES * PAGE * UNITS * 4
+
+
+# ---------------------------------------------------------------------------
 # granitemoehybrid: recurrent state in slot entries of the donated cache
 # ---------------------------------------------------------------------------
 
@@ -273,6 +346,39 @@ def test_the_granite_step_walks_the_table_and_gathers_no_view(granite_step):
     assert len(_kernel_calls(text)) == 1
     pool = '%d,%d,%d' % (G_SLOTS * spec.max_pages + 1, PAGE, width)
     assert not [n for n in _results(text, pool) if n.startswith('copy')]
+
+
+def test_the_granite_run_carries_its_state_in_place(chip, one_chip,
+                                                    granite_step):
+    """The run's loop carries the 2.4 GB of state of the served cell (268
+    MB here) and the pools: each updated in place, no copy of either, and
+    nothing of the state's size allocated beside them."""
+    spec = granite_step[0]
+    from mxnet_tpu.serving.decode import GraniteHybridLM
+    model = GraniteHybridLM(dict(
+        vocab=1024, max_len=G_MAX_LEN, hidden=4096,
+        layer_types=['mamba', 'attention'], eps=1e-5, head_dim=128,
+        heads=32, kv_heads=8, mamba_heads=128, mamba_head_dim=64,
+        mamba_state=128, mamba_conv=4, mamba_chunk=256, experts=72,
+        held_experts=[0, 1], top_k=10, expert_hidden=768,
+        shared_hidden=1536, embedding_multiplier=12.0,
+        residual_multiplier=0.22, attention_multiplier=0.0078125,
+        logits_scaling=16.0))
+    text, memory = _run_program(model, G_SLOTS,
+                                G_SLOTS * spec.max_pages + 1, chip,
+                                one_chip)
+    assert len(re.findall(r' while\(', text)) == 1
+    aliases = re.search(r'input_output_alias=\{(.*?)\}, entry', text).group(1)
+    assert len(re.findall(r'\{\d+\}: \(\d+, \{\}, may-alias\)', aliases)) \
+        == 4
+    state = 4
+    for d in G_STATE:
+        state *= d
+    shape = ','.join(str(d) for d in G_STATE)
+    assert not [n for n in _results(text, shape) if n.startswith('copy')]
+    pool = '%d,%d,%d' % (G_SLOTS * spec.max_pages + 1, PAGE, 8 * 128)
+    assert not [n for n in _results(text, pool) if n.startswith('copy')]
+    assert memory.temp_size_in_bytes < state
 
 
 # ---------------------------------------------------------------------------
